@@ -28,11 +28,11 @@ def main() -> int:
     os.environ["TIDB_TPU_NUM_PROCESSES"] = str(nproc)
     os.environ["TIDB_TPU_PROCESS_ID"] = str(pid)
     os.environ["TIDB_TPU_TILE"] = "1024"
-    os.environ["TIDB_TPU_COMPILE_CACHE"] = "0"  # per-process compiles
 
     import jax
 
     jax.config.update("jax_platforms", "cpu")
+    jax.config.update("jax_enable_compilation_cache", False)  # per-process compiles
 
     # join the cluster on the MAIN thread before any worker thread races
     # into backend init (get_mesh -> _maybe_init_multihost)

@@ -19,7 +19,6 @@ Pallas kernel instead of composing jnp ops.  The tier's contract:
 """
 
 from .kernels import (  # noqa: F401
-    pallas_available,
     pallas_enabled,
     remap_codes,
     trace_remap_kernel,

@@ -28,23 +28,13 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-try:  # the tier degrades to the jnp fallbacks when pallas is absent
-    from jax.experimental import pallas as pl
-
-    _PALLAS_OK = True
-except Exception:  # pragma: no cover - jax without pallas
-    pl = None
-    _PALLAS_OK = False
-
-
-def pallas_available() -> bool:
-    return _PALLAS_OK
+from jax.experimental import pallas as pl
 
 
 def pallas_enabled() -> bool:
     """The tier switch: TIDB_TPU_PALLAS=0 restores the plain-XLA
     composition at every call site (the unfused comparator)."""
-    return _PALLAS_OK and os.environ.get("TIDB_TPU_PALLAS", "1") != "0"
+    return os.environ.get("TIDB_TPU_PALLAS", "1") != "0"
 
 
 def _interpret() -> bool:

@@ -38,10 +38,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.4.35 stable API
-    from jax import shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..chunk import Chunk, Column
 from ..copr import jax_engine as je
@@ -611,8 +608,8 @@ def _build_mpp_fn(spec: MPPJoinSpec, ps: _SideState, bs: _SideState,
         in_specs = in_specs + tuple(
             P() for r in (remaps or ()) if r is not None)
     fn = shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_rep=False)
-    return _packed_jit(fn)
+                   out_specs=out_specs, check_vma=False)
+    return _packed_jit(fn, mesh)
 
 
 # ---------------------------------------------------------------------------

@@ -233,10 +233,7 @@ def trace_exchange_kernel(mode: str = "shuffle"):
     """make_jaxpr stats for the canonical exchange join over a 1-device
     mesh (deterministic across environments regardless of how many
     virtual devices the harness exposes); used by lint.kernelcheck."""
-    try:
-        from jax import shard_map
-    except ImportError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     S, cap, n_local = 1, 64, 256
@@ -316,10 +313,7 @@ def _tree_kernel_fn():
     shared by trace_tree_join_kernel and run_tree_join_kernel so the
     traced jaxpr and the executed result can never diverge on mesh or
     spec constants.  Returns (fn, n_local)."""
-    try:
-        from jax import shard_map
-    except ImportError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     S, cap, n_local, cap_out = _TREE_KERNEL_SHAPE
@@ -442,10 +436,7 @@ def trace_grouped_agg_kernel(budget: int = 7):
     program over a 1-device mesh; `budget` rides the runtime scalar
     slot — lint.kernelcheck traces two budgets and requires identical
     jaxprs (the budget must never become a compiled constant)."""
-    try:
-        from jax import shard_map
-    except ImportError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     S, cap_out, cap_g = 1, 256, 32

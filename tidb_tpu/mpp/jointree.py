@@ -45,10 +45,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.4.35 stable API
-    from jax import shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..chunk import Chunk, Column
 from ..copr.device_health import classify_failure
@@ -357,7 +354,7 @@ def _build_rung_fn(spec: MPPJoinTreeSpec, r: int, states, mesh, mode: str,
         P("dp"), P("dp"), P("dp"),
         tuple(P() for _ in range(2 * MESH_RANGE_SLOTS)))
     fn = shard_map(shard_fn, mesh=mesh, in_specs=full_in,
-                   out_specs=out_specs, check_rep=False)
+                   out_specs=out_specs, check_vma=False)
     return jax.jit(fn)
 
 
@@ -540,8 +537,8 @@ def _build_final_fn(spec: MPPJoinTreeSpec, states, mesh, n_in: int,
         in_specs = in_specs + tuple(
             P() for r in (remaps or ()) if r is not None)
     fn = shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_rep=False)
-    return _packed_jit(fn)
+                   out_specs=out_specs, check_vma=False)
+    return _packed_jit(fn, mesh)
 
 
 # ---------------------------------------------------------------------------
