@@ -271,16 +271,21 @@ def test_chaos_sweep_read_path(sess):
          lambda: DeviceFailure("chip 5 died", device_ids=(5,)), "tpu"),
         ("mesh/hbm_oom",
          lambda: HbmOomError("hbm allocation failure"), "tpu"),
-        ("mesh/rebuild", lambda: RuntimeError("rebuild interrupted"), "tpu"),
+        ("mesh/rebuild", lambda: DeviceFailure("rebuild interrupted"), "tpu"),
         ("distsql/task_error", lambda: RuntimeError("chip died"), "cpu"),
         ("copr/region_error", lambda: RegionError("injected"), "cpu"),
     ]
     for name, make_exc, engine in sites:
         sess.execute(f"set tidb_use_tpu = {1 if engine == 'tpu' else 0}")
-        if name == "mesh/rebuild":
-            # a rebuild only happens when the device set changes
-            DEVICE_HEALTH.record_error(1, RuntimeError("pre-tripped"))
-        for sql in SWEEP_QUERIES:
+        for qi, sql in enumerate(SWEEP_QUERIES):
+            if name == "mesh/rebuild":
+                # a rebuild only happens when the device set changes, and
+                # the retry after a classified failure completes it: flip
+                # the set before every query
+                if qi % 2 == 0:
+                    DEVICE_HEALTH.record_error(1, RuntimeError("pre-tripped"))
+                else:
+                    DEVICE_HEALTH.reset()
             fired = {"n": 0}
 
             def action(_exc=make_exc, _f=fired, **ctx):

@@ -20,6 +20,7 @@ from tidb_tpu.dataplane import (PartitionMapMismatch, activate_dataplane,
                                 get_dataplane)
 from tidb_tpu.dataplane.shard import _pack_column, _unpack_column
 from tidb_tpu.metrics import REGISTRY
+from tidb_tpu.copr.device_health import DeviceFailure
 from tidb_tpu.store.fault import FAILPOINTS, failpoint, once
 from tidb_tpu.tpch_data import build_lineitem
 
@@ -293,7 +294,15 @@ def test_reshard_chaos_site_falls_back_then_converges(two_member_fleet):
     # the chaos site: the FIRST replay of an orphaned partition dies
     # mid-re-shard.  The dispatch must fall back (parity preserved) and
     # the NEXT dispatch must replay the whole transition successfully.
-    with failpoint("dataplane/reshard", once(RuntimeError("injected"))):
+    # an unclassified exception there is a bug, not a device failure: it
+    # reaches the client instead of being answered by the local path
+    with failpoint("dataplane/reshard", once(TypeError("injected bug"))):
+        before_err = _cnt("dataplane_errors_total")
+        with pytest.raises(TypeError, match="injected bug"):
+            sA.execute(Q6)
+        assert _cnt("dataplane_errors_total") == before_err
+    with failpoint("dataplane/reshard",
+                   once(DeviceFailure("injected device failure"))):
         before_err = _cnt("dataplane_errors_total")
         assert sA.execute(Q6)[0].rows == oracle6
         assert _cnt("dataplane_errors_total") > before_err

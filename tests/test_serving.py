@@ -16,6 +16,7 @@ from tidb_tpu import serving
 from tidb_tpu.errors import MaxExecutionTimeExceeded, QueryKilledError
 from tidb_tpu.metrics import REGISTRY
 from tidb_tpu.session import Domain
+from tidb_tpu.copr.device_health import DeviceFailure
 from tidb_tpu.store.fault import failpoint, once
 
 
@@ -410,13 +411,26 @@ def test_microbatch_chaos_batch_dispatch(sess):
             for k in (100, 200)]
     solo = [sess.query(q) for q in sqls]
     e0, = _snap("serving_batch_errors_total")
-    with failpoint("serving/batch_dispatch", once(RuntimeError("chaos"))):
+    with failpoint("serving/batch_dispatch",
+                   once(DeviceFailure("chaos: chip died"))):
         results, errors, _ = _concurrent(d, sqls, window_ms=300)
     assert errors == [None, None], errors
     for q, got, want in zip(sqls, results, solo):
         _approx_rows(got, want, q)
     e1, = _snap("serving_batch_errors_total")
     assert e1 == e0 + 1, "chaos site never fired on the batch path"
+
+
+def test_microbatch_unclassified_error_reaches_the_client(sess):
+    """A bug in the batch dispatch (not a runtime device failure) is not
+    papered over by the solo rungs: every member sees the exception."""
+    d = sess.domain
+    sqls = [f"select count(*), sum(x) from t where k = {k}"
+            for k in (100, 200)]
+    with failpoint("serving/batch_dispatch",
+                   once(TypeError("unexpected keyword argument"))):
+        _, errors, _ = _concurrent(d, sqls, window_ms=300)
+    assert [type(e) for e in errors] == [TypeError, TypeError], errors
 
 
 def test_microbatch_respects_max_batch(sess):

@@ -5,10 +5,10 @@ gathers for dictionary-code re-mapping, the bit-unpack shift chain of
 the cold tier), the emitter drops one level and calls a hand-written
 Pallas kernel instead of composing jnp ops.  The tier's contract:
 
-- kernels run in **interpret mode** by default (pure-jax evaluation, so
-  the CPU tier-1 harness and any non-TPU backend execute them with no
-  Mosaic toolchain); ``TIDB_TPU_PALLAS_COMPILE=1`` opts into compiled
-  Mosaic lowering on real TPU backends;
+- kernels compile to Mosaic on a TPU backend and run in **interpret
+  mode** (pure-jax evaluation) everywhere else, so the CPU tier-1
+  harness executes them with no Mosaic toolchain; both are compiled for
+  a described v5e at the production tile in tests/test_tpu_compile.py;
 - ``TIDB_TPU_PALLAS=0`` disables the tier entirely — every call site
   falls back to its plain-XLA composition, the bench's unfused
   comparator (parity is test-asserted both ways);

@@ -49,6 +49,7 @@ import queue
 import threading
 from typing import Dict, List, Optional, Tuple
 
+from ..copr.device_health import classify_failure
 from ..errors import TiDBTPUError
 from ..metrics import REGISTRY
 from .partition import PartitionMap, PartitionMapMismatch
@@ -178,7 +179,9 @@ def try_run_dataplane(storage, req) -> Optional[List]:
             return None
         except TiDBTPUError:
             raise  # semantic errors (kill, quota) surface unchanged
-        except Exception:
+        except Exception as e:
+            if classify_failure(e) is None:
+                raise  # not a runtime device failure: reaches the client
             REGISTRY.inc("dataplane_errors_total")
             log.warning("dataplane dispatch failed; falling back to the "
                         "local path", exc_info=True)

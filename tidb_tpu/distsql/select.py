@@ -27,6 +27,7 @@ from dataclasses import dataclass, field
 from typing import Iterator, List, Optional
 
 from ..chunk import Chunk
+from ..copr.device_health import classify_failure
 from ..copr.ir import DAG
 from ..errors import TiDBTPUError
 from ..store.fault import FAILPOINTS
@@ -207,6 +208,11 @@ class SelectResult:
                     raise
                 except BaseException as e:
                     if engine == "tpu":
+                        if classify_failure(e) is None:
+                            # not a runtime device failure (TypeError,
+                            # lowering/compile error, a bug): the oracle
+                            # must not answer for the device in silence
+                            raise
                         # runtime device failure: this region falls back
                         # to the CPU engine (coprocessor.go:912-999
                         # retries a failed region; our "other store" is
@@ -236,6 +242,22 @@ class SelectResult:
                     sp.set(scan_engine=self.scan_engine,
                            tasks=self.total_tasks,
                            fallback_tasks=self.fallback_tasks)
+
+    @staticmethod
+    def _mesh_failed(exc: BaseException, what: str):
+        """The mesh rung steps down to the per-region path only on a
+        classified runtime device failure; anything else (TypeError,
+        lowering/compile error, a bug) re-raises and reaches the
+        client."""
+        import logging
+
+        from ..metrics import REGISTRY
+
+        if classify_failure(exc) is None:
+            raise exc
+        REGISTRY.inc("mesh_scan_errors_total")
+        logging.getLogger("tidb_tpu.distsql").warning(
+            "%s; falling back to per-region path", what, exc_info=exc)
 
     def _produce(self):
         try:
@@ -280,16 +302,8 @@ class SelectResult:
                     out = try_run_mesh(self.storage, self.req)
                 except TiDBTPUError:
                     raise
-                except Exception:
-                    import logging
-
-                    from ..metrics import REGISTRY
-
-                    REGISTRY.inc("mesh_scan_errors_total")
-                    logging.getLogger("tidb_tpu.distsql").warning(
-                        "mesh scan failed; falling back to per-region path",
-                        exc_info=True,
-                    )
+                except Exception as e:
+                    self._mesh_failed(e, "mesh scan failed")
                     out = None
                 if out is not None:
                     # filter results arrive as a LAZY generator (streamed
@@ -307,19 +321,11 @@ class SelectResult:
                         return
                     except (_Closed, TiDBTPUError):
                         raise
-                    except Exception:
+                    except Exception as e:
                         if emitted:
                             raise
-                        import logging
-
-                        from ..metrics import REGISTRY
-
-                        REGISTRY.inc("mesh_scan_errors_total")
-                        logging.getLogger("tidb_tpu.distsql").warning(
-                            "mesh stream failed before first chunk; "
-                            "falling back to per-region path",
-                            exc_info=True,
-                        )
+                        self._mesh_failed(
+                            e, "mesh stream failed before first chunk")
                 self.scan_engine = "tile-fanout"
             else:
                 self.scan_engine = "cpu"

@@ -161,10 +161,12 @@ class MySQLServer:
         if self._checkpoint_task is not None:
             self._checkpoint_task.cancel()
             self._checkpoint_task = None
-        if self._server is not None:
-            self._server.close()
-            await self._server.wait_closed()
-            self._server = None
+        # close() stops the listener at once; wait_closed() also waits for
+        # every open connection (Python >= 3.12), so it comes last, after
+        # the drain below has closed them
+        server, self._server = self._server, None
+        if server is not None:
+            server.close()
         loop = asyncio.get_running_loop()
         deadline = loop.time() + max(drain_s, 0.0)
         while loop.time() < deadline:
@@ -233,6 +235,8 @@ class MySQLServer:
         tasks = list(self._conns)
         if tasks:
             await asyncio.wait(tasks, timeout=5.0)
+        if server is not None:
+            await server.wait_closed()
         self.pool.shutdown(wait=False)
 
     # ------------------------------------------------------------------

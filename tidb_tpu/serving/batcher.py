@@ -32,6 +32,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from ..copr.device_health import classify_failure
 from ..errors import TiDBTPUError
 from ..metrics import REGISTRY
 from ..store.fault import FAILPOINTS
@@ -410,7 +411,9 @@ def try_run_batched(storage, req):
                                  lambda live: _run_batch(ctx, live))
         except TiDBTPUError:
             raise  # kill / deadline / shutdown: the statement's own fate
-        except BaseException as e:  # noqa: BLE001 — fall back to solo
+        except BaseException as e:  # noqa: BLE001
+            if classify_failure(e) is None:
+                raise  # not a runtime device failure: reaches the client
             log.warning("micro-batch dispatch failed; falling back to "
                         "solo execution: %s", e)
             sp.set(batch=member.batch_size, outcome="error")
