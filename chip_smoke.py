@@ -408,6 +408,34 @@ def run_one_chip(smoke: Smoke, rows: int, q3_rows: tuple, seed: int = 7):
     ])
 
 
+def run_pallas_parity(smoke: Smoke, n: int):
+    """The Pallas kernels are off the Q1/Q6/Q3 path (computed string keys,
+    cold-tier columns): run each once, compiled, against the jnp composition
+    it replaces."""
+    import jax
+    import numpy as np
+
+    from tidb_tpu.copr.pallas import kernels
+
+    rng = np.random.default_rng(0)
+    codes = rng.integers(0, 1 << 4, n).astype(np.uint8)
+    mapping = rng.integers(0, 1 << 30, kernels.REMAP_MAX_CAP).astype(np.int32)
+    keys = rng.integers(0, kernels.REMAP_MAX_CAP, n).astype(np.int32)
+    packed = (codes[0::2] | (codes[1::2] << 4)).astype(np.uint8)
+    cases = [
+        ("remap_codes", lambda: kernels.remap_codes(keys, mapping, n),
+         mapping[keys]),
+        ("unpack_codes", lambda: kernels.unpack_codes(packed, 4, n), codes),
+    ]
+    for name, fn, want in cases:
+        t0 = time.perf_counter()
+        got = np.asarray(jax.jit(fn)())
+        equal = bool(np.array_equal(got, want))
+        emit({"pallas": name, "rows": n, "compiled": not kernels._interpret(),
+              "equal": equal, "first_s": time.perf_counter() - t0})
+        smoke.check(equal, f"pallas {name} disagrees with its reference")
+
+
 def run_four_chip(smoke: Smoke, rows: int, mpp_rows: tuple, n_devices: int,
                   seed: int = 7):
     """What exists only across chips: lineitem sharded over the mesh with
@@ -486,6 +514,7 @@ def main() -> int:
         run_four_chip(smoke, args.rows, MPP_ROWS, 4, args.seed)
     else:
         run_one_chip(smoke, args.rows, Q3_ROWS, args.seed)
+        run_pallas_parity(smoke, 1 << 20)
     report_device()
     if smoke.problems:
         print(f"chip_smoke: {len(smoke.problems)} checks failed",
