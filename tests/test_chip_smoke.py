@@ -2,7 +2,8 @@
 
 The smoke is the proof that the served path starts on the chip; this file is
 the proof that the smoke cannot pass on a lower rung: a mesh program that
-raises must fail it — the regression that hid the `check_rep` break.
+raises must fail it — the regression that hid the shard_map keyword the
+installed JAX refuses.
 """
 
 import os
@@ -54,8 +55,8 @@ def test_fallback_counters_did_not_move(report):
 
 
 def test_broken_mesh_program_fails_the_smoke(monkeypatch):
-    """A mesh builder that raises TypeError (what `check_rep=` did under the
-    installed JAX) must fail the smoke, not pass on the tile rung."""
+    """A mesh builder that raises TypeError (what a shard_map keyword the
+    installed JAX refuses did) must fail the smoke, not pass on the tile rung."""
     from tidb_tpu.copr import parallel
 
     def broken(*a, **kw):

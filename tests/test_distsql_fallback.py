@@ -3,7 +3,7 @@
 A device rung steps down only on what copr/device_health.classify_failure
 recognises as a runtime device failure.  TypeError, AttributeError, lowering
 and compile errors and anything else unclassified reach the client — a silent
-step down is how a `shard_map(check_rep=...)` the installed JAX refuses went
+step down is how a shard_map keyword the installed JAX refuses went
 unnoticed on every mesh dispatch.  (The micro-batch and data-plane rungs are
 held to the same rule in test_serving.py and test_dataplane.py.)
 """
@@ -54,7 +54,7 @@ def test_classified_failure_on_the_tile_rung_falls_back_and_counts(
 
 
 @pytest.mark.parametrize("exc", [
-    TypeError("shard_map() got an unexpected keyword argument 'check_rep'"),
+    TypeError("shard_map() got an unexpected keyword argument"),
     AttributeError("module 'jax' has no attribute 'nope'"),
     NotImplementedError("Strided store with non 32-bit data"),
     RuntimeError("a bare runtime error names no device"),

@@ -246,14 +246,17 @@ class SelectResult:
     @staticmethod
     def _mesh_failed(exc: BaseException, what: str):
         """The mesh rung steps down to the per-region path only on a
-        classified runtime device failure; anything else (TypeError,
-        lowering/compile error, a bug) re-raises and reaches the
-        client."""
+        classified runtime device failure, or on the typed membership
+        move whose retries ran out (CoordEpochMismatch: the per-region
+        path needs no mesh); anything else (TypeError, lowering/compile
+        error, a bug) re-raises and reaches the client."""
         import logging
 
+        from ..coord import CoordEpochMismatch
         from ..metrics import REGISTRY
 
-        if classify_failure(exc) is None:
+        if (classify_failure(exc) is None
+                and not isinstance(exc, CoordEpochMismatch)):
             raise exc
         REGISTRY.inc("mesh_scan_errors_total")
         logging.getLogger("tidb_tpu.distsql").warning(

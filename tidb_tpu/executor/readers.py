@@ -58,7 +58,7 @@ class TableReaderExec(Executor):
 
     def _route(self, engine: str) -> str:
         """First cost model for TPU-vs-host routing: a device scan pays a
-        fixed dispatch+readback latency (dominant on tunneled chips), the
+        fixed dispatch+readback latency, the
         host pays per-row; route small scans to the host (the reference's
         per-operator cop-vs-root cost split, planner/core/task.go)."""
         v = self.ctx.vars
