@@ -61,6 +61,13 @@ MPPQ = (
     " where l_shipdate > '1995-03-15'"
 )
 
+#: (name, sql, operators of which EXPLAIN must show one, rungs that may serve
+#: it, counter that must move on every device run)
+LINEITEM_SCANS = [
+    ("q1", Q1, ("TableReader",), ("mesh",), "mesh_scans_total"),
+    ("q6", Q6, ("TableReader",), ("mesh",), "mesh_scans_total"),
+]
+
 #: a run in which any of these moved was not served by the device alone
 FALLBACK_COUNTERS = (
     "mesh_scan_errors_total",
@@ -69,7 +76,6 @@ FALLBACK_COUNTERS = (
     "mpp_tree_fallback_total",
     "mesh_failover_retries_total",
 )
-DEVICE_RUNGS = ("mesh", "tile-fanout", "microbatch", "dataplane")
 _MYSQL_FLOAT_TYPES = (4, 5)  # FLOAT, DOUBLE; decimals and ints compare exact
 
 
@@ -396,10 +402,7 @@ def _scans(smoke: Smoke, domain, queries, setup: tuple = ()):
 
 def run_one_chip(smoke: Smoke, rows: int, q3_rows: tuple, seed: int = 7):
     """Q1 and Q6 over lineitem, Q3 over its own pair, each over the wire."""
-    _scans(smoke, _load_lineitem(rows, seed), [
-        ("q1", Q1, ("TableReader",), ("mesh",), "mesh_scans_total"),
-        ("q6", Q6, ("TableReader",), ("mesh",), "mesh_scans_total"),
-    ])
+    _scans(smoke, _load_lineitem(rows, seed), LINEITEM_SCANS)
     from tidb_tpu.tpch_data import Q3_SQL
 
     _scans(smoke, _load_q3(*q3_rows, seed), [
@@ -446,10 +449,7 @@ def run_four_chip(smoke: Smoke, rows: int, mpp_rows: tuple, n_devices: int,
     smoke.check(len(mesh_devices) == n_devices,
                 f"get_mesh() spans {len(mesh_devices)} devices, "
                 f"expected {n_devices}")
-    _scans(smoke, _load_lineitem(rows, seed), [
-        ("q1", Q1, ("TableReader",), ("mesh",), "mesh_scans_total"),
-        ("q6", Q6, ("TableReader",), ("mesh",), "mesh_scans_total"),
-    ])
+    _scans(smoke, _load_lineitem(rows, seed), LINEITEM_SCANS)
     spans = sorted({len(data.sharding.device_set)
                     for data, _ in MESH_CACHE._cache.values()})
     emit({"mesh_cache_arrays": len(MESH_CACHE._cache),

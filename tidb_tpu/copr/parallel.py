@@ -47,6 +47,7 @@ from ..store.kv import CopRequest
 from ..types import TypeKind
 from .device_health import (
     DEVICE_HEALTH,
+    DeviceFailure,
     attribute_devices,
     classify_failure,
 )
@@ -1515,8 +1516,9 @@ def _guarded_stream(storage, req: CopRequest, tid: int, gen, attempts: int):
                 if gen is None or isinstance(gen, list):
                     # re-analysis on the rebuilt mesh declined the
                     # request (data changed under us): surface as a
-                    # pre-first-chunk error so distsql falls back
-                    raise RuntimeError(
+                    # pre-first-chunk device failure — the retry follows
+                    # one — so distsql steps down to the per-region path
+                    raise DeviceFailure(
                         "mesh retry declined: "
                         f"{getattr(req, 'mesh_reject_reason', 'ineligible')}")
             for c in gen:
