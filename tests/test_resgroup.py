@@ -113,6 +113,37 @@ def test_chunk_bounds_split_and_disabled():
         assert a1 <= b0
 
 
+def test_chunk_budget_settles_when_a_dispatch_scans_the_whole_table():
+    """A mesh program masks the rows outside a chunk's bounds, it does not
+    skip them: one dispatch costs one pass over the table.  Fed the rows
+    scanned, the budget settles near ceil(pass_ms / budget_ms) chunks; fed
+    the rows in bounds it shrank geometrically, run over run, whenever a
+    pass outlasted the budget."""
+    from tidb_tpu.copr.chunking import (chunk_bounds, chunk_budget_rows,
+                                        observe_chunk)
+
+    kind, table, pass_ms = "unit-whole-table-pass", 1 << 26, 250.0
+    counts = []
+    for _run in range(5):
+        n = len(chunk_bounds([(0, table)], chunk_budget_rows(kind)))
+        counts.append(n)
+        for _ in range(n):
+            observe_chunk(kind, pass_ms, table)
+    assert counts[0] == 82  # the cold-start guess: 8,192 rows/ms
+    assert counts[1:] == [counts[1]] * 4 and 1 <= counts[1] <= 4, counts
+
+
+def test_mesh_dispatch_reports_rows_scanned_not_rows_in_bounds(sess, chunked):
+    name = "dispatch_chunk_agg_rows"
+    h0 = REGISTRY.hist_stats(name) or {"count": 0, "sum": 0.0}
+    sess.query("select g, count(*), sum(x) from t group by g")
+    h1 = REGISTRY.hist_stats(name)
+    n = h1["count"] - h0["count"]
+    assert n > 1, "the query did not take the chunked path"
+    n_rows = sess.query("select count(*) from t")[0][0]
+    assert (h1["sum"] - h0["sum"]) / n >= n_rows > 2048
+
+
 # ---------------------------------------------------------------------------
 # parity: chunked == unchunked across the corpus
 # ---------------------------------------------------------------------------

@@ -128,7 +128,10 @@ def chunk_bounds(bounds: Sequence[Tuple[int, int]], budget_rows: int,
 
 def observe_chunk(kind: str, ms: float, rows: int):
     """Feed one completed chunk back into the budget estimate and the
-    chunk telemetry (/metrics, EXPLAIN ANALYZE `chunks: N`)."""
+    chunk telemetry (/metrics, EXPLAIN ANALYZE `chunks: N`).  `rows` is
+    what the dispatch made the device process: for a mesh program, every
+    resident row, since rows outside the chunk's bounds are masked, not
+    skipped."""
     REGISTRY.inc("dispatch_chunks_total")
     REGISTRY.observe_hist(f"dispatch_chunk_{kind}_ms", ms)
     REGISTRY.observe_hist(f"dispatch_chunk_{kind}_rows", float(rows))

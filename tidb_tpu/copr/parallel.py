@@ -1850,8 +1850,13 @@ def _run_mesh_once(storage, req: CopRequest, tid: int,
                 # group yields the device at every chunk boundary
                 with dispatch_admission(DISPATCH_LOCK):
                     out = fn(datas, valids, del_mask, sub, lvals, pargs)
+            # the budget learns from the rows the device SCANNED: the
+            # program masks rows outside `sub`, it does not skip them, so
+            # a dispatch costs one pass over the resident table whatever
+            # its bounds (fed the chunk's rows the estimate shrank run
+            # over run whenever a pass outlasted the budget)
             observe_chunk(kind, (time.perf_counter() - t0) * 1000.0,
-                          rows)
+                          int(del_mask.size))
             return out
 
         if kind == "agg" and an.agg_mode == "sort":
@@ -1971,8 +1976,9 @@ def _stream_filter(req, table, an, fn, datas, valids, del_mask, inserted,
             with span("copr.chunk", kind="filter", chunk=ci, rows=crows):
                 with dispatch_admission(DISPATCH_LOCK):
                     mask = fn(datas, valids, del_mask, sub, lvals, pargs)
+            # rows scanned, not rows in bounds: see _chunk_dispatch
             observe_chunk("filter", (time.perf_counter() - t0) * 1000.0,
-                          crows)
+                          int(del_mask.size))
             handles = np.flatnonzero(mask)
             if remaining is not None:
                 handles = handles[:remaining]
