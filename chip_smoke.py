@@ -61,12 +61,17 @@ MPPQ = (
     " where l_shipdate > '1995-03-15'"
 )
 
-#: (name, sql, operators of which EXPLAIN must show one, rungs that may serve
-#: it, counter that must move on every device run)
-LINEITEM_SCANS = [
-    ("q1", Q1, ("TableReader",), ("mesh",), "mesh_scans_total"),
-    ("q6", Q6, ("TableReader",), ("mesh",), "mesh_scans_total"),
-]
+#: how a plan is served: the operator EXPLAIN names -> (the rungs the
+#: executed trace may then report, the counter every device run must move)
+SCAN = {"TableReader": (("mesh",), "mesh_scans_total")}
+#: the planner picks Q3's device join by size: the broadcast lookup inside the
+#: cop task, or the MPP exchange when the build side is too big to broadcast
+JOIN = {
+    "DeviceJoinReader": (("mesh",), "mesh_scans_total"),
+    "MPPJoin": (("mpp-shuffle", "mpp-broadcast"), "mpp_joins_total"),
+}
+SHUFFLE_JOIN = {"ExchangeSender": (("mpp-shuffle",), "mpp_joins_total")}
+LINEITEM_SCANS = [("q1", Q1, SCAN), ("q6", Q6, SCAN)]
 
 #: a run in which any of these moved was not served by the device alone
 FALLBACK_COUNTERS = (
@@ -251,19 +256,18 @@ class Smoke:
 
     # -- one query through the wire ------------------------------------
     def run_query(self, cli: WireClient, name: str, sql: str,
-                  expect_plan: tuple, expect_rungs: tuple,
-                  expect_counter: str):
+                  served_by: dict):
         """EXPLAIN, first run, traced second run, oracle run; one JSON
-        line.  `expect_plan`: operator names of which EXPLAIN must show
-        one.  `expect_rungs`: the scan_engine values every distsql fan-out
-        of the second run may report.  `expect_counter`: the counter that
-        must move on both device runs."""
+        line.  `served_by`: see SCAN / JOIN above — the first operator of
+        it that EXPLAIN shows decides which rungs and counter are right."""
         cli.query("set tidb_use_tpu = 1")
         _, plan = cli.query("explain " + sql)
         ops = [r[0].strip(" └─│├") for r in plan]
-        named = [p for p in expect_plan if any(p in op for op in ops)]
+        named = [p for p in served_by if any(p in op for op in ops)]
         self.check(bool(named),
-                   f"{name}: EXPLAIN names none of {expect_plan}: {ops}")
+                   f"{name}: EXPLAIN names none of {list(served_by)}: {ops}")
+        expect_rungs, expect_counter = served_by[named[0]] if named else (
+            (), "mesh_scans_total")
 
         c0 = self.counters()
         t0 = time.perf_counter()
@@ -315,7 +319,8 @@ class Smoke:
             "second_run_chunks": sum(1 for s in spans
                                      if s["name"] == "copr.chunk"),
             "rows": len(rows), "second_rows": second_rows,
-            expect_counter: served, "fallback_counters_moved": moved,
+            "served_counter": {expect_counter: served},
+            "fallback_counters_moved": moved,
             "parity": mismatch is None,
             "note": "smoke, not a benchmark",
         })
@@ -394,8 +399,8 @@ def _scans(smoke: Smoke, domain, queries, setup: tuple = ()):
     cli = served.client()
     for stmt in setup:
         cli.query(stmt)
-    for name, sql, plan, rungs, counter in queries:
-        smoke.run_query(cli, name, sql, plan, rungs, counter)
+    for name, sql, served_by in queries:
+        smoke.run_query(cli, name, sql, served_by)
     cli.close()
     served.stop()
 
@@ -405,10 +410,7 @@ def run_one_chip(smoke: Smoke, rows: int, q3_rows: tuple, seed: int = 7):
     _scans(smoke, _load_lineitem(rows, seed), LINEITEM_SCANS)
     from tidb_tpu.tpch_data import Q3_SQL
 
-    _scans(smoke, _load_q3(*q3_rows, seed), [
-        ("q3", Q3_SQL, ("DeviceJoinReader", "MPPJoin", "ExchangeSender"),
-         ("mesh", "mpp-shuffle", "mpp-broadcast"), "mesh_scans_total"),
-    ])
+    _scans(smoke, _load_q3(*q3_rows, seed), [("q3", Q3_SQL, JOIN)])
 
 
 def run_pallas_parity(smoke: Smoke, n: int):
@@ -464,10 +466,9 @@ def run_four_chip(smoke: Smoke, rows: int, mpp_rows: tuple, n_devices: int,
         smoke.check(max(in_use) - min(in_use) < max(in_use) / 4,
                     f"bytes_in_use unbalanced across the mesh: {in_use}")
 
-    _scans(smoke, _load_q3(*mpp_rows, seed), [
-        ("mpp-shuffle-join", MPPQ, ("ExchangeSender",), ("mpp-shuffle",),
-         "mpp_joins_total"),
-    ], setup=("set tidb_enforce_mpp = 1",))
+    _scans(smoke, _load_q3(*mpp_rows, seed),
+           [("mpp-shuffle-join", MPPQ, SHUFFLE_JOIN)],
+           setup=("set tidb_enforce_mpp = 1",))
 
 
 def report_device():

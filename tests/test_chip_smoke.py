@@ -43,6 +43,7 @@ def test_every_query_reports_a_device_rung(report):
         assert q["engine"] == ["mesh"], (name, q["engine"])
         assert q["parity"] and q["rows"] > 0, (name, q)
     assert queries["q3"]["plan"] == ["DeviceJoinReader"]
+    assert queries["q3"]["served_counter"] == {"mesh_scans_total": [2.0, 2.0]}
 
 
 def test_fallback_counters_did_not_move(report):
@@ -96,3 +97,28 @@ def test_compare_rows_exact_for_decimals_relative_for_doubles():
     assert cmp([246], [("1.10",)], [("1.1",)]) is not None
     assert cmp([8], [("1",)], []) is not None
     assert cmp([8], [(None,)], [(None,)]) is None
+
+
+def test_four_chip_phases_on_the_virtual_mesh():
+    """`--chips 4`'s phases over the harness's eight virtual devices: sharded
+    lineitem, psum-merged Q1/Q6, and the MPP shuffle join served by its
+    exchange with `mpp_joins_total` moving."""
+    import contextlib
+    import io
+    import json
+
+    import jax
+
+    smoke = chip_smoke.Smoke()
+    out = io.StringIO()
+    with contextlib.redirect_stdout(out):
+        chip_smoke.run_four_chip(smoke, 16384, (16384, 4096),
+                                 len(jax.devices()))
+    assert smoke.problems == []
+    lines = [json.loads(ln) for ln in out.getvalue().splitlines()]
+    join = next(ln for ln in lines if ln.get("query") == "mpp-shuffle-join")
+    assert join["plan"] == ["ExchangeSender"]
+    assert join["rung"] == ["mpp-shuffle"] and join["parity"]
+    assert min(join["served_counter"]["mpp_joins_total"]) > 0
+    sizes = next(ln for ln in lines if "device_set_sizes" in ln)
+    assert sizes["device_set_sizes"] == [len(jax.devices())]
