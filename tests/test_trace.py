@@ -553,3 +553,290 @@ def test_profiler_torn_persist_file_starts_fresh(env, tmp_path):
         assert p.folded() == ""
     finally:
         recorder.unchain_export_hook(p.fold)
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 29: spans where the statements' dark time was
+# ---------------------------------------------------------------------------
+
+
+def _end(sp):
+    return sp.start_ns + sp.dur_ns
+
+
+def _assert_in_order(spans):
+    """On the clock, in the order given, none overlapping the next."""
+    for a, b in zip(spans, spans[1:]):
+        assert _end(a) <= b.start_ns, (a.name, _end(a), b.name, b.start_ns)
+
+
+@pytest.mark.parametrize("start_ns", [12_345, None])
+def test_add_span_places_a_pretimed_span_where_told(start_ns):
+    tr, token = trace_mod.start_trace("select 1")
+    try:
+        before = time.perf_counter_ns()
+        sp = tr.add_span("wire.read", 700, start_ns=start_ns, bytes=9)
+        after = time.perf_counter_ns()
+    finally:
+        trace_mod.finish_trace(tr, token)
+    assert sp in tr.root.children and sp.dur_ns == 700
+    assert sp.attrs == {"bytes": 9}
+    if start_ns is None:
+        assert before <= sp.start_ns <= after  # the moment of the append
+    else:
+        # before the root's start: the envelope renders as it comes
+        assert sp.start_ns == 12_345 < tr.root.start_ns
+        row = [r for r in tr.rows() if r[0].startswith("  wire.read")][0]
+        assert row[1].startswith("-")
+        d = tr.to_dict()["root"]["children"][0]
+        assert d["name"] == "wire.read" and d["start_us"] < 0
+        back = trace_mod.import_trace(trace_mod.trace_payload(tr))
+        child = back.root.children[0]
+        assert child.start_ns < back.root.start_ns  # survives the export
+
+
+def test_nothing_emits_the_span_name_no_phase_maps():
+    from tidb_tpu.trace.recorder import PHASES
+
+    assert "copr.execute" not in PHASES
+    assert PHASES["copr.device.execute"] == "device_ms"
+
+
+SERVED = {
+    "result_set": "select l_flag, count(*) from li group by l_flag",
+    "ok_packet": "insert into envelope_t values (1)",
+    "error": "select no_such_column from li",
+}
+
+
+@pytest.mark.parametrize("kind", sorted(SERVED))
+def test_served_statement_carries_the_servers_envelope(env, kind):
+    """A statement served by MySQLServer to a socket client: wire.read,
+    admission.wait, server.handoff, the root, session.account,
+    server.respond (around wire.write), each at its true place."""
+    import asyncio
+
+    from test_lifecycle import WireClient, run
+    from tidb_tpu.server import MySQLServer
+
+    d, _s = env
+    sql = SERVED[kind]
+
+    async def body():
+        srv = MySQLServer(d, port=0)
+        await srv.start()
+        try:
+            mine = set(srv.domain.sessions)
+            cli = WireClient(srv.host, srv.port)
+            await cli.connect()
+            mine = set(srv.domain.sessions) - mine
+            await cli.query("create table if not exists envelope_t"
+                            " (a bigint)")
+            await asyncio.sleep(0.05)  # think time: wire.read starts early
+            t_send = time.perf_counter_ns()
+            got = await cli.query(sql)
+            t_done = time.perf_counter_ns()
+            sess = srv.domain.sessions[mine.pop()]
+            cli.close()
+            return sess.last_trace, got, t_send, t_done
+        finally:
+            await srv.stop()
+
+    tr, got, t_send, t_done = run(body())
+    assert tr.sql == sql and tr.finished
+    assert ("error" in got) == (kind == "error")
+    top = {sp.name: sp for sp in tr.root.children}
+    chain = [top["wire.read"], top["admission.wait"], top["server.handoff"],
+             tr.root, top["session.account"], top["server.respond"]]
+    _assert_in_order(chain)
+    # the envelope abuts the root on both sides
+    assert _end(top["server.handoff"]) == tr.root.start_ns
+    assert top["session.account"].start_ns == _end(tr.root)
+    # wire.read began while the client was thinking and ends inside the
+    # client's send-to-done interval; everything later lies inside it
+    assert top["wire.read"].start_ns < t_send
+    assert top["wire.read"].dur_ns >= int(0.04 * 1e9)
+    assert t_send <= _end(top["wire.read"]) <= t_done
+    for sp in chain[1:]:
+        assert t_send <= sp.start_ns and _end(sp) <= t_done, sp.name
+    assert top["wire.read"].attrs["bytes"] == len(sql)
+    assert top["admission.wait"].attrs["queued"] == 0
+    # whether it crossed tidb_slow_log_threshold (300 ms; a loaded test
+    # machine can take that long)
+    assert top["session.account"].attrs["slow"] is (tr.duration_ms() >= 300)
+    resp = top["server.respond"]
+    assert resp.attrs["bytes"] > 0
+    if kind == "result_set":
+        ww = top["wire.write"]
+        assert resp.start_ns <= ww.start_ns and _end(ww) <= _end(resp)
+        assert ww.attrs["rows"] == resp.attrs["rows"] == len(got["rows"])
+        assert ww.attrs["bytes"] == resp.attrs["bytes"]
+    else:
+        assert "wire.write" not in top and resp.attrs["rows"] == 0
+
+
+MESH = {
+    "agg": Q1ISH,
+    "filter": "select l_orderkey, l_qty from li where l_qty < 7",
+    "topn": "select l_orderkey, l_price from li order by l_price desc"
+            " limit 5",
+}
+CHUNK_CHILDREN = ["copr.dispatch.wait", "copr.args", "copr.device.execute",
+                  "copr.readback", "copr.unpack"]
+
+
+@pytest.mark.parametrize("kind", sorted(MESH))
+def test_every_mesh_dispatch_is_divided(env, kind):
+    d, s = env
+    s.query(MESH[kind])  # the first dispatch is labelled copr.compile
+    s.query(MESH[kind])
+    tr = s.last_trace
+    chunks = _spans_by_name(tr, "copr.chunk")
+    assert chunks and all(c.attrs["kind"] == kind for c in chunks)
+    for c in chunks:
+        assert [k.name for k in c.children] == CHUNK_CHILDREN
+        _assert_in_order(c.children)
+        assert c.start_ns <= c.children[0].start_ns
+        assert _end(c.children[-1]) <= _end(c)
+        assert sum(k.dur_ns for k in c.children) <= c.dur_ns
+        by = {k.name: k for k in c.children}
+        assert by["copr.device.execute"].attrs["program"].startswith(
+            f"mesh_{kind}_")
+        rb = by["copr.readback"]
+        assert [k.name for k in rb.children] == ["copr.device.wait"]
+        wait = rb.children[0]
+        assert rb.start_ns <= wait.start_ns and _end(wait) <= _end(rb)
+        assert rb.attrs["bytes"] == by["copr.unpack"].attrs["bytes"] > 0
+        assert by["copr.unpack"].attrs["rows"] > 0
+    # one program a statement shape: every pass launches the same one
+    assert len({c.children[2].attrs["program"] for c in chunks}) == 1
+
+
+def test_program_names_follow_the_fingerprint():
+    from tidb_tpu.copr.parallel import _program_name
+
+    a = _program_name("agg", "fp one")
+    assert a == _program_name("agg", "fp one") == "mesh_agg_" + a[-8:]
+    assert a != _program_name("agg", "fp two")
+    assert int(a[-8:], 16) >= 0 and len(a) == len("mesh_agg_") + 8
+
+
+STREAMED = {
+    "plain": ("select l_orderkey, l_qty from li where l_qty < 9", None),
+    # the planner keeps unfolded decimal arithmetic at the root (Q6's shape)
+    "root_selection": ("select l_orderkey from li where l_qty < 30 and l_price"
+                       " between 500.5 - 100.25 and 500.5 + 100.25", None),
+    "limit": ("select l_orderkey from li where l_qty < 30 limit 17", 17),
+}
+
+
+@pytest.mark.parametrize("case", sorted(STREAMED))
+def test_streamed_filter_finishing_is_spanned(env, case):
+    d, s = env
+    sql, limit = STREAMED[case]
+    rows = s.query(sql)
+    tr = s.last_trace
+    from test_lifecycle import _wait_no_select_threads
+
+    # a LIMIT closes the result early: let the producer finish its span
+    assert _wait_no_select_threads() == []
+    fanout = _spans_by_name(tr, "distsql.fanout")
+    assert len(fanout) == 1 and fanout[0].attrs["scan_engine"] == "mesh"
+    selects = _spans_by_name(tr, "copr.select")
+    gathers = _spans_by_name(tr, "copr.gather")
+    chunks = _spans_by_name(tr, "copr.chunk")
+    assert selects and gathers
+    assert len(selects) == len(chunks)  # once a chunk
+    assert all(sp in fanout[0].children for sp in selects + gathers)
+    passed = sum(sp.attrs["rows"] for sp in selects)
+    assert sum(sp.attrs["rows"] for sp in gathers) == passed
+    assert all(sp.attrs["rows_in"] >= sp.attrs["rows"] for sp in selects)
+    assert all(sp.attrs["bytes"] > 0 for sp in gathers)
+    nxt = [sp for sp in tr.root.children if sp.name == "executor.next"]
+    assert len(nxt) == 1
+    ops = nxt[0].attrs["ops"]
+    assert all(len(op) == 5 and op[4] >= 0 for op in ops)
+    assert ops[0][2] == nxt[0].attrs["rows"] == len(rows)  # the root's rows
+    reader = [op for op in ops if op[1] == "TableReaderExec"]
+    assert len(reader) == 1
+    if limit is None:
+        assert reader[0][2] == passed  # what the device let through
+    else:
+        assert len(rows) == limit <= passed
+    if case == "root_selection":
+        assert any(op[1] == "SelectionExec" for op in ops)
+        assert len(rows) < passed
+    # self times are parts of the drain: they cannot exceed it
+    assert sum(op[4] for op in ops) <= nxt[0].dur_ns / 1e6 + 1e-6
+
+
+def test_full_queue_wait_is_spanned():
+    """`SelectResult._put` opens distsql.put.wait only when the first try
+    found the queue full, and closes it when the chunk is taken."""
+    import queue
+    import threading
+
+    from tidb_tpu.distsql.select import SelectResult
+    from tidb_tpu.lifecycle import QueryScope
+
+    res = SelectResult.__new__(SelectResult)
+    res._chunks = queue.Queue(maxsize=1)
+    res._stop = threading.Event()
+    res._scope = QueryScope(None)
+    tr, token = trace_mod.start_trace("select 1")
+    try:
+        res._put("a")  # room: no span
+        assert not _spans_by_name(tr, "distsql.put.wait")
+        threading.Timer(0.08, res._chunks.get).start()
+        res._put("b")  # full until the consumer takes "a"
+    finally:
+        trace_mod.finish_trace(tr, token)
+    waits = _spans_by_name(tr, "distsql.put.wait")
+    assert len(waits) == 1 and waits[0].dur_ns >= int(0.05 * 1e9)
+    assert res._chunks.get_nowait() == "b"
+
+
+@pytest.mark.parametrize("kind", sorted(MESH) + ["root_selection"])
+def test_recorder_off_same_rows_nothing_recorded(env, kind):
+    d, s = env
+    sql = MESH.get(kind) or STREAMED[kind][0]
+    want = s.query(sql)
+    s.execute("set tidb_enable_slow_log = 0")
+    try:
+        ring = list(trace_mod.TRACE_RING)
+        last = s.last_trace
+        got = s.query(sql)
+        assert list(trace_mod.TRACE_RING) == ring
+        assert s.last_trace is last
+    finally:
+        s.execute("set tidb_enable_slow_log = 1")
+    assert sorted(got) == sorted(want)
+
+
+def test_recorder_off_served_statement_leaves_no_stamp(env):
+    from test_lifecycle import WireClient, run
+    from tidb_tpu.server import MySQLServer
+
+    d, _s = env
+
+    async def body():
+        srv = MySQLServer(d, port=0)
+        await srv.start()
+        try:
+            mine = set(srv.domain.sessions)
+            cli = WireClient(srv.host, srv.port)
+            await cli.connect()
+            sess = srv.domain.sessions[(set(srv.domain.sessions) - mine).pop()]
+            await cli.query("set tidb_enable_slow_log = 0")
+            marker = sess.last_trace
+            ring = list(trace_mod.TRACE_RING)
+            got = await cli.query(SERVED["result_set"])
+            assert list(trace_mod.TRACE_RING) == ring
+            assert sess.last_trace is marker
+            assert sess._pending_envelope is None  # consumed, not kept
+            cli.close()
+            return got
+        finally:
+            await srv.stop()
+
+    assert len(run(body())["rows"]) == 3

@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import threading
 import time
+import zlib
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -832,7 +833,55 @@ def _readback_sharding(mesh: Mesh):
     return NamedSharding(mesh, P()) if jax.process_count() > 1 else None
 
 
-def _packed_jit(fn, mesh: Mesh):
+def _launch(jitted, program: Optional[str], args):
+    """Enqueue one compiled program.  `copr.device.execute` ends when
+    the call returns, which is at the enqueue; `program` is the name the
+    device trace knows the program by (its XLA module is `jit_<name>`)."""
+    from ..trace import span
+
+    attrs = {"program": program} if program else {}
+    with span("copr.device.execute", hbm_bytes=_hbm_bytes(), **attrs):
+        return jitted(*args)
+
+
+def _read_back(out) -> np.ndarray:
+    """One device result to the host.  `copr.readback` is the wait for
+    the device plus the copy; its child `copr.device.wait` ends when the
+    device has finished, so `copr.device.execute` + `copr.device.wait` is
+    the device's part of a dispatch as the host sees it and the rest of
+    `copr.readback` is the copy.  The copy is asked for BEFORE the wait,
+    as a lone `np.asarray` asks for it, so that it follows the program on
+    the device with no host round trip in between."""
+    from ..trace import span
+
+    with span("copr.readback") as sp:
+        out.copy_to_host_async()
+        with span("copr.device.wait"):
+            out.block_until_ready()
+        buf = np.asarray(out)
+        sp.set(bytes=buf.nbytes)
+    return buf
+
+
+def _call_args(datas, valids, del_mask, bounds, lvals, pargs) -> tuple:
+    """The runtime operands of one mesh dispatch (`copr.args`): the
+    column tuples and the range-slot scalars of `_bounds_args`."""
+    from ..trace import span
+
+    with span("copr.args"):
+        return (tuple(datas), tuple(valids), del_mask,
+                _bounds_args(bounds), tuple(lvals), *pargs)
+
+
+def _program_name(kind: str, fp: str) -> str:
+    """`mesh_<kind>_<crc32 of the program-cache fingerprint>`: what the
+    jitted callable is called, so the device trace's `XLA Modules` line
+    tells the mesh programs apart, and what `copr.device.execute` carries
+    as `program=`, so spans and device time can be joined."""
+    return f"mesh_{kind}_{zlib.crc32(fp.encode()) & 0xFFFFFFFF:08x}"
+
+
+def _packed_jit(fn, mesh: Mesh, name: Optional[str] = None, merge=None):
     """jit `fn` (whose output is a pytree of 64-bit-wide arrays) so the whole
     result crosses device->host as ONE flat float64 buffer.
 
@@ -845,6 +894,11 @@ def _packed_jit(fn, mesh: Mesh):
     rather than a bitcast: 0 <= half < 2^32 is always exactly representable
     in f64, and no 64-bit bitcast-convert is needed (assumed unsafe under
     the TPU's x64 emulation; not re-tested on the attached chip).
+
+    `name` names the jitted callable (see `_program_name`); `merge`, where
+    given, is applied to the unpacked pytree inside the `copr.unpack` span
+    (the caller's per-shard merge is part of getting from the packed
+    buffer to what the caller receives).
     """
     meta = {}
 
@@ -869,29 +923,29 @@ def _packed_jit(fn, mesh: Mesh):
         meta["specs"] = specs
         return jnp.concatenate(flat) if flat else jnp.zeros(0, jnp.float64)
 
+    if name:
+        packed.__name__ = packed.__qualname__ = name
     jitted = jax.jit(packed, out_shardings=_readback_sharding(mesh))
 
     def call(*args):
         from ..trace import span
 
-        with span("copr.device.execute", hbm_bytes=_hbm_bytes()):
-            out = jitted(*args)
-        with span("copr.readback") as sp:
-            buf = np.asarray(out)
-            sp.set(bytes=buf.nbytes)
-        leaves, off = [], 0
-        for shape, dt in meta["specs"]:
-            n = int(np.prod(shape, dtype=np.int64)) if shape else 1
-            if np.issubdtype(dt, np.floating):
-                seg = buf[off: off + n].astype(dt)
-                off += n
-            else:
-                hi = buf[off: off + n].astype(np.int64)
-                lo = buf[off + n: off + 2 * n].astype(np.int64)
-                off += 2 * n
-                seg = ((hi << 32) + lo).astype(dt)
-            leaves.append(seg.reshape(shape))
-        return jax.tree_util.tree_unflatten(meta["treedef"], leaves)
+        buf = _read_back(_launch(jitted, name, args))
+        with span("copr.unpack", rows=int(buf.size), bytes=buf.nbytes):
+            leaves, off = [], 0
+            for shape, dt in meta["specs"]:
+                n = int(np.prod(shape, dtype=np.int64)) if shape else 1
+                if np.issubdtype(dt, np.floating):
+                    seg = buf[off: off + n].astype(dt)
+                    off += n
+                else:
+                    hi = buf[off: off + n].astype(np.int64)
+                    lo = buf[off + n: off + 2 * n].astype(np.int64)
+                    off += 2 * n
+                    seg = ((hi << 32) + lo).astype(dt)
+                leaves.append(seg.reshape(shape))
+            out = jax.tree_util.tree_unflatten(meta["treedef"], leaves)
+            return merge(out) if merge is not None else out
 
     return call
 
@@ -1005,7 +1059,7 @@ def _build_mesh_core(an: _Analyzed, kind: str, col_order: List[int],
 
 def _build_mesh_fn(an: _Analyzed, kind: str, col_order: List[int],
                    mesh: Mesh, tiles_per_shard: int, hoisted: bool = False,
-                   col_layout=None):
+                   col_layout=None, name: Optional[str] = None):
     """One jitted shard_map program over the whole fragment.
 
     Inputs: datas [n_pad, TILE] x cols (cold columns: [n_pad,
@@ -1015,7 +1069,7 @@ def _build_mesh_fn(an: _Analyzed, kind: str, col_order: List[int],
     tail (probe key sets, lookup payloads, and — when `hoisted` — the
     replicated (pi, pf) predicate parameter vectors).  Every range of a
     steady-state fragment runs in this ONE dispatch; intermediates never
-    leave HBM.
+    leave HBM.  `name` is the jitted callable's (`_program_name`).
     """
     S = len(mesh.devices.ravel())
     n_local = tiles_per_shard * je.TILE
@@ -1023,19 +1077,15 @@ def _build_mesh_fn(an: _Analyzed, kind: str, col_order: List[int],
                             hoisted=hoisted, col_layout=col_layout)
 
     if kind == "agg" and an.agg_mode == "sort":
-        return _wrap_sort_agg(an, core, mesh, S, n_local)
+        return _wrap_sort_agg(an, core, mesh, S, n_local, name)
 
     if kind == "agg":
         agg_ir = an.agg
         G = an.num_groups
         tags = je._agg_tags(agg_ir)
-        packed = _packed_jit(core, mesh)
 
-        def wrapped(datas, valids, del_mask, bounds, lvals=(), pargs=()):
-            gcount, results = packed(
-                tuple(datas), tuple(valids), del_mask,
-                _bounds_args(bounds), tuple(lvals), *pargs,
-            )
+        def merge_shards(out):
+            gcount, results = out
             merged = []
             for tag, r in zip(tags, results):
                 if tag == "minmax":
@@ -1050,43 +1100,44 @@ def _build_mesh_fn(an: _Analyzed, kind: str, col_order: List[int],
                     merged.append((tag, r))
             return gcount, merged
 
+        packed = _packed_jit(core, mesh, name, merge=merge_shards)
+
+        def wrapped(datas, valids, del_mask, bounds, lvals=(), pargs=()):
+            return packed(*_call_args(datas, valids, del_mask, bounds,
+                                      lvals, pargs))
+
         return wrapped
 
     if kind == "topn":
         from ..serving import topn_budget
 
         k = min(topn_budget(an.topn.limit), n_local)
-        packed = _packed_jit(core, mesh)
+        packed = _packed_jit(core, mesh, name)
 
         def wrapped(datas, valids, del_mask, bounds, lvals=(), pargs=()):
-            gidx, cnt = packed(
-                tuple(datas), tuple(valids), del_mask,
-                _bounds_args(bounds), tuple(lvals), *pargs,
-            )
+            gidx, cnt = packed(*_call_args(datas, valids, del_mask, bounds,
+                                           lvals, pargs))
             return gidx, cnt, k
         return wrapped
 
     # filter (with optional projection evaluated on device).  The mask comes
     # back bit-packed: 1 bit/row instead of 1 byte/row is an 8x smaller
     # device->host readback.
-    jitted = jax.jit(
-        lambda *a: jnp.packbits(core(*a).astype(jnp.uint8)),
-        out_shardings=_readback_sharding(mesh),
-    )
+    def packed_mask(*a):
+        return jnp.packbits(core(*a).astype(jnp.uint8))
+
+    if name:
+        packed_mask.__name__ = packed_mask.__qualname__ = name
+    jitted = jax.jit(packed_mask, out_shardings=_readback_sharding(mesh))
 
     def wrapped(datas, valids, del_mask, bounds, lvals=(), pargs=()):
         from ..trace import span
 
         n_rows = S * n_local
-        with span("copr.device.execute", hbm_bytes=_hbm_bytes()):
-            out = jitted(
-                tuple(datas), tuple(valids), del_mask,
-                _bounds_args(bounds), tuple(lvals), *pargs,
-            )
-        with span("copr.readback") as sp:
-            bits = np.asarray(out)
-            sp.set(bytes=bits.nbytes)
-        return np.unpackbits(bits, count=n_rows).astype(np.bool_)
+        bits = _read_back(_launch(jitted, name, _call_args(
+            datas, valids, del_mask, bounds, lvals, pargs)))
+        with span("copr.unpack", rows=n_rows, bytes=bits.nbytes):
+            return np.unpackbits(bits, count=n_rows).astype(np.bool_)
     return wrapped
 
 
@@ -1228,18 +1279,16 @@ def _build_sort_agg_core(an: _Analyzed, col_order: List[int], mesh: Mesh,
 
 
 def _wrap_sort_agg(an: _Analyzed, core, mesh: Mesh, S: int,
-                   n_local: int):
+                   n_local: int, name: Optional[str] = None):
     import os as _os
 
     OUT = min(int(_os.environ.get("TIDB_TPU_AGG_OUT", 1 << 17)), n_local)
     tags = je._agg_tags(an.agg)
-    packed = _packed_jit(core, mesh)
+    packed = _packed_jit(core, mesh, name)
 
     def wrapped(datas, valids, del_mask, bounds, lvals=(), pargs=()):
-        n_uniq, keys, results = packed(
-            tuple(datas), tuple(valids), del_mask,
-            _bounds_args(bounds), tuple(lvals), *pargs,
-        )
+        n_uniq, keys, results = packed(*_call_args(
+            datas, valids, del_mask, bounds, lvals, pargs))
         return {
             "mode": "sort",
             "S": S, "OUT": OUT,
@@ -1756,7 +1805,8 @@ def _run_mesh_once(storage, req: CopRequest, tid: int,
     if fn is None:
         fn = _build_mesh_fn(an, kind, col_order, mesh, Tl,
                             hoisted=hoisted is not None,
-                            col_layout=col_layout)
+                            col_layout=col_layout,
+                            name=_program_name(kind, fp))
         _COMPILED.put(fp, fn)
         # label this query's FIRST dispatch as the compile: jit compiles
         # lazily, so the program-cache miss pays XLA compilation there
@@ -1979,23 +2029,40 @@ def _stream_filter(req, table, an, fn, datas, valids, del_mask, inserted,
             # rows scanned, not rows in bounds: see _chunk_dispatch
             observe_chunk("filter", (time.perf_counter() - t0) * 1000.0,
                           int(del_mask.size))
-            handles = np.flatnonzero(mask)
-            if remaining is not None:
-                handles = handles[:remaining]
-                remaining -= len(handles)
+            # the host's finishing of the chunk, each step under a span
+            # of its own (none of them wraps a yield: the consumer's time
+            # between two slices is the queue's, not this thread's work)
+            with span("copr.select", rows_in=int(mask.size)) as sp:
+                handles = np.flatnonzero(mask)
+                # done with the mask: give its byte a row back here, before
+                # the gathers, not when the next pass's mask replaces it
+                # (inside that pass's copr.chunk, 7 ms at 67 M rows)
+                del mask
+                if remaining is not None:
+                    handles = handles[:remaining]
+                    remaining -= len(handles)
+                sp.set(rows=len(handles))
             for off in range(0, len(handles), STREAM_ROWS):
                 scope_check()  # between streamed host gathers
                 hsub = handles[off: off + STREAM_ROWS]
-                chunk = table.gather_chunk(list(an.scan.columns), hsub)
-                if an.proj_exprs is not None:
-                    # dict-rewritten exprs expect coded strings; gather
-                    # decodes, so project from the original projection IR
-                    chunk = Chunk([
-                        _eval_to_column(p, chunk)
-                        for p in an.projection.exprs
-                    ])
+                with span("copr.gather", rows=len(hsub)) as sp:
+                    chunk = table.gather_chunk(list(an.scan.columns), hsub)
+                    if an.proj_exprs is not None:
+                        # dict-rewritten exprs expect coded strings; gather
+                        # decodes, so project from the original projection
+                        # IR
+                        chunk = Chunk([
+                            _eval_to_column(p, chunk)
+                            for p in an.projection.exprs
+                        ])
+                    # the arrays' own bytes (an object column counts its
+                    # pointers: no walk over the values)
+                    sp.set(bytes=sum(c.data.nbytes for c in chunk.columns))
                 if tail:
-                    for tc in run_tail(dag, tail, [chunk], req.aux):
+                    with span("copr.tail", rows_in=chunk.num_rows) as sp:
+                        tcs = run_tail(dag, tail, [chunk], req.aux)
+                        sp.set(rows=sum(tc.num_rows for tc in tcs))
+                    for tc in tcs:
                         REGISTRY.inc("mesh_stream_chunks_total")
                         yield tc
                     continue

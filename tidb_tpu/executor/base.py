@@ -198,11 +198,40 @@ class Executor:
         return Chunk.empty(self.ftypes)
 
 
+def operator_times(exe: Executor) -> list:
+    """[[plan_id, operator, rows, loops, self_ms], ...] of an executor
+    tree, root first, from the OperatorStats its `next()` calls kept:
+    self_ms is the operator's time in `next()` less its children's, so a
+    reader's is its wait for the cop result and a root operator's is its
+    own work.  Executors without a plan id (internal helpers) are left
+    out and their time stays with their parent."""
+    out: list = []
+
+    def walk(e: Executor) -> int:
+        """Time to take off the parent: this operator's, or (no plan id,
+        so no stats of its own) its children's."""
+        st = e.ctx.stats.get(e.plan_id) if e.plan_id >= 0 else None
+        if st is not None:
+            at = len(out)
+            out.append(None)
+        below = sum(walk(c) for c in e.children)
+        if st is None:
+            return below
+        out[at] = [e.plan_id, type(e).__name__, st.rows, st.loops,
+                   max(st.time_ns - below, 0) / 1e6]
+        return st.time_ns
+
+    walk(exe)
+    return out
+
+
 def collect_all(exe: Executor) -> List[Chunk]:
     """Open/drain/close an executor tree (statement driver helper).
     Root open/next/close are traced (executor.go:196-212's trace region,
-    mapped onto the span recorder; no-ops when tracing is off)."""
-    from ..trace import span
+    mapped onto the span recorder; no-ops when tracing is off); the
+    `executor.next` span carries the drained tree's `operator_times` as
+    `ops`."""
+    from ..trace import NOOP, span
 
     with span("executor.open"):
         exe.open()
@@ -213,7 +242,8 @@ def collect_all(exe: Executor) -> List[Chunk]:
             while True:
                 c = exe.next()
                 if c is None:
-                    sp.set(rows=n)
+                    if sp is not NOOP:
+                        sp.set(rows=n, ops=operator_times(exe))
                     return out
                 if c.num_rows:
                     n += c.num_rows

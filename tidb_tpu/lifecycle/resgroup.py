@@ -480,7 +480,10 @@ def dispatch_admission(lock):
     device time on its chunk, never for sitting in the DISPATCH_LOCK
     queue behind other tenants' chunks — queue time is the scheduler's
     cost, and billing it would make one tenant's burst drain everyone
-    else's RU budget."""
+    else's RU budget.  That queue time is the trace's
+    ``copr.dispatch.wait`` span: from asking for `lock` to holding it
+    (the group's own wait before it stays ``resgroup.throttle``)."""
+    from ..trace import span
     from .scope import current_scope
 
     scope = current_scope()
@@ -489,12 +492,16 @@ def dispatch_admission(lock):
         _throttled_admit(group, scope)
     elapsed_ms = 0.0
     try:
-        with lock:
+        with span("copr.dispatch.wait"):
+            lock.acquire()
+        try:
             t0 = time.perf_counter()
             try:
                 yield
             finally:
                 elapsed_ms = (time.perf_counter() - t0) * 1000.0
+        finally:
+            lock.release()
     finally:
         if group is not None:
             group.charge(elapsed_ms, scope)
@@ -531,5 +538,7 @@ def _throttled_admit(group: ResourceGroup, scope):
 
         tr = current_trace()
         if tr is not None:
-            tr.add_span("resgroup.throttle", int(wait_ms * 1e6),
+            wait_ns = int(wait_ms * 1e6)
+            tr.add_span("resgroup.throttle", wait_ns,
+                        start_ns=time.perf_counter_ns() - wait_ns,
                         group=group.name)

@@ -305,7 +305,7 @@ class MySQLServer:
                 # network/client wait from admission-queue wait
                 t_recv = _time.perf_counter_ns()
                 data = await pr.recv()
-                recv_wait_ns = _time.perf_counter_ns() - t_recv
+                recv = (t_recv, _time.perf_counter_ns() - t_recv)
                 if not data:
                     break
                 pw.seq = pr.seq
@@ -318,13 +318,12 @@ class MySQLServer:
                 if cmd == COM_INIT_DB:
                     await self._run_sql(
                         sess, f"use {payload.decode()}", pw, loop,
-                        recv_wait_ns=recv_wait_ns,
+                        recv=recv,
                     )
                     continue
                 if cmd == COM_QUERY:
                     sql = payload.decode("utf8", "replace")
-                    await self._run_sql(sess, sql, pw, loop,
-                                        recv_wait_ns=recv_wait_ns)
+                    await self._run_sql(sess, sql, pw, loop, recv=recv)
                     continue
                 if cmd == COM_FIELD_LIST:
                     await pw.send(P.eof_packet())
@@ -357,7 +356,7 @@ class MySQLServer:
                     )
                     await self._run_sql(sess, st["sql"], pw, loop,
                                         params=params, binary=True,
-                                        recv_wait_ns=recv_wait_ns)
+                                        recv=recv)
                     continue
                 if cmd in (COM_STMT_CLOSE, COM_STMT_RESET):
                     sid = struct.unpack_from("<I", payload, 0)[0]
@@ -379,7 +378,9 @@ class MySQLServer:
 
     async def _run_sql(self, sess, sql: str, pw: PacketWriter, loop,
                        params=None, binary: bool = False,
-                       recv_wait_ns: int = 0):
+                       recv: tuple = (0, 0)):
+        """`recv` is (perf_counter_ns when the wait for the command
+        packet began, ns waited): the statement's wire.read span."""
         # ---- bounded admission (the overload front door) --------------
         # the worker pool admits `workers` statements; up to max_queued
         # more wait (bounded by queue_deadline_s); anything past that is
@@ -391,12 +392,14 @@ class MySQLServer:
             await self._reject_shutdown(pw, sql)
             return
         sem = self._admission
-        wait_ns = 0
+        # (start, ns waited, depth found) of the admission.wait span
+        admission = (0, 0, 0)
         if sem is not None:
             if sem.locked() and self._queued >= self.max_queued:
                 await self._reject_overload(pw, sql, "admission queue full")
                 return
             t0 = _time.perf_counter_ns()
+            queued = self._queued
             self._queued += 1
             # live queue-depth gauge: the serving layer's ADAPTIVE
             # micro-batch window reads this to widen under pressure
@@ -414,15 +417,15 @@ class MySQLServer:
             finally:
                 self._queued -= 1
                 REGISTRY.set("admission_queue_depth", float(self._queued))
-            wait_ns = _time.perf_counter_ns() - t0
-            REGISTRY.observe("admission_wait_ms", wait_ns / 1e6)
+            admission = (t0, _time.perf_counter_ns() - t0, queued)
+            REGISTRY.observe("admission_wait_ms", admission[1] / 1e6)
         try:
             if self._draining:
                 # drain began while this statement waited in the queue
                 await self._reject_shutdown(pw, sql)
                 return
             await self._run_sql_admitted(sess, sql, pw, loop, params,
-                                         binary, recv_wait_ns, wait_ns)
+                                         binary, recv, admission)
         finally:
             if sem is not None:
                 sem.release()
@@ -450,51 +453,88 @@ class MySQLServer:
 
     async def _run_sql_admitted(self, sess, sql: str, pw: PacketWriter,
                                 loop, params, binary: bool,
-                                recv_wait_ns: int, admission_wait_ns: int):
-        # wire.read attribution: the statement's trace root records how
-        # many bytes the COM_QUERY/COM_STMT_EXECUTE payload carried and
-        # how long the server waited on the socket for it (an asyncio-
-        # level wire.read span, distinct from admission-queue wait)
-        sess._pending_wire_read = (
-            len(sql.encode("utf8", "replace")), recv_wait_ns)
-        sess._pending_admission_wait_ns = admission_wait_ns
+                                recv: tuple, admission: tuple):
+        # the envelope's first half: stamps left on the session, which
+        # Session.execute turns into spans of its trace at their true
+        # places BEFORE the root — wire.read (bytes the COM_QUERY /
+        # COM_STMT_EXECUTE payload carried, socket wait for it),
+        # admission.wait (queue wait, depth found) and server.handoff
+        # (from here on the loop thread to the pool thread's start)
+        sess._pending_envelope = {
+            "read_start_ns": recv[0], "read_ns": recv[1],
+            "read_bytes": len(sql.encode("utf8", "replace")),
+            "admission_start_ns": admission[0],
+            "admission_ns": admission[1], "queued": admission[2],
+            "handoff_start_ns": _time.perf_counter_ns(),
+        }
+
+        stamp: list = []
+
+        def execute():
+            # the stamp is read on the pool thread, as execute returns
+            try:
+                return sess.execute(sql, params)
+            finally:
+                stamp.append(_time.perf_counter_ns())
+
         try:
-            rss = await loop.run_in_executor(
-                self.pool, lambda: sess.execute(sql, params)
-            )
+            rss = await loop.run_in_executor(self.pool, execute)
         except TiDBTPUError as e:
             # typed errors carry their MySQL code (errors.py hierarchy)
-            await pw.send(P.err_packet(getattr(e, "code", 1105), str(e)))
+            await self._respond(sess, sql, stamp, pw, [
+                P.err_packet(getattr(e, "code", 1105), str(e))])
             return
         except Exception as e:  # pragma: no cover - defensive
-            await pw.send(P.err_packet(1105, f"internal error: {e}"))
+            await self._respond(sess, sql, stamp, pw, [
+                P.err_packet(1105, f"internal error: {e}")])
             return
         rs = rss[-1] if rss else ResultSet()
         if not rs.is_query:
-            await pw.send(P.ok_packet(rs.affected_rows, rs.last_insert_id,
-                                      warnings=len(rs.warnings)))
+            await self._respond(sess, sql, stamp, pw, [
+                P.ok_packet(rs.affected_rows, rs.last_insert_id,
+                            warnings=len(rs.warnings))])
             return
+        fts = rs.ftypes
+        encode = (lambda r: P.binary_row(r, fts)) if binary else P.text_row
+
+        def packets():
+            yield bytes([len(rs.headers)])
+            for i, h in enumerate(rs.headers):
+                yield P.column_def(
+                    h, fts[i] if fts and i < len(fts) else None
+                )
+            yield P.eof_packet()
+            for row in rs.rows:
+                yield encode(row)
+            yield P.eof_packet()
+
+        await self._respond(sess, sql, stamp, pw, packets(),
+                            rows=len(rs.rows))
+
+    @staticmethod
+    async def _respond(sess, sql: str, stamp: list, pw: PacketWriter,
+                       packets, rows: Optional[int] = None):
+        """Send the answer's packets, then close the envelope on the
+        statement's finished trace: `server.respond` from the moment
+        Session.execute returned on the pool thread (`stamp`) to the last
+        packet handed to the socket, and for a result set (`rows` given)
+        `wire.write` inside it, over the encode + write alone.  Both are
+        appended after the fact: the statement ended before its rows hit
+        the socket."""
         t0 = _time.perf_counter_ns()
         nbytes = 0
-        fts = rs.ftypes
-        await pw.send(bytes([len(rs.headers)]))
-        for i, h in enumerate(rs.headers):
-            await pw.send(P.column_def(
-                h, fts[i] if fts and i < len(fts) else None
-            ))
-        await pw.send(P.eof_packet())
-        encode = (lambda r: P.binary_row(r, fts)) if binary else P.text_row
-        for row in rs.rows:
-            pkt = encode(row)
+        for pkt in packets:
             nbytes += len(pkt)
             await pw.send(pkt)
-        await pw.send(P.eof_packet())
+        t1 = _time.perf_counter_ns()
         tr = getattr(sess, "last_trace", None)
-        if tr is not None and tr.finished and tr.sql == sql:
-            # result encode+write time, appended onto the finished trace
-            # (the statement ended before its rows hit the socket)
-            tr.add_span("wire.write", _time.perf_counter_ns() - t0,
-                        bytes=nbytes, rows=len(rs.rows))
+        if tr is None or not tr.finished or tr.sql != sql or not stamp:
+            return
+        tr.add_span("server.respond", t1 - stamp[0], start_ns=stamp[0],
+                    bytes=nbytes, rows=rows or 0)
+        if rows is not None:
+            tr.add_span("wire.write", t1 - t0, start_ns=t0,
+                        bytes=nbytes, rows=rows)
 
 
 def _count_params(sql: str) -> int:

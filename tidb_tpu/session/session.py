@@ -129,8 +129,10 @@ class Session:
         # shutdown|error) for the slow log / summary / metrics
         self._scope = None
         self.last_termination = "ok"
-        self._pending_wire_read = None  # server-set COM_QUERY payload size
-        self._pending_admission_wait_ns = 0  # server-set queue wait
+        # stamps the wire server leaves for the next execute(): when it
+        # began to wait for the packet, for admission and for a pool
+        # thread (trace spans wire.read / admission.wait / server.handoff)
+        self._pending_envelope: Optional[dict] = None
         from collections import OrderedDict
 
         self._plan_cache: "OrderedDict" = OrderedDict()
@@ -139,6 +141,7 @@ class Session:
     # public API
     # ------------------------------------------------------------------
     def execute(self, sql: str, params: Optional[list] = None) -> List[ResultSet]:
+        env, self._pending_envelope = self._pending_envelope, None
         from . import bindinfo
 
         if bindinfo.is_binding_stmt(sql):
@@ -173,20 +176,8 @@ class Session:
         tr = token = None
         if not tracing_active() and self.vars.get_bool("tidb_enable_slow_log"):
             tr, token = start_trace(sql, self.conn_id)
-            wr = getattr(self, "_pending_wire_read", None)
-            if wr:
-                # (bytes, socket-wait ns) from the wire layer; the wait
-                # becomes an asyncio-level wire.read span so admission
-                # wait and network wait are distinguishable in traces
-                nb, wait_ns = wr if isinstance(wr, tuple) else (wr, 0)
-                tr.root.set(wire_read_bytes=nb)
-                if wait_ns:
-                    tr.add_span("wire.read", wait_ns, bytes=nb)
-                self._pending_wire_read = None
-            aw = getattr(self, "_pending_admission_wait_ns", 0)
-            if aw:
-                tr.add_span("admission.wait", aw)
-                self._pending_admission_wait_ns = 0
+            if env is not None:
+                self._open_envelope(tr, env)
         exc: Optional[BaseException] = None
         # activation happens IMMEDIATELY before the try whose finally
         # deactivates: an exception in the setup above must not leak the
@@ -232,22 +223,51 @@ class Session:
                     tr.root.set(termination=term)
                 self.last_trace = tr
                 totals = finish_trace(tr, token)
-                self._maybe_slow_log(tr, totals)
+                slow = self._maybe_slow_log(tr, totals)
                 self._observe_slo(sql, tr)
+                if env is not None:
+                    # the accounting above, from the root's end to here
+                    # (a served statement only: an in-process session's
+                    # tree ends with its root)
+                    end = tr.root.start_ns + tr.root.dur_ns
+                    tr.add_span("session.account",
+                                time.perf_counter_ns() - end,
+                                start_ns=end, slow=slow)
+
+    @staticmethod
+    def _open_envelope(tr, env: dict):
+        """What the wire server did before this trace's root opened, as
+        pre-timed spans at their true places (before the root's start):
+        the socket wait for the command packet, the admission queue, and
+        the hand-off from the loop thread to this pool thread, which ends
+        where the root starts (execute()'s first lines included, so that
+        nothing lies between the two)."""
+        nb = env["read_bytes"]
+        tr.root.set(wire_read_bytes=nb)
+        if env["read_ns"]:
+            tr.add_span("wire.read", env["read_ns"],
+                        start_ns=env["read_start_ns"], bytes=nb)
+        if env["admission_ns"]:
+            tr.add_span("admission.wait", env["admission_ns"],
+                        start_ns=env["admission_start_ns"],
+                        queued=env["queued"])
+        t0 = env["handoff_start_ns"]
+        tr.add_span("server.handoff", tr.root.start_ns - t0, start_ns=t0)
 
     def query(self, sql: str, params: Optional[list] = None) -> List[tuple]:
         """Convenience: rows of the last result set."""
         return self.execute(sql, params)[-1].rows
 
-    def _maybe_slow_log(self, tr, totals):
+    def _maybe_slow_log(self, tr, totals) -> bool:
         """Account a finished trace: phase aggregates always fold into
         the statement summary; the slow log gets an entry when the
-        statement crossed tidb_slow_log_threshold ms (0 logs all)."""
+        statement crossed tidb_slow_log_threshold ms (0 logs all).
+        Returns whether it did."""
         try:
             dur_ms = tr.duration_ms()
-            threshold = self.vars.get_int("tidb_slow_log_threshold", 300)
-            self.domain.record_trace(tr, totals, dur_ms,
-                                     slow=dur_ms >= threshold)
+            slow = dur_ms >= self.vars.get_int("tidb_slow_log_threshold", 300)
+            self.domain.record_trace(tr, totals, dur_ms, slow=slow)
+            return slow
         except Exception:
             # the slow log is advisory and must never fail the
             # statement — but silent breakage would disable the whole
@@ -255,6 +275,7 @@ class Session:
             from ..metrics import REGISTRY
 
             REGISTRY.inc("trace_accounting_errors_total")
+            return False
 
     def _observe_slo(self, sql: str, tr):
         """Per-statement-class end-to-end latency histogram + SLO

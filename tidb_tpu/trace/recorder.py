@@ -11,6 +11,17 @@ Phase attribution: span names beginning with a known phase prefix (see
 PHASES) aggregate into the per-phase totals the slow log, the statement
 summary and the /metrics histograms consume; byte counts ride in span
 attrs (`bytes=`), engine/rung attribution in `engine=` attrs.
+
+Pre-timed spans (`QueryTrace.add_span`) stand where their caller says
+the work began (`start_ns=`), so the server's envelope around the root
+(`wire.read`, `admission.wait`, `server.handoff` before it;
+`session.account`, `server.respond` and `wire.write` after it) lies
+OUTSIDE the root's interval: `rows()` / `to_dict()` render offsets
+before the root's start as negative numbers.  The three spans after the
+root are appended once `finish_trace` has run, so the slow log, the
+phase histograms and the export to a coordinator never see them; a
+holder of the finished QueryTrace (the ring, `Session.last_trace`, a
+chained export hook that keeps the object) does.
 """
 
 from __future__ import annotations
@@ -130,11 +141,16 @@ class QueryTrace:
             parent.children.append(s)
         return s
 
-    def add_span(self, name: str, dur_ns: int = 0, **attrs) -> Span:
-        """Append a pre-timed span under the root after the fact — the
-        wire layer records result write time onto the already-finished
-        trace (the statement ended before the rows hit the socket)."""
+    def add_span(self, name: str, dur_ns: int = 0,
+                 start_ns: Optional[int] = None, **attrs) -> Span:
+        """Append a pre-timed span under the root after the fact, at
+        `start_ns` on `perf_counter_ns` (the moment the work BEGAN; left
+        out, the moment of the append) — the wire layer records result
+        write time onto the already-finished trace (the statement ended
+        before the rows hit the socket)."""
         s = Span(name, self)
+        if start_ns is not None:
+            s.start_ns = start_ns
         s.dur_ns = dur_ns
         if attrs:
             s.set(**attrs)
@@ -278,10 +294,8 @@ PHASES = {
     "plan": "plan_ms",
     "copr.compile": "compile_ms",
     "copr.transfer": "transfer_ms",
-    # one fused XLA launch per mesh dispatch (whole-fragment fusion);
-    # the legacy name stays mapped for externally recorded traces
+    # one fused XLA launch per mesh dispatch (whole-fragment fusion)
     "copr.device.execute": "device_ms",
-    "copr.execute": "device_ms",
     "copr.readback": "readback_ms",
     "mpp.exchange": "exchange_ms",
     "txn.prewrite": "commit_ms",
