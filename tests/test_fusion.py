@@ -581,13 +581,7 @@ def test_mesh_agg_overflow_peels_agg_to_host_tail():
             return tuple((0, "") if x is None else (1, float(x))
                          for x in r)
 
-        got, want = sorted(got, key=key), sorted(want, key=key)
-        assert [r[:2] for r in got] == [r[:2] for r in want]
-        # the chunk count follows the estimator's timing, and with it the
-        # order in which the double partial sums are added: the last
-        # digit may differ
-        assert [r[2] for r in got] == pytest.approx(
-            [r[2] for r in want], rel=1e-12)
+        assert sorted(got, key=key) == sorted(want, key=key)
     finally:
         if prior is None:
             os.environ.pop("TIDB_TPU_AGG_OUT", None)

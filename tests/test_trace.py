@@ -770,34 +770,11 @@ def test_streamed_filter_finishing_is_spanned(env, case):
     assert sum(op[4] for op in ops) <= nxt[0].dur_ns / 1e6 + 1e-6
 
 
-def test_full_queue_wait_is_spanned():
-    """`SelectResult._put` opens distsql.put.wait only when the first try
-    found the queue full, and closes it when the chunk is taken."""
-    import queue
-    import threading
-
-    from tidb_tpu.distsql.select import SelectResult
-    from tidb_tpu.lifecycle import QueryScope
-
-    res = SelectResult.__new__(SelectResult)
-    res._chunks = queue.Queue(maxsize=1)
-    res._stop = threading.Event()
-    res._scope = QueryScope(None)
-    tr, token = trace_mod.start_trace("select 1")
-    try:
-        res._put("a")  # room: no span
-        assert not _spans_by_name(tr, "distsql.put.wait")
-        threading.Timer(0.08, res._chunks.get).start()
-        res._put("b")  # full until the consumer takes "a"
-    finally:
-        trace_mod.finish_trace(tr, token)
-    waits = _spans_by_name(tr, "distsql.put.wait")
-    assert len(waits) == 1 and waits[0].dur_ns >= int(0.05 * 1e9)
-    assert res._chunks.get_nowait() == "b"
-
-
 @pytest.mark.parametrize("kind", sorted(MESH) + ["root_selection"])
-def test_recorder_off_same_rows_nothing_recorded(env, kind):
+def test_recorder_off_same_rows_nothing_recorded(env, kind, monkeypatch):
+    # one chunk in both runs: the chunk count follows the estimator's
+    # timing, and the last digit of a double sum follows the chunk count
+    monkeypatch.setenv("TIDB_TPU_DISPATCH_CHUNK_ROWS", "0")
     d, s = env
     sql = MESH.get(kind) or STREAMED[kind][0]
     want = s.query(sql)

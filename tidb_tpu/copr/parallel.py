@@ -849,13 +849,10 @@ def _read_back(out) -> np.ndarray:
     the device plus the copy; its child `copr.device.wait` ends when the
     device has finished, so `copr.device.execute` + `copr.device.wait` is
     the device's part of a dispatch as the host sees it and the rest of
-    `copr.readback` is the copy.  The copy is asked for BEFORE the wait,
-    as a lone `np.asarray` asks for it, so that it follows the program on
-    the device with no host round trip in between."""
+    `copr.readback` is the copy."""
     from ..trace import span
 
     with span("copr.readback") as sp:
-        out.copy_to_host_async()
         with span("copr.device.wait"):
             out.block_until_ready()
         buf = np.asarray(out)
@@ -895,7 +892,8 @@ def _packed_jit(fn, mesh: Mesh, name: Optional[str] = None, merge=None):
     in f64, and no 64-bit bitcast-convert is needed (assumed unsafe under
     the TPU's x64 emulation; not re-tested on the attached chip).
 
-    `name` names the jitted callable (see `_program_name`); `merge`, where
+    `name` names the jitted callable (see `_program_name`; the MPP callers
+    give none and stay `jit_packed` in the device trace); `merge`, where
     given, is applied to the unpacked pytree inside the `copr.unpack` span
     (the caller's per-shard merge is part of getting from the packed
     buffer to what the caller receives).
@@ -1058,8 +1056,8 @@ def _build_mesh_core(an: _Analyzed, kind: str, col_order: List[int],
 
 
 def _build_mesh_fn(an: _Analyzed, kind: str, col_order: List[int],
-                   mesh: Mesh, tiles_per_shard: int, hoisted: bool = False,
-                   col_layout=None, name: Optional[str] = None):
+                   mesh: Mesh, tiles_per_shard: int, name: str,
+                   hoisted: bool = False, col_layout=None):
     """One jitted shard_map program over the whole fragment.
 
     Inputs: datas [n_pad, TILE] x cols (cold columns: [n_pad,
@@ -1126,8 +1124,7 @@ def _build_mesh_fn(an: _Analyzed, kind: str, col_order: List[int],
     def packed_mask(*a):
         return jnp.packbits(core(*a).astype(jnp.uint8))
 
-    if name:
-        packed_mask.__name__ = packed_mask.__qualname__ = name
+    packed_mask.__name__ = packed_mask.__qualname__ = name
     jitted = jax.jit(packed_mask, out_shardings=_readback_sharding(mesh))
 
     def wrapped(datas, valids, del_mask, bounds, lvals=(), pargs=()):
@@ -1279,7 +1276,7 @@ def _build_sort_agg_core(an: _Analyzed, col_order: List[int], mesh: Mesh,
 
 
 def _wrap_sort_agg(an: _Analyzed, core, mesh: Mesh, S: int,
-                   n_local: int, name: Optional[str] = None):
+                   n_local: int, name: str):
     import os as _os
 
     OUT = min(int(_os.environ.get("TIDB_TPU_AGG_OUT", 1 << 17)), n_local)
@@ -1804,9 +1801,9 @@ def _run_mesh_once(storage, req: CopRequest, tid: int,
     fn = _COMPILED.get(fp)
     if fn is None:
         fn = _build_mesh_fn(an, kind, col_order, mesh, Tl,
+                            _program_name(kind, fp),
                             hoisted=hoisted is not None,
-                            col_layout=col_layout,
-                            name=_program_name(kind, fp))
+                            col_layout=col_layout)
         _COMPILED.put(fp, fn)
         # label this query's FIRST dispatch as the compile: jit compiles
         # lazily, so the program-cache miss pays XLA compilation there
@@ -2034,10 +2031,6 @@ def _stream_filter(req, table, an, fn, datas, valids, del_mask, inserted,
             # between two slices is the queue's, not this thread's work)
             with span("copr.select", rows_in=int(mask.size)) as sp:
                 handles = np.flatnonzero(mask)
-                # done with the mask: give its byte a row back here, before
-                # the gathers, not when the next pass's mask replaces it
-                # (inside that pass's copr.chunk, 7 ms at 67 M rows)
-                del mask
                 if remaining is not None:
                     handles = handles[:remaining]
                     remaining -= len(handles)

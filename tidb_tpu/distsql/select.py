@@ -131,34 +131,19 @@ class SelectResult:
         self._thread.start()
 
     # ---- producer side -------------------------------------------------
-    def _check_producing(self):
-        if self._stop.is_set():
-            raise _Closed()
-        # a cancelled statement stops producing; the error surfaces to
-        # the consumer via _finish_error (the producer catches it)
-        self._scope.check()
-
     def _put(self, item):
-        """Bounded put that never deadlocks a closed result.  A queue
-        found full at the first try is the consumer's pace, not this
-        thread's work: the wait until the chunk is taken is the trace's
-        `distsql.put.wait` span."""
-        from ..trace import span
-
-        self._check_producing()
-        try:
-            self._chunks.put_nowait(item)
-            return
-        except queue.Full:
-            pass
-        with span("distsql.put.wait"):
-            while True:
-                self._check_producing()
-                try:
-                    self._chunks.put(item, timeout=0.05)
-                    return
-                except queue.Full:
-                    continue
+        """Bounded put that never deadlocks a closed result."""
+        while True:
+            if self._stop.is_set():
+                raise _Closed()
+            # a cancelled statement stops producing; the error surfaces
+            # to the consumer via _finish_error (the producer catches it)
+            self._scope.check()
+            try:
+                self._chunks.put(item, timeout=0.05)
+                return
+            except queue.Full:
+                continue
 
     def _run_task(self, clip: KeyRange) -> List[Chunk]:
         """One region's cop task: retry transient errors with typed backoff;
