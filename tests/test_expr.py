@@ -203,3 +203,85 @@ def test_pushdown_registry():
     assert not can_push_expr(eq)
     assert can_push_expr(eq, dict_cols={0})
     assert not can_push_expr(e, blacklist={"+"})
+
+
+def _fold(sql_expr):
+    """(unfolded, folded) planner expression of one constant SQL expression."""
+    from tidb_tpu.parser import parse
+    from tidb_tpu.planner.columns import Schema
+    from tidb_tpu.planner.expr_build import ExprBuilder, fold_constant
+
+    ast_expr = parse("select " + sql_expr)[0].fields[0].expr
+    raw = ExprBuilder(Schema([]), fold_constants=False).build(ast_expr)
+    return raw, fold_constant(raw)
+
+
+@pytest.mark.parametrize("sql_expr, value, precision, scale", [
+    ("0.06 - 0.01", 5, 2, 2),
+    ("0.09 + 0.01", 10, 2, 2),
+    ("0.02 - 0.01", 1, 2, 2),
+    ("0.01 - 0.01", 0, 2, 2),
+    ("0.5 * 0.5", 25, 2, 2),
+    ("1 / 4", 2500, 4, 4),
+    ("0.02 - 0.05", -3, 2, 2),
+    ("-(1234.5 * 3)", -37035, 5, 1),
+    ("999999999999999.5 + 0.5", 10 ** 16, 17, 1),
+])
+def test_fold_constant_types_decimal_by_value(sql_expr, value, precision, scale):
+    """A folded decimal constant is as wide as its value, not as the
+    operator's worst case (DECIMAL(38, s), which no device program takes)."""
+    from tidb_tpu.expr.pushdown import can_push_expr
+
+    raw, c = _fold(sql_expr)
+    assert raw.ftype.kind == TypeKind.DECIMAL and raw.ftype.precision == 38
+    assert isinstance(c, Constant) and c.value == value
+    assert c.ftype.kind == TypeKind.DECIMAL
+    assert (c.ftype.precision, c.ftype.scale) == (precision, scale)
+    assert c.ftype.scale == raw.ftype.scale
+    assert c.ftype.nullable == raw.ftype.nullable
+    assert not c.ftype.is_wide_decimal and can_push_expr(c)
+
+
+@pytest.mark.parametrize("sql_expr, value", [
+    ("999999999999999999.5 + 999999999999999999.5", 19999999999999999990),
+    ("9999999999999999999 + 1.5", 100000000000000000005),
+    ("null + 0.5", None),
+    ("1.0 / 0", None),
+])
+def test_fold_constant_keeps_wide_and_null_types(sql_expr, value):
+    """19 digits and more stay on the exact host path; NULL has no digits."""
+    raw, c = _fold(sql_expr)
+    assert isinstance(c, Constant) and c.value == value
+    assert c.ftype == raw.ftype and c.ftype.is_wide_decimal
+
+
+def test_fold_constant_keeps_declared_narrow_type():
+    raw, c = _fold("cast(1 as decimal(10,2))")
+    assert c.value == 100 and c.ftype == raw.ftype
+    assert (c.ftype.precision, c.ftype.scale) == (10, 2)
+
+
+@pytest.mark.parametrize("name, const, column, pushable", [
+    # 0.06 - 0.01 beside a scale-4 column: 5 -> 500, fits
+    ("<", "0.06 - 0.01", ty_decimal(12, 4), True),
+    ("<", "999999999999999.5 + 0.5", ty_decimal(12, 1), True),
+    # 17 digits at scale 1, raised to scale 4: 10**16 * 1000 passes int64
+    ("<", "999999999999999.5 + 0.5", ty_decimal(12, 4), False),
+    ("in", "999999999999999.5 + 0.5", ty_decimal(12, 4), False),
+    ("+", "999999999999999.5 + 0.5", ty_decimal(12, 4), False),
+    ("coalesce", "999999999999999.5 + 0.5", ty_decimal(12, 4), False),
+    # a literal is typed by its digits too, and meets the same gate
+    ("<", "9999999999999999.5", ty_decimal(12, 4), False),
+])
+def test_pushdown_refuses_constant_raised_past_int64(name, const, column,
+                                                     pushable):
+    """The device raises a decimal operand to the finest scale beside it
+    with a plain int64 multiply; a constant that would wrap there stays on
+    the exact host path, whole expression with it."""
+    from tidb_tpu.expr.pushdown import can_push_expr
+
+    _, c = _fold(const)
+    assert can_push_expr(c)
+    e = fn(name, col(0, column), c)
+    assert can_push_expr(e) is pushable
+    assert can_push_expr(fn("not", fn("isnull", e))) is pushable

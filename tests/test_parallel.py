@@ -367,3 +367,44 @@ def test_filter_results_stream_in_bounded_chunks():
         assert after - before <= 2
     finally:
         pp.STREAM_ROWS = orig
+
+
+@pytest.fixture(scope="module")
+def sess4(sess):
+    """A scale-4 decimal column beside `t`, base rows on the mesh."""
+    d = sess.domain
+    sess.execute("create table t4 (k bigint primary key, c decimal(12,4))")
+    t = d.catalog.info_schema().table("test", "t4")
+    n = 5_000
+    d.storage.table(t.id).bulk_load_arrays(
+        [np.arange(n, dtype=np.int64),
+         np.arange(n, dtype=np.int64) * 10_000 + 1234],  # k.1234
+        ts=d.storage.current_ts())
+    sess.execute("analyze table t4")
+    return sess
+
+
+@pytest.mark.parametrize("where, on_device, rows", [
+    # folded 0.25 at scale 2, raised to the column's scale 4 on the device
+    ("c >= 0.5 * 0.5", True, 4_999),
+    ("c between 0.5 * 0.5 and 100.5 + 0.5", True, 100),
+    # 10**16 at scale 1 (17 digits): * 1000 would wrap int64 on the device
+    # and match no row; the conjunct keeps the exact host path instead
+    ("c < 999999999999999.5 + 0.5", False, 5_000),
+    ("c between 0.5 * 0.5 and 999999999999999.5 + 0.5", False, 4_999),
+    ("c + (999999999999999.5 + 0.5) > 0", False, 5_000),
+    ("c in (999999999999999.5 + 0.5, 5.1234)", False, 1),
+    ("c < 9999999999999999.5", False, 5_000),
+])
+def test_folded_decimal_bound_beside_finer_column(sess4, where, on_device,
+                                                  rows):
+    sql = "select count(*), sum(c) from t4 where " + where
+    sess4.execute("set tidb_use_tpu = 1")
+    plan = sess4.execute("explain " + sql)[0].rows
+    cop = [r[0] for r in plan if r[2] == "cop[tpu]"]
+    assert any("Selection" in op for op in cop) is on_device, plan
+    assert any("Aggregation" in op for op in cop) is on_device, plan
+    before = _mesh_count()
+    got = _parity(sess4, sql)
+    assert _mesh_count() > before
+    assert got[0][0] == rows

@@ -9,6 +9,11 @@ the parity suite and the fused-fraction receipt can never drift apart).
 Reference: cmd/explaintest/t/tpch.test (golden TPC-H plans).
 """
 
+import json
+import pathlib
+import re
+from decimal import Decimal
+
 import pytest
 
 from tidb_tpu.tpch_data import TPCH_QUERIES, build_tpch_domain
@@ -55,6 +60,53 @@ def test_q1_plan_pushes_agg(sess):
     cop = [r for r in rows if r[2] == "cop[tpu]"]
     assert any("Aggregation" in r[0] for r in cop)
     assert any("Selection" in r[0] for r in cop)
+
+
+def _q6_spec_cases():
+    """Q6 as the specification writes it (`D - 0.01 and D + 0.01`), the text
+    and the parameter tuples the benchmark sends, each beside the same
+    statement with the two bounds written out as literals."""
+    q = json.loads((pathlib.Path(__file__).resolve().parents[1]
+                    / "benchmarks" / "queries" / "q6.json").read_text())
+    assert "{discount} - 0.01" in q["sql"] and "{discount} + 0.01" in q["sql"]
+    cases = []
+    for p in q["params"]:
+        d = Decimal(p["discount"])
+        lit = (q["sql"].replace("{discount} - 0.01", str(d - Decimal("0.01")))
+               .replace("{discount} + 0.01", str(d + Decimal("0.01"))))
+        cases.append(pytest.param(q["sql"].format(**p), lit.format(**p),
+                                  id=p["date"]))
+    return cases
+
+
+@pytest.mark.parametrize("spec, literal", _q6_spec_cases())
+def test_q6_as_specified_runs_on_the_device(sess, spec, literal):
+    """The folded `0.06 - 0.01` is as narrow as its value, so the discount
+    conjunct and with it the aggregate are pushed down: the plan is the
+    literal text's, and the answer the literal text's and the oracle's."""
+
+    def plan(sql):
+        rows = sess.execute("explain " + sql)[0].rows
+        return [(re.sub(r"_\d+$", "", r[0]), r[2], r[3]) for r in rows]
+
+    sess.execute("set tidb_use_tpu = 1")
+    rows = plan(spec)
+    assert rows == plan(literal)
+    assert not [r for r in rows if r[1] == "root" and "Selection" in r[0]]
+    cop = {r[0].split("─")[-1]: r[2] for r in rows if r[1] == "cop[tpu]"}
+    assert "partial" in cop["Aggregation"], rows
+    assert cop["Selection"].count("l_shipdate") == 2, rows
+    assert "l_discount >=" in cop["Selection"], rows
+    assert "l_discount <=" in cop["Selection"], rows
+    assert "l_quantity <" in cop["Selection"], rows
+    tpu = sess.query(spec)
+    assert tpu[0][0] is not None
+    assert tpu == sess.query(literal)
+    sess.execute("set tidb_use_tpu = 0")
+    try:
+        assert tpu == sess.query(spec) == sess.query(literal)
+    finally:
+        sess.execute("set tidb_use_tpu = 1")
 
 
 def test_explain_analyze_names_engine(sess):

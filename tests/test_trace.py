@@ -723,9 +723,11 @@ def test_program_names_follow_the_fingerprint():
 
 STREAMED = {
     "plain": ("select l_orderkey, l_qty from li where l_qty < 9", None),
-    # the planner keeps unfolded decimal arithmetic at the root (Q6's shape)
+    # a folded constant of 19 digits or more is a wide decimal: host-only,
+    # so its conjunct stays at the root (what Q6's shape did before PR 30)
     "root_selection": ("select l_orderkey from li where l_qty < 30 and l_price"
-                       " between 500.5 - 100.25 and 500.5 + 100.25", None),
+                       " between 500.5 - 100.25 and"
+                       " 10000000000000000000.5 + 100.25", None),
     "limit": ("select l_orderkey from li where l_qty < 30 limit 17", 17),
 }
 

@@ -13,7 +13,7 @@ from __future__ import annotations
 
 from typing import Iterable, Set
 
-from ..types import TypeKind
+from ..types import DECIMAL_INT64_DIGITS, TypeKind
 from .aggregation import AggDesc
 from .expression import ColumnExpr, Constant, Expression, ScalarFunc
 
@@ -79,6 +79,19 @@ DEVICE_KINDS = {
 }
 
 
+def _consts_fit_beside(e: ScalarFunc) -> bool:
+    """The device raises a decimal operand to the finest scale beside it
+    (or its function's own) by a plain int64 multiply.  A decimal Constant's
+    precision counts its value's digits (a literal's, a folded constant's),
+    so one that the multiply would carry past int64 is known here, and the
+    expression keeps the exact host path."""
+    fts = [a.ftype for a in e.args] + [e.ftype]
+    s = max((ft.scale for ft in fts if ft.kind == TypeKind.DECIMAL), default=0)
+    return all(a.ftype.precision + s - a.ftype.scale <= DECIMAL_INT64_DIGITS
+               for a in e.args
+               if isinstance(a, Constant) and a.ftype.kind == TypeKind.DECIMAL)
+
+
 def can_push_expr(e: Expression, blacklist: Set[str] = frozenset(),
                   dict_cols: Set[int] = frozenset()) -> bool:
     """True if the whole expression tree can run on the device.
@@ -128,6 +141,8 @@ def can_push_expr(e: Expression, blacklist: Set[str] = frozenset(),
                     return False
                 return True
         elif any(a.ftype.kind == TypeKind.STRING for a in e.args):
+            return False
+        if not _consts_fit_beside(e):
             return False
         return all(can_push_expr(a, blacklist, dict_cols) for a in e.args)
     return False

@@ -18,6 +18,7 @@ from ..expr.builtins import REGISTRY, infer_ftype
 from ..expr.expression import ColumnExpr, Constant, Expression, ScalarFunc
 from ..parser import ast
 from ..types import (
+    DECIMAL_INT64_DIGITS,
     FieldType,
     TypeKind,
     ty_bool,
@@ -415,8 +416,21 @@ def fold_constant(e: Expression) -> Expression:
                 x = x.item()
             # NOTE: DECIMAL constants store the scaled-int representation,
             # matching Column.constant / the cop IR wire format.
-            return Constant(x, e.ftype)
+            return Constant(x, _folded_type(x, e.ftype))
     return e
+
+
+def _folded_type(x, ft: FieldType) -> FieldType:
+    """Type of a folded constant.  Decimal arithmetic is inferred at its
+    worst case, DECIMAL(38, s), which is host-only; a folded value whose
+    digits fit int64 is typed by its digits, as literal_to_constant types a
+    literal, so the constant can be pushed down.  The scale never changes."""
+    if not ft.is_wide_decimal or not isinstance(x, int):
+        return ft
+    prec = max(len(str(abs(x))), ft.scale)
+    if prec > DECIMAL_INT64_DIGITS:
+        return ft
+    return ty_decimal(prec, ft.scale, ft.nullable)
 
 
 def _bare_word(node, default: str) -> str:
