@@ -408,3 +408,167 @@ def test_folded_decimal_bound_beside_finer_column(sess4, where, on_device,
     got = _parity(sess4, sql)
     assert _mesh_count() > before
     assert got[0][0] == rows
+
+
+# ---------------------------------------------------------------------------
+# the operands of a dispatch reach the device inside the call (ISSUE 33)
+# ---------------------------------------------------------------------------
+
+def _is_host(a, dtype):
+    return isinstance(a, np.ndarray) and a.dtype == dtype
+
+
+@pytest.mark.parametrize("bounds, scalars", [
+    ([], ()), ([(5, 9)], ()), ([(3, 1 << 62), (7, 9)], (4, 1 << 62)),
+    ([(0, 1), (2, 3), (4, 5), (6, 1 << 62)], (11,)),
+])
+def test_operand_vector_is_one_host_int64_array(bounds, scalars):
+    """Building the operand vector starts no device work (a `jnp.int64(x)`
+    is a transfer and a program of its own), pads the range slots with
+    empty ranges, keeps 2^62 exact, and traces as non-weak int64."""
+    from tidb_tpu.copr import parallel as pl
+
+    with jax.transfer_guard_host_to_device("disallow"):
+        out = pl._bounds_args(bounds, scalars)
+    slots = 2 * pl.MESH_RANGE_SLOTS
+    assert _is_host(out, np.int64) and out.shape == (slots + len(scalars),)
+    flat = [x for lohi in bounds for x in lohi]
+    assert out.tolist() == (flat + [0] * (slots - len(flat))
+                            + list(scalars))
+    aval = jax.typeof(out)
+    assert aval.dtype == np.int64 and not aval.weak_type
+
+
+def test_call_args_hands_over_host_operands_without_device_work():
+    from tidb_tpu.copr import parallel as pl
+    from tidb_tpu.trace import recorder
+
+    col = jax.numpy.zeros((8, 4), dtype=np.int32)
+    keys = jax.numpy.arange(16)
+    pf = np.array([0.5], np.float64)
+    tr, token = recorder.start_trace("q")
+    try:
+        with jax.transfer_guard_host_to_device("disallow"):
+            args = pl._call_args([col], [None], col, [(1, 5)], (),
+                                 (keys, pf), np.array([3, 7, 1 << 62]))
+    finally:
+        recorder.finish_trace(tr, token)
+    assert args[0] == (col,) and args[2] is col and args[4] == ()
+    assert args[5] is keys and args[6] is pf and len(args) == 7
+    assert _is_host(args[3], np.int64)
+    assert args[3].tolist() == [1, 5, 0, 0, 0, 0, 0, 0, 3, 7, 1 << 62]
+    (sp,) = [s for s in tr.root.children if s.name == "copr.args"]
+    # the operand vector and the float parameters; the resident key set
+    # is not counted
+    assert sp.attrs == {"operands": 2, "bytes": 11 * 8 + 8}
+
+
+_OPERAND_SQL = {
+    "dense_agg": "select g, count(*), sum(x), min(d), max(x) from t"
+                 " where x < 61.5 and k >= 100 group by g",
+    "sort_agg": "select x, count(*), sum(d) from t where d > 2.5"
+                " group by x",
+    "topn": "select k, x from t where x > 3.25 order by x desc limit 7",
+    "filter": "select k, d from t where x < 2.5 and d > 10",
+}
+_OPERAND_RANGES = {
+    1: [(0, 20_000)],
+    2: [(100, 6_000), (11_000, 19_999)],
+    4: [(0, 3_000), (5_000, 5_001), (7_000, 12_345), (15_000, 1 << 62)],
+}
+
+
+def _run_over_ranges(sess, sql, ranges, use_tpu):
+    """The statement's rows with its one table reader held to `ranges`
+    (SQL alone only ever asks for the whole table)."""
+    from tidb_tpu.executor import collect_all
+    from tidb_tpu.parser import parse_one
+    from tidb_tpu.planner.physical import PhysTableReader
+    from tidb_tpu.store.kv import KeyRange
+
+    sess.execute(f"set tidb_use_tpu = {use_tpu}")
+    phys = sess._plan(parse_one(sql))
+    readers, todo = [], [phys]
+    while todo:
+        p = todo.pop()
+        todo.extend(p.children)
+        if isinstance(p, PhysTableReader):
+            readers.append(p)
+    (reader,) = readers
+    whole = reader.ranges
+    reader.ranges = [KeyRange(whole[0].table_id, a, b) for a, b in ranges]
+    try:
+        chunks = collect_all(phys.build(sess._exec_ctx()))
+    finally:
+        reader.ranges = whole
+    return [r for c in chunks for r in c.to_pylist()]
+
+
+@pytest.mark.parametrize("hoisted", [True, False],
+                         ids=["hoisted", "literal"])
+@pytest.mark.parametrize("n_ranges, chunk_rows", [
+    (1, "0"), (2, "0"), (4, "0"), (4, "2048")],  # "0": one dispatch
+    ids=["1range", "2ranges", "4ranges", "4ranges-chunked"])
+@pytest.mark.parametrize("kind", sorted(_OPERAND_SQL))
+def test_mesh_dispatch_over_ranges_equals_the_oracle(
+        sess, monkeypatch, kind, n_ranges, chunk_rows, hoisted):
+    """Every kind of mesh program, handed its range slots and parameter
+    vectors as host values, returns what the oracle engine returns."""
+    from tidb_tpu import serving
+    from tidb_tpu.copr import parallel as pl
+    from tidb_tpu.trace import recorder
+
+    calls, sorted_aggs = [], []
+    real, real_sort = pl._call_args, pl._sort_agg_chunks
+
+    def spy(*a):
+        with jax.transfer_guard_host_to_device("disallow"):
+            calls.append(real(*a))
+        return calls[-1]
+
+    def sort_spy(*a):
+        sorted_aggs.append(1)
+        return real_sort(*a)
+
+    monkeypatch.setattr(pl, "_call_args", spy)
+    monkeypatch.setattr(pl, "_sort_agg_chunks", sort_spy)
+    monkeypatch.setenv("TIDB_TPU_DISPATCH_CHUNK_ROWS", chunk_rows)
+    sql, ranges = _OPERAND_SQL[kind], _OPERAND_RANGES[n_ranges]
+    serving.configure(shape_buckets=hoisted)
+    tr, token = recorder.start_trace(sql)
+    try:
+        got = _run_over_ranges(sess, sql, ranges, 1)
+    finally:
+        recorder.finish_trace(tr, token)
+        serving.configure(shape_buckets=True)
+    assert calls, "not on the mesh path"
+    assert (len(calls) == 1) is (chunk_rows == "0")
+    passes, todo = [], [tr.root]
+    while todo:
+        sp = todo.pop()
+        todo.extend(sp.children)
+        if sp.name == "copr.chunk":
+            passes.append(sp)
+    assert len(passes) == len(calls)
+    assert {sp.attrs["kind"] for sp in passes} == {
+        "agg" if kind.endswith("agg") else kind}
+    assert bool(sorted_aggs) is (kind == "sort_agg")
+    n_calls = len(calls)
+    want = _run_over_ranges(sess, sql, ranges, 0)
+    assert len(calls) == n_calls, "the oracle dispatched to the mesh"
+    if kind != "topn":  # the aggregates' and the filter's rows are a set
+        got, want = sorted(got, key=repr), sorted(want, key=repr)
+    assert len(got) == len(want) > 0
+    for ra, rb in zip(got, want):
+        assert all(_approx_eq(x, y) for x, y in zip(ra, rb)), (ra, rb)
+    for args in calls:
+        # one int64 vector (range slots, then the hoisted int64
+        # parameters) and at most one float64 parameter vector
+        assert _is_host(args[3], np.int64) and len(args) <= 6
+        assert all(_is_host(pf, np.float64) and pf.size for pf in args[5:])
+        assert (len(args[3]) > 8 or len(args) > 5) is hoisted
+    if chunk_rows == "0":
+        base = 20_000
+        assert calls[0][3][:8].tolist() == (
+            [min(x, base) for lohi in ranges for x in lohi]
+            + [0] * (8 - 2 * len(ranges)))

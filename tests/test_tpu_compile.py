@@ -126,20 +126,25 @@ def _fragment_args(sess, table, sql, mesh, n_tiles, build_pad=16):
         valids.append(None)
     pargs = []
     for lk in an.lookups:
-        pargs += [sds((build_pad,), np.int64), sds((), np.int64)]
+        pargs.append(sds((build_pad,), np.int64))
         for ft in lk.payload_ftypes:
             pargs += [sds((build_pad,), par._full_dtype(ft.kind)),
                       sds((build_pad,), np.bool_)]
+    # the operand vector as _run_mesh_once fills it: range slots, one key
+    # count a lookup, the hoisted int64 parameters; a non-empty float64
+    # parameter vector is the last parg
+    scalars = [0] * len(an.lookups)
     if hoisted is not None:
-        pargs += [sds(hoisted[0].shape, np.int64),
-                  sds(hoisted[1].shape, np.float64)]
-    core = par._build_mesh_core(an, kind, col_order, mesh,
-                                tiles_per_shard=n_tiles // S,
-                                hoisted=hoisted is not None)
+        scalars += list(hoisted[0])
+        if len(hoisted[1]):
+            pargs.append(sds(hoisted[1].shape, np.float64))
+    ints = par._bounds_args([], scalars)
+    core = par._build_mesh_core(
+        an, kind, col_order, mesh, tiles_per_shard=n_tiles // S,
+        hoisted=hoisted and (len(hoisted[0]), len(hoisted[1])))
     args = (tuple(datas), tuple(valids),
             sds((n_tiles, PROD_TILE), np.bool_, sharded),
-            tuple(sds((), np.int64) for _ in range(2 * par.MESH_RANGE_SLOTS)),
-            ()) + tuple(pargs)
+            sds(ints.shape, ints.dtype), ()) + tuple(pargs)
     return core, args
 
 

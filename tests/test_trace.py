@@ -686,7 +686,20 @@ CHUNK_CHILDREN = ["copr.dispatch.wait", "copr.args", "copr.device.execute",
 
 
 @pytest.mark.parametrize("kind", sorted(MESH))
-def test_every_mesh_dispatch_is_divided(env, kind):
+def test_every_mesh_dispatch_is_divided(env, kind, monkeypatch):
+    import jax
+
+    from tidb_tpu.copr import parallel as pl
+
+    real = pl._call_args
+
+    def no_device_work(*a):
+        # a jnp scalar made in here would be a transfer and a
+        # jit_convert_element_type of its own among the device programs
+        with jax.transfer_guard_host_to_device("disallow"):
+            return real(*a)
+
+    monkeypatch.setattr(pl, "_call_args", no_device_work)
     d, s = env
     s.query(MESH[kind])  # the first dispatch is labelled copr.compile
     s.query(MESH[kind])
@@ -700,6 +713,10 @@ def test_every_mesh_dispatch_is_divided(env, kind):
         assert _end(c.children[-1]) <= _end(c)
         assert sum(k.dur_ns for k in c.children) <= c.dur_ns
         by = {k.name: k for k in c.children}
+        # the operand vector (eight range slots at least, eight bytes
+        # each) and at most a float64 parameter vector beside it
+        assert by["copr.args"].attrs["operands"] in (1, 2)
+        assert by["copr.args"].attrs["bytes"] >= 64
         assert by["copr.device.execute"].attrs["program"].startswith(
             f"mesh_{kind}_")
         rb = by["copr.readback"]

@@ -840,12 +840,7 @@ def trace_fused_fragment(table, dag, n_ranges: int = 1, cold: bool = False,
                                 tiles_per_shard=1,
                                 col_layout=col_layout if cold else None)
     del_mask = np.ones((1, tile), dtype=np.bool_)
-    bounds = []
-    for r in range(par.MESH_RANGE_SLOTS):
-        if r < n_ranges:
-            bounds += [np.int64(r * 8), np.int64(r * 8 + 8)]
-        else:
-            bounds += [np.int64(0), np.int64(0)]
+    bounds = par._bounds_args(
+        [(r * 8, r * 8 + 8) for r in range(n_ranges)])
     return jax.make_jaxpr(core)(
-        tuple(datas), tuple(valids), del_mask, tuple(bounds),
-        tuple(lvals))
+        tuple(datas), tuple(valids), del_mask, bounds, tuple(lvals))

@@ -702,13 +702,23 @@ def _cols_env(an: _Analyzed, col_order: List[int], datas, valids,
     return env
 
 
-def _split_hoisted(pargs, hoisted: bool):
-    """Peel the trailing (pi, pf) parameter vectors off the variadic parg
-    tail when predicate constants were hoisted; probes/lookups keep
-    reading their positional prefix unchanged."""
-    if not hoisted:
-        return pargs, None
-    return pargs[:-2], (pargs[-2], pargs[-1])
+def _split_operands(an: _Analyzed, ints, pargs, hoisted):
+    """The shard program's reading of its int64 operand vector
+    (`_bounds_args`): past the range slots stand the key counts of
+    `an.probes` then `an.lookups`, then the hoisted int64 parameters.
+    `hoisted` is the (pi, pf) lengths, or None when no predicate
+    constant was hoisted; a non-empty pf is the last of `pargs`.
+    Returns (key counts, pargs without pf, the (pi, pf) params or
+    None)."""
+    off = 2 * MESH_RANGE_SLOTS
+    n_keys = len(an.probes) + len(an.lookups)
+    counts = ints[off: off + n_keys]
+    if hoisted is None:
+        return counts, pargs, None
+    pi = ints[off + n_keys:]
+    if hoisted[1]:
+        return counts, pargs[:-1], (pi, pargs[-1])
+    return counts, pargs, (pi, jnp.zeros(0, dtype=jnp.float64))
 
 
 from .cache import ProgramCache  # noqa: E402
@@ -743,20 +753,28 @@ STREAM_ROWS = 1 << 16
 MESH_RANGE_SLOTS = 4
 
 
-def _bounds_args(bounds):
-    """[(lo, hi), ...] -> the 2*MESH_RANGE_SLOTS runtime scalars the
-    fused program's range mask reads (pad slots are empty ranges)."""
-    out = []
-    for r in range(MESH_RANGE_SLOTS):
-        lo, hi = bounds[r] if r < len(bounds) else (0, 0)
-        out.append(jnp.int64(lo))
-        out.append(jnp.int64(hi))
-    return tuple(out)
+def _bounds_args(bounds, scalars=()):
+    """[(lo, hi), ...] -> the int64 operand vector of a fused program:
+    the 2*MESH_RANGE_SLOTS runtime scalars its range mask reads (pad
+    slots are empty ranges), then `scalars`, the call's other int64
+    operands (`_split_operands` says which).
+
+    ONE host array, because each host operand of the jitted call is a
+    transfer of its own, some 0.2 ms on the chip whatever its size; and
+    host, because a `jnp.int64(x)` made before the call is a transfer
+    and a device program (`jit_convert_element_type`) per scalar."""
+    ints = np.zeros(2 * MESH_RANGE_SLOTS + len(scalars), dtype=np.int64)
+    for r, (lo, hi) in enumerate(bounds):
+        ints[2 * r], ints[2 * r + 1] = lo, hi
+    ints[2 * MESH_RANGE_SLOTS:] = scalars
+    return ints
 
 
 def _mesh_masks(del_mask, bounds, n_local: int):
     """(global row offsets, live-row mask) for one shard: the union of
-    every range slot's [lo, hi) clip, ANDed with the deletion mask."""
+    every range slot's [lo, hi) clip, ANDed with the deletion mask.
+    `bounds` is the operand vector of `_bounds_args`; only its range
+    slots are read here."""
     shard = jax.lax.axis_index("dp").astype(jnp.int64)
     gofs = shard * n_local + jnp.arange(n_local, dtype=jnp.int64)
     m = jnp.zeros(n_local, dtype=jnp.bool_)
@@ -781,7 +799,7 @@ def _key_device(d):
     return d.astype(jnp.int64)
 
 
-def _apply_probes(an: _Analyzed, cols, m, pargs, n_local: int):
+def _apply_probes(an: _Analyzed, cols, m, pargs, counts, n_local: int):
     """AND the runtime join-filter membership tests into the row mask:
     sorted build keys broadcast to every shard, searchsorted probe.
     Then run the broadcast lookup JOINS: drop misses and extend the
@@ -789,18 +807,18 @@ def _apply_probes(an: _Analyzed, cols, m, pargs, n_local: int):
     completes ON DEVICE, inside the same shard program as the scan and
     the partial aggregation."""
     for i, p in enumerate(an.probes):
-        keys, kn = pargs[2 * i], pargs[2 * i + 1]
+        keys, kn = pargs[i], counts[i]
         d, v = compile_expr(p.key, cols, n_local)
         k = _key_device(d)
         pos = jnp.searchsorted(keys, k)
         pos_c = jnp.clip(pos, 0, keys.shape[0] - 1)
         hit = (pos < kn) & (keys[pos_c] == k)
         m = m & v & hit
-    off = 2 * len(an.probes)
+    off = len(an.probes)
     out_idx = len(an.scan.columns)
-    for lk in an.lookups:
-        keys, kn = pargs[off], pargs[off + 1]
-        off += 2
+    for j, lk in enumerate(an.lookups):
+        keys, kn = pargs[off], counts[len(an.probes) + j]
+        off += 1
         d, v = compile_expr(lk.key, cols, n_local)
         k = d.astype(jnp.int64)
         pos = jnp.searchsorted(keys, k)
@@ -817,12 +835,12 @@ def _apply_probes(an: _Analyzed, cols, m, pargs, n_local: int):
     return m
 
 
-def _probe_specs(an: _Analyzed, hoisted: bool = False):
-    specs = [P(), P()] * len(an.probes)
+def _probe_specs(an: _Analyzed, hoisted=None):
+    specs = [P()] * len(an.probes)
     for lk in an.lookups:
-        specs += [P(), P()] + [P(), P()] * len(lk.payload_ftypes)
-    if hoisted:
-        specs += [P(), P()]  # replicated (pi, pf) parameter vectors
+        specs += [P()] + [P(), P()] * len(lk.payload_ftypes)
+    if hoisted is not None and hoisted[1]:
+        specs += [P()]  # the replicated pf parameter vector
     return tuple(specs)
 
 
@@ -860,14 +878,25 @@ def _read_back(out) -> np.ndarray:
     return buf
 
 
-def _call_args(datas, valids, del_mask, bounds, lvals, pargs) -> tuple:
+def _call_args(datas, valids, del_mask, bounds, lvals=(), pargs=(),
+               scalars=()) -> tuple:
     """The runtime operands of one mesh dispatch (`copr.args`): the
-    column tuples and the range-slot scalars of `_bounds_args`."""
+    resident column tuples, and what is not resident as host numpy
+    values that the jitted call itself carries to the device: the int64
+    operand vector of `_bounds_args` (range slots, then `scalars`: key
+    counts and hoisted int64 parameters) and, last of `pargs`, a
+    non-empty float64 parameter vector.  `operands` counts those and
+    `bytes` sums them; key sets, payloads and layout operands are device
+    arrays already and are not counted."""
     from ..trace import span
 
-    with span("copr.args"):
-        return (tuple(datas), tuple(valids), del_mask,
-                _bounds_args(bounds), tuple(lvals), *pargs)
+    with span("copr.args") as sp:
+        ints = _bounds_args(bounds, scalars)
+        host = [a for a in (ints, *lvals, *pargs)
+                if not isinstance(a, jax.Array)]
+        sp.set(operands=len(host), bytes=sum(a.nbytes for a in host))
+        return (tuple(datas), tuple(valids), del_mask, ints,
+                tuple(lvals), *pargs)
 
 
 def _program_name(kind: str, fp: str) -> str:
@@ -948,20 +977,19 @@ def _packed_jit(fn, mesh: Mesh, name: Optional[str] = None, merge=None):
     return call
 
 
-def _mesh_in_specs(an: _Analyzed, hoisted: bool, n_lvals: int = 0):
+def _mesh_in_specs(an: _Analyzed, hoisted, n_lvals: int = 0):
     """shard_map input specs shared by every fused mesh program: sharded
-    column/validity/deletion arrays, the replicated range-bound slots,
+    column/validity/deletion arrays, the replicated int64 operand vector,
     the replicated layout dictionary-value operands (one per cold
     column), then the variadic parg tail."""
-    return (P("dp"), P("dp"), P("dp"),
-            tuple(P() for _ in range(2 * MESH_RANGE_SLOTS)),
+    return (P("dp"), P("dp"), P("dp"), P(),
             tuple(P() for _ in range(n_lvals)),
             ) + _probe_specs(an, hoisted)
 
 
 def _build_mesh_core(an: _Analyzed, kind: str, col_order: List[int],
                      mesh: Mesh, tiles_per_shard: int,
-                     hoisted: bool = False, col_layout=None):
+                     hoisted=None, col_layout=None):
     """The raw shard_map'd whole-fragment program (pre-jit).
 
     One body per mesh: each shard flattens its local tiles to a
@@ -973,9 +1001,10 @@ def _build_mesh_core(an: _Analyzed, kind: str, col_order: List[int],
     jits + packs it) and by kernelcheck's fused-fragment corpus
     (jax.make_jaxpr over a 1-device mesh).
 
-    Signature: core(datas, valids, del_mask, bounds, lvals, *pargs)
-    where bounds is the 2*MESH_RANGE_SLOTS scalar tuple from
-    _bounds_args and lvals the cold columns' dictionary-value runtime
+    Signature: core(datas, valids, del_mask, ints, lvals, *pargs)
+    where ints is the int64 operand vector from _bounds_args (read by
+    _mesh_masks and _split_operands; `hoisted` is the (pi, pf) lengths
+    or None) and lvals the cold columns' dictionary-value runtime
     operands (empty tuple for an all-hot fragment — the common case
     compiles the identical program it always did).
     """
@@ -992,21 +1021,22 @@ def _build_mesh_core(an: _Analyzed, kind: str, col_order: List[int],
         return _build_sort_agg_core(an, col_order, mesh, tiles_per_shard,
                                     hoisted=hoisted, col_layout=col_layout)
 
-    def region_ctx(datas, valids, del_mask, bounds, lvals, pargs):
-        pargs, params = _split_hoisted(pargs, hoisted)
+    def region_ctx(datas, valids, del_mask, ints, lvals, pargs):
+        counts, pargs, params = _split_operands(an, ints, pargs, hoisted)
         cols = _cols_env(an, col_order, datas, valids, n_local, params,
                          col_layout=col_layout, lvals=lvals)
-        gofs, row_mask = _mesh_masks(del_mask, bounds, n_local)
+        gofs, row_mask = _mesh_masks(del_mask, ints, n_local)
         ctx = fusion.RegionContext(an=an, cols=cols, n=n_local,
                                    mask=row_mask, axis="dp", gofs=gofs,
                                    n_global=n_global)
         fusion.selection_mask(ctx)
-        ctx.mask = _apply_probes(an, cols, ctx.mask, pargs, n_local)
+        ctx.mask = _apply_probes(an, cols, ctx.mask, pargs, counts,
+                                 n_local)
         return ctx
 
     if kind == "agg":
-        def shard_fn(datas, valids, del_mask, bounds, lvals, *pargs):
-            ctx = region_ctx(datas, valids, del_mask, bounds, lvals,
+        def shard_fn(datas, valids, del_mask, ints, lvals, *pargs):
+            ctx = region_ctx(datas, valids, del_mask, ints, lvals,
                              pargs)
             gidx = fusion.dense_group_codes(ctx)
             gcount, results = fusion.dense_agg_results(ctx, gidx)
@@ -1034,8 +1064,8 @@ def _build_mesh_core(an: _Analyzed, kind: str, col_order: List[int],
         desc = fusion.topn_desc(an)
         k = min(topn_budget(an.topn.limit), n_local)
 
-        def shard_fn(datas, valids, del_mask, bounds, lvals, *pargs):
-            ctx = region_ctx(datas, valids, del_mask, bounds, lvals,
+        def shard_fn(datas, valids, del_mask, ints, lvals, *pargs):
+            ctx = region_ctx(datas, valids, del_mask, ints, lvals,
                              pargs)
             key = fusion.topn_key(ctx)
             idx, cnt = ops.masked_top_k(key, ctx.mask, k, desc)
@@ -1043,8 +1073,8 @@ def _build_mesh_core(an: _Analyzed, kind: str, col_order: List[int],
 
         out_specs = P("dp")
     else:  # filter: the fused selection mask (projection reads it later)
-        def shard_fn(datas, valids, del_mask, bounds, lvals, *pargs):
-            ctx = region_ctx(datas, valids, del_mask, bounds, lvals,
+        def shard_fn(datas, valids, del_mask, ints, lvals, *pargs):
+            ctx = region_ctx(datas, valids, del_mask, ints, lvals,
                              pargs)
             return ctx.mask
 
@@ -1057,15 +1087,16 @@ def _build_mesh_core(an: _Analyzed, kind: str, col_order: List[int],
 
 def _build_mesh_fn(an: _Analyzed, kind: str, col_order: List[int],
                    mesh: Mesh, tiles_per_shard: int, name: str,
-                   hoisted: bool = False, col_layout=None):
+                   hoisted=None, col_layout=None):
     """One jitted shard_map program over the whole fragment.
 
     Inputs: datas [n_pad, TILE] x cols (cold columns: [n_pad,
     TILE*bits/8] packed bytes), valids likewise, del_mask [n_pad, TILE],
-    the range-bound list (padded to MESH_RANGE_SLOTS runtime scalars),
-    the cold columns' dictionary-value operands, then the variadic parg
-    tail (probe key sets, lookup payloads, and — when `hoisted` — the
-    replicated (pi, pf) predicate parameter vectors).  Every range of a
+    the int64 operand vector (the range-bound list padded to
+    MESH_RANGE_SLOTS, key counts, hoisted int64 parameters), the cold
+    columns' dictionary-value operands, then the variadic parg tail
+    (probe key sets, lookup payloads, and a non-empty hoisted float64
+    parameter vector).  Every range of a
     steady-state fragment runs in this ONE dispatch; intermediates never
     leave HBM.  `name` is the jitted callable's (`_program_name`).
     """
@@ -1100,9 +1131,8 @@ def _build_mesh_fn(an: _Analyzed, kind: str, col_order: List[int],
 
         packed = _packed_jit(core, mesh, name, merge=merge_shards)
 
-        def wrapped(datas, valids, del_mask, bounds, lvals=(), pargs=()):
-            return packed(*_call_args(datas, valids, del_mask, bounds,
-                                      lvals, pargs))
+        def wrapped(*operands):  # the arguments of _call_args
+            return packed(*_call_args(*operands))
 
         return wrapped
 
@@ -1112,9 +1142,8 @@ def _build_mesh_fn(an: _Analyzed, kind: str, col_order: List[int],
         k = min(topn_budget(an.topn.limit), n_local)
         packed = _packed_jit(core, mesh, name)
 
-        def wrapped(datas, valids, del_mask, bounds, lvals=(), pargs=()):
-            gidx, cnt = packed(*_call_args(datas, valids, del_mask, bounds,
-                                           lvals, pargs))
+        def wrapped(*operands):
+            gidx, cnt = packed(*_call_args(*operands))
             return gidx, cnt, k
         return wrapped
 
@@ -1127,12 +1156,11 @@ def _build_mesh_fn(an: _Analyzed, kind: str, col_order: List[int],
     packed_mask.__name__ = packed_mask.__qualname__ = name
     jitted = jax.jit(packed_mask, out_shardings=_readback_sharding(mesh))
 
-    def wrapped(datas, valids, del_mask, bounds, lvals=(), pargs=()):
+    def wrapped(*operands):
         from ..trace import span
 
         n_rows = S * n_local
-        bits = _read_back(_launch(jitted, name, _call_args(
-            datas, valids, del_mask, bounds, lvals, pargs)))
+        bits = _read_back(_launch(jitted, name, _call_args(*operands)))
         with span("copr.unpack", rows=n_rows, bytes=bits.nbytes):
             return np.unpackbits(bits, count=n_rows).astype(np.bool_)
     return wrapped
@@ -1189,7 +1217,7 @@ def _fd_sort_lookup(an: _Analyzed):
 
 
 def _build_sort_agg_core(an: _Analyzed, col_order: List[int], mesh: Mesh,
-                         tiles_per_shard: int, hoisted: bool = False,
+                         tiles_per_shard: int, hoisted=None,
                          col_layout=None):
     """Sort-based per-shard partial aggregation for arbitrary group keys
     (any NDV, float, NULLable, expression keys) — the shard_map'd core.
@@ -1216,15 +1244,15 @@ def _build_sort_agg_core(an: _Analyzed, col_order: List[int], mesh: Mesh,
     remaps = getattr(an, "key_remaps", None)
     n_lvals = n_cold + _n_remaps(an)
 
-    def shard_fn(datas, valids, del_mask, bounds, lvals, *pargs):
-        pargs, params = _split_hoisted(pargs, hoisted)
+    def shard_fn(datas, valids, del_mask, ints, lvals, *pargs):
+        counts, pargs, params = _split_operands(an, ints, pargs, hoisted)
         cols = _cols_env(an, col_order, datas, valids, n_local, params,
                         col_layout=col_layout, lvals=lvals)
-        gofs, m = _mesh_masks(del_mask, bounds, n_local)
+        gofs, m = _mesh_masks(del_mask, ints, n_local)
         ctx = fusion.RegionContext(an=an, cols=cols, n=n_local, mask=m,
                                    axis="dp", gofs=gofs, n_global=n_global)
         fusion.selection_mask(ctx)
-        m = _apply_probes(an, cols, ctx.mask, pargs, n_local)
+        m = _apply_probes(an, cols, ctx.mask, pargs, counts, n_local)
         key_bits, key_flags = [], []
         rslot = 0
         for gi, g in enumerate(agg_ir.group_by):
@@ -1254,7 +1282,7 @@ def _build_sort_agg_core(an: _Analyzed, col_order: List[int], mesh: Mesh,
             # (XLA CSE folds this searchsorted into _apply_probes' one)
             ar = jnp.arange(n_local, dtype=jnp.int64)
             lk = an.lookups[0]
-            bkeys = pargs[2 * len(an.probes)]
+            bkeys = pargs[len(an.probes)]
             dk, _vk = compile_expr(lk.key, cols, n_local)
             posk = jnp.clip(jnp.searchsorted(bkeys, dk.astype(jnp.int64)),
                             0, bkeys.shape[0] - 1)
@@ -1283,9 +1311,8 @@ def _wrap_sort_agg(an: _Analyzed, core, mesh: Mesh, S: int,
     tags = je._agg_tags(an.agg)
     packed = _packed_jit(core, mesh, name)
 
-    def wrapped(datas, valids, del_mask, bounds, lvals=(), pargs=()):
-        n_uniq, keys, results = packed(*_call_args(
-            datas, valids, del_mask, bounds, lvals, pargs))
+    def wrapped(*operands):
+        n_uniq, keys, results = packed(*_call_args(*operands))
         return {
             "mode": "sort",
             "S": S, "OUT": OUT,
@@ -1686,6 +1713,7 @@ def _run_mesh_once(storage, req: CopRequest, tid: int,
     # runtime join-filter payloads: sorted build keys, padded to a pow2
     # bucket so compiled programs are reused across key-set sizes
     pargs: list = []
+    counts: List[int] = []
     kpads: List[int] = []
     for p in an.probes:
         arr = (req.aux or {}).get(f"probe_keys_{p.filter_id}")
@@ -1713,7 +1741,7 @@ def _run_mesh_once(storage, req: CopRequest, tid: int,
             padded = np.full(kpad, np.iinfo(np.int64).max, dtype=np.int64)
             padded[:k] = arr
         pargs.append(jnp.asarray(padded))
-        pargs.append(jnp.int64(k))
+        counts.append(k)
         kpads.append(kpad)
 
     for lk in an.lookups:
@@ -1734,7 +1762,7 @@ def _run_mesh_once(storage, req: CopRequest, tid: int,
         padded = np.full(kpad, np.iinfo(np.int64).max, dtype=np.int64)
         padded[:k] = arr
         pargs.append(jnp.asarray(padded))
-        pargs.append(jnp.int64(k))
+        counts.append(k)
         for j, ft in enumerate(lk.payload_ftypes):
             pl = np.zeros(kpad, dtype=_full_dtype(ft.kind))
             pl[:k] = payload[j]
@@ -1802,7 +1830,8 @@ def _run_mesh_once(storage, req: CopRequest, tid: int,
     if fn is None:
         fn = _build_mesh_fn(an, kind, col_order, mesh, Tl,
                             _program_name(kind, fp),
-                            hoisted=hoisted is not None,
+                            hoisted=(None if hoisted is None
+                                     else (len(hoisted[0]), len(hoisted[1]))),
                             col_layout=col_layout)
         _COMPILED.put(fp, fn)
         # label this query's FIRST dispatch as the compile: jit compiles
@@ -1812,10 +1841,15 @@ def _run_mesh_once(storage, req: CopRequest, tid: int,
         with span("copr.compile", cache="hit", kind=kind):
             pass
     pargs = tuple(pargs)
+    # the statement's int64 scalars ride the operand vector behind each
+    # dispatch's range slots (the shard program reads them back through
+    # _split_operands); float64 parameters, where there are any, are the
+    # last parg, a host array as hoist_conds made it
+    scalars = np.array(counts, dtype=np.int64)
     if hoisted is not None:
-        # replicated parameter vectors ride the variadic parg tail (the
-        # shard program peels them back off via _split_hoisted)
-        pargs = pargs + (jnp.asarray(hoisted[0]), jnp.asarray(hoisted[1]))
+        scalars = np.concatenate([scalars, hoisted[0]])
+        if len(hoisted[1]):
+            pargs = pargs + (hoisted[1],)
 
     # one delta pass for the whole table
     deleted, inserted = table.delta_overlay(req.ts, 0, 1 << 62)
@@ -1846,7 +1880,7 @@ def _run_mesh_once(storage, req: CopRequest, tid: int,
         # in STREAM_ROWS slices as the consumer drains the bounded queue,
         # so peak host memory no longer scales with the selected row count
         return _stream_filter(req, table, an, fn, datas, valids, del_mask,
-                              inserted, pargs, mesh_ids=mesh_ids,
+                              inserted, pargs, scalars, mesh_ids=mesh_ids,
                               bounds=bounds, tail=tail, dag=dag,
                               lvals=lvals,
                               split_label=plan.reason_label)
@@ -1896,7 +1930,8 @@ def _run_mesh_once(storage, req: CopRequest, tid: int,
                 # admission re-acquired per chunk: a depleted resource
                 # group yields the device at every chunk boundary
                 with dispatch_admission(DISPATCH_LOCK):
-                    out = fn(datas, valids, del_mask, sub, lvals, pargs)
+                    out = fn(datas, valids, del_mask, sub, lvals, pargs,
+                             scalars)
             # the budget learns from the rows the device SCANNED: the
             # program masks rows outside `sub`, it does not skip them, so
             # a dispatch costs one pass over the resident table whatever
@@ -1978,8 +2013,8 @@ def _run_mesh_once(storage, req: CopRequest, tid: int,
 
 
 def _stream_filter(req, table, an, fn, datas, valids, del_mask, inserted,
-                   pargs=(), mesh_ids=(), bounds=(), tail=None, dag=None,
-                   lvals=(), split_label=None):
+                   pargs=(), scalars=(), mesh_ids=(), bounds=(), tail=None,
+                   dag=None, lvals=(), split_label=None):
     """Generator over a mesh filter's result chunks: ONE fused bit-packed
     mask dispatch covering every range, then STREAM_ROWS-sized host
     gathers on demand (distsql/stream.go:33-124; kv.Request.Streaming
@@ -2022,7 +2057,8 @@ def _stream_filter(req, table, an, fn, datas, valids, del_mask, inserted,
             t0 = time.perf_counter()
             with span("copr.chunk", kind="filter", chunk=ci, rows=crows):
                 with dispatch_admission(DISPATCH_LOCK):
-                    mask = fn(datas, valids, del_mask, sub, lvals, pargs)
+                    mask = fn(datas, valids, del_mask, sub, lvals, pargs,
+                              scalars)
             # rows scanned, not rows in bounds: see _chunk_dispatch
             observe_chunk("filter", (time.perf_counter() - t0) * 1000.0,
                           int(del_mask.size))

@@ -358,7 +358,6 @@ def _build_mpp_fn(spec: MPPJoinSpec, ps: _SideState, bs: _SideState,
     # the fused program's fingerprint — same policy as the mesh scan
     p_prep = _shard_side(p_an, p_order, ps.n_local, MESH_RANGE_SLOTS)
     b_prep = _shard_side(b_an, b_order, bs.n_local, MESH_RANGE_SLOTS)
-    n_pb = n_bb = MESH_RANGE_SLOTS
     louter = spec.kind == "left_outer"
     aggs = spec.aggs
     group_by = spec.group_by
@@ -598,10 +597,10 @@ def _build_mpp_fn(spec: MPPJoinSpec, ps: _SideState, bs: _SideState,
                 out_states.append((P("dp"), P()))
         out_specs = (P(), P(), tuple(out_states))
 
-    in_specs = (P("dp"), P("dp"), P("dp"), tuple(P() for _ in
-                                                 range(2 * n_pb)),
-                P("dp"), P("dp"), P("dp"), tuple(P() for _ in
-                                                 range(2 * n_bb)))
+    # each side's range slots are one replicated int64 vector
+    # (parallel._bounds_args)
+    in_specs = (P("dp"), P("dp"), P("dp"), P(),
+                P("dp"), P("dp"), P("dp"), P())
     if grouped:
         in_specs = in_specs + (P(),)  # the runtime group-budget slot
         # replicated remap-mapping operands (computed string keys)

@@ -345,14 +345,13 @@ def _build_rung_fn(spec: MPPJoinTreeSpec, r: int, states, mesh, mode: str,
     n_out_slots = n_slots + (len(b_order) if emits else 0)
     out_specs = (P(), P(), tuple(P("dp") for _ in range(2 * n_out_slots)),
                  P("dp"))
+    # a scanned side's range slots are one replicated int64 vector
+    # (parallel._bounds_args)
     if first:
-        in_specs = (P("dp"), P("dp"), P("dp"),
-                    tuple(P() for _ in range(2 * MESH_RANGE_SLOTS)))
+        in_specs = (P("dp"), P("dp"), P("dp"), P())
     else:
         in_specs = tuple(P("dp") for _ in range(2 * n_slots)) + (P("dp"),)
-    full_in = tuple(in_specs) + (
-        P("dp"), P("dp"), P("dp"),
-        tuple(P() for _ in range(2 * MESH_RANGE_SLOTS)))
+    full_in = tuple(in_specs) + (P("dp"), P("dp"), P("dp"), P())
     fn = shard_map(shard_fn, mesh=mesh, in_specs=full_in,
                    out_specs=out_specs, check_vma=False)
     return jax.jit(fn)
