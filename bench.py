@@ -516,125 +516,6 @@ def lockcheck_bench(n: int = None) -> dict:
                        + (proc.stderr or proc.stdout)[-400:])
 
 
-def _measure_kill_latency(domain, sql: str):
-    """Run `sql` in a victim session on its own thread, KILL it once the
-    dispatch sequence is in flight, and return (kill-to-return seconds,
-    outcome)."""
-    import threading
-
-    victim = domain.new_session()
-    victim.execute("set tidb_use_tpu = 1")
-    started = threading.Event()
-    done = threading.Event()
-    result = {}
-
-    def run():
-        started.set()
-        try:
-            victim.query(sql)
-            result["outcome"] = "completed"
-        except BaseException as e:  # noqa: BLE001
-            result["outcome"] = type(e).__name__
-        done.set()
-
-    th = threading.Thread(target=run)
-    th.start()
-    started.wait()
-    time.sleep(0.05)  # let the statement reach the device
-    t0 = time.perf_counter()
-    domain.kill(victim.conn_id, True)
-    done.wait(timeout=120)
-    lat = time.perf_counter() - t0
-    th.join(timeout=10)
-    return lat, result.get("outcome", "hung")
-
-
-def kill_latency_bench(sess, n: int) -> dict:
-    """Interruptible-dispatch receipt (ISSUE 17): KILL-to-return latency
-    of an oversized scan with chunked dispatch vs the unchunked
-    comparator (TIDB_TPU_DISPATCH_CHUNK=0 — the KILL waits out the whole
-    fused dispatch), plus a 2-group 1:3 weighted-fairness run whose
-    consumed-RU ratio must track the quota ratio."""
-    import threading
-
-    from tidb_tpu.metrics import REGISTRY
-
-    d = sess.domain
-    out: dict = {}
-    prior_rows = os.environ.get("TIDB_TPU_DISPATCH_CHUNK_ROWS")
-    prior_ms = os.environ.get("TIDB_TPU_DISPATCH_CHUNK")
-    try:
-        # chunked leg: force many chunks regardless of the latency
-        # estimate so the between-chunk seam is exercised
-        os.environ["TIDB_TPU_DISPATCH_CHUNK_ROWS"] = str(
-            max(n // 64, 1024))
-        os.environ.pop("TIDB_TPU_DISPATCH_CHUNK", None)
-        lat_c, how_c = _measure_kill_latency(d, Q1)
-        # unchunked comparator: one fused dispatch per fragment
-        os.environ.pop("TIDB_TPU_DISPATCH_CHUNK_ROWS", None)
-        os.environ["TIDB_TPU_DISPATCH_CHUNK"] = "0"
-        lat_u, how_u = _measure_kill_latency(d, Q1)
-    finally:
-        for k, v in (("TIDB_TPU_DISPATCH_CHUNK_ROWS", prior_rows),
-                     ("TIDB_TPU_DISPATCH_CHUNK", prior_ms)):
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
-    out["chunked_kill_s"] = round(lat_c, 4)
-    out["chunked_outcome"] = how_c
-    out["unchunked_kill_s"] = round(lat_u, 4)
-    out["unchunked_outcome"] = how_u
-    out["speedup"] = round(lat_u / lat_c, 2) if lat_c > 0 else None
-    log(f"kill latency: chunked={lat_c:.3f}s ({how_c}) "
-        f"unchunked={lat_u:.3f}s ({how_u})")
-
-    # ---- 2-group weighted fairness (1:3 RU quotas) ----------------------
-    adm = d.new_session()
-    adm.execute("create resource group if not exists bench_small"
-                " ru_per_sec = 60")
-    adm.execute("create resource group if not exists bench_big"
-                " ru_per_sec = 180")
-    base = REGISTRY.snapshot()
-    stop = threading.Event()
-
-    def worker(group):
-        s2 = d.new_session()
-        s2.execute(f"set tidb_tpu_resource_group = '{group}'")
-        s2.execute("set tidb_use_tpu = 1")
-        while not stop.is_set():
-            try:
-                s2.query(Q6)
-            except BaseException:  # noqa: BLE001 — throttles expected
-                pass
-
-    threads = [threading.Thread(target=worker, args=(g,))
-               for g in ("bench_small", "bench_big")]
-    for t in threads:
-        t.start()
-    time.sleep(4.0)
-    stop.set()
-    for t in threads:
-        t.join(timeout=60)
-    snap = REGISTRY.snapshot()
-
-    def delta(name):
-        return snap.get(name, 0.0) - base.get(name, 0.0)
-
-    ru_small = delta("resgroup_bench_small_ru_consumed_total")
-    ru_big = delta("resgroup_bench_big_ru_consumed_total")
-    out["fairness"] = {
-        "small_ru": round(ru_small, 1),
-        "big_ru": round(ru_big, 1),
-        "ratio": round(ru_big / ru_small, 2) if ru_small > 0 else None,
-        "target_ratio": 3.0,
-        "throttled": delta("resgroup_throttled_total"),
-    }
-    log(f"fairness 1:3 quotas -> consumed RU {ru_small:.0f}:{ru_big:.0f}"
-        f" (ratio {out['fairness']['ratio']})")
-    return out
-
-
 def dataplane_bench(n: int) -> dict:
     """Sharded data-plane receipt (ISSUE 18): warm Q6 scan throughput
     with the whole table resident on ONE member (LocalPlane degenerate
@@ -1230,18 +1111,6 @@ def _run_inner(state: dict):
         except BaseException as e:  # noqa: BLE001
             state["lockcheck"] = {"error": repr(e)}
         state["phases"]["lockcheck_done"] = round(
-            time.perf_counter() - T0, 1)
-        persist_partial(state)
-
-    # interruptible chunked dispatch (ISSUE 17): KILL-to-return latency
-    # chunked vs the unchunked comparator + 2-group RU fairness
-    if state.get("q1") and remaining() > 90:
-        try:
-            state["kill_latency"] = kill_latency_bench(
-                sess, state.get("loaded_rows", 262_144))
-        except BaseException as e:  # noqa: BLE001
-            state["kill_latency"] = {"error": repr(e)}
-        state["phases"]["kill_latency_done"] = round(
             time.perf_counter() - T0, 1)
         persist_partial(state)
 

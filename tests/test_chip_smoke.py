@@ -109,6 +109,11 @@ def test_four_chip_phases_on_the_virtual_mesh():
 
     import jax
 
+    from tidb_tpu.copr.parallel import MESH_CACHE
+
+    # the smoke's process is new; this worker's may hold arrays that a
+    # failover test (test_chaos.py) sharded over its seven survivors
+    MESH_CACHE.clear()
     smoke = chip_smoke.Smoke()
     out = io.StringIO()
     with contextlib.redirect_stdout(out):

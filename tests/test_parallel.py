@@ -474,6 +474,7 @@ _OPERAND_SQL = {
 _OPERAND_RANGES = {
     1: [(0, 20_000)],
     2: [(100, 6_000), (11_000, 19_999)],
+    3: [(0, 2_048), (4_097, 9_000), (12_345, 1 << 62)],  # one pad slot
     4: [(0, 3_000), (5_000, 5_001), (7_000, 12_345), (15_000, 1 << 62)],
 }
 
@@ -506,14 +507,14 @@ def _run_over_ranges(sess, sql, ranges, use_tpu):
 
 @pytest.mark.parametrize("hoisted", [True, False],
                          ids=["hoisted", "literal"])
-@pytest.mark.parametrize("n_ranges, chunk_rows", [
-    (1, "0"), (2, "0"), (4, "0"), (4, "2048")],  # "0": one dispatch
-    ids=["1range", "2ranges", "4ranges", "4ranges-chunked"])
+@pytest.mark.parametrize("n_ranges", [1, 2, 3, 4],
+                         ids=["1range", "2ranges", "3ranges", "4ranges"])
 @pytest.mark.parametrize("kind", sorted(_OPERAND_SQL))
 def test_mesh_dispatch_over_ranges_equals_the_oracle(
-        sess, monkeypatch, kind, n_ranges, chunk_rows, hoisted):
+        sess, monkeypatch, kind, n_ranges, hoisted):
     """Every kind of mesh program, handed its range slots and parameter
-    vectors as host values, returns what the oracle engine returns."""
+    vectors as host values in ONE dispatch, returns what the oracle
+    engine returns."""
     from tidb_tpu import serving
     from tidb_tpu.copr import parallel as pl
     from tidb_tpu.trace import recorder
@@ -532,7 +533,6 @@ def test_mesh_dispatch_over_ranges_equals_the_oracle(
 
     monkeypatch.setattr(pl, "_call_args", spy)
     monkeypatch.setattr(pl, "_sort_agg_chunks", sort_spy)
-    monkeypatch.setenv("TIDB_TPU_DISPATCH_CHUNK_ROWS", chunk_rows)
     sql, ranges = _OPERAND_SQL[kind], _OPERAND_RANGES[n_ranges]
     serving.configure(shape_buckets=hoisted)
     tr, token = recorder.start_trace(sql)
@@ -541,34 +541,31 @@ def test_mesh_dispatch_over_ranges_equals_the_oracle(
     finally:
         recorder.finish_trace(tr, token)
         serving.configure(shape_buckets=True)
-    assert calls, "not on the mesh path"
-    assert (len(calls) == 1) is (chunk_rows == "0")
+    assert len(calls) == 1, "not on the mesh path, or not one dispatch"
     passes, todo = [], [tr.root]
     while todo:
         sp = todo.pop()
         todo.extend(sp.children)
         if sp.name == "copr.chunk":
             passes.append(sp)
-    assert len(passes) == len(calls)
+    assert len(passes) == 1
     assert {sp.attrs["kind"] for sp in passes} == {
         "agg" if kind.endswith("agg") else kind}
     assert bool(sorted_aggs) is (kind == "sort_agg")
-    n_calls = len(calls)
     want = _run_over_ranges(sess, sql, ranges, 0)
-    assert len(calls) == n_calls, "the oracle dispatched to the mesh"
+    assert len(calls) == 1, "the oracle dispatched to the mesh"
     if kind != "topn":  # the aggregates' and the filter's rows are a set
         got, want = sorted(got, key=repr), sorted(want, key=repr)
     assert len(got) == len(want) > 0
     for ra, rb in zip(got, want):
         assert all(_approx_eq(x, y) for x, y in zip(ra, rb)), (ra, rb)
-    for args in calls:
-        # one int64 vector (range slots, then the hoisted int64
-        # parameters) and at most one float64 parameter vector
-        assert _is_host(args[3], np.int64) and len(args) <= 6
-        assert all(_is_host(pf, np.float64) and pf.size for pf in args[5:])
-        assert (len(args[3]) > 8 or len(args) > 5) is hoisted
-    if chunk_rows == "0":
-        base = 20_000
-        assert calls[0][3][:8].tolist() == (
-            [min(x, base) for lohi in ranges for x in lohi]
-            + [0] * (8 - 2 * len(ranges)))
+    (args,) = calls
+    # one int64 vector (range slots, then the hoisted int64
+    # parameters) and at most one float64 parameter vector
+    assert _is_host(args[3], np.int64) and len(args) <= 6
+    assert all(_is_host(pf, np.float64) and pf.size for pf in args[5:])
+    assert (len(args[3]) > 8 or len(args) > 5) is hoisted
+    base = 20_000
+    assert args[3][:8].tolist() == (
+        [min(x, base) for lohi in ranges for x in lohi]
+        + [0] * (8 - 2 * len(ranges)))

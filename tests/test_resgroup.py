@@ -1,17 +1,15 @@
-"""Interruptible chunked dispatch + per-statement resource groups
-(ISSUE 17).
+"""One dispatch per mesh statement + per-statement resource groups.
 
-Tentpole coverage:
+Coverage:
 
-- chunked-vs-unchunked parity across the fusion corpus (rows, agg,
-  TopN) — chunking changes only range-slot operand VALUES on the same
-  compiled program, never results;
-- the chunk count must NOT enter any program fingerprint: no new
-  compiled entries appear when the chunk budget changes;
-- KILL of an in-flight oversized scan lands at the between-chunk seam:
-  the statement returns within two chunk dispatches of the kill instead
-  of running the remaining sequence;
-- resource groups: token-bucket quotas charge per chunk, depleted
+- a mesh statement dispatches its program ONCE over all of its range
+  slots, between a pre- and a post-dispatch cancellation seam: a KILL
+  that arrives while the dispatch is in flight lands at the
+  post-dispatch seam, and the session re-runs with full parity;
+- with one dispatch a statement the order in which double partial sums
+  are added never changes: a grouped SUM(double) is bit-identical run
+  over run;
+- resource groups: token-bucket quotas charge per dispatch, depleted
   non-burstable groups raise the typed retriable ResourceGroupThrottled,
   two groups with 1:3 quotas observe device-time share near the ratio,
   and QUERY_LIMIT cancels the runaway statement through its scope with
@@ -21,7 +19,6 @@ Tentpole coverage:
   INFORMATION_SCHEMA.TIDB_TPU_RESOURCE_GROUPS memtable.
 """
 
-import os
 import threading
 import time
 
@@ -40,12 +37,8 @@ from tidb_tpu.store.fault import FAILPOINTS, failpoint
 
 Q_AGG = ("select g, sum(x), count(*), min(x), max(x) from t "
          "group by g order by g")
-Q_SUM = "select sum(x) from t where k < 15000 and x < 50"
 Q_TOPN = "select k, x from t order by x desc limit 7"
 Q_FILTER = "select k from t where x < 2.5"
-
-CORPUS = (Q_AGG, Q_SUM, Q_TOPN, Q_FILTER)
-
 
 @pytest.fixture(scope="module")
 def sess():
@@ -67,14 +60,6 @@ def sess():
     return s
 
 
-@pytest.fixture()
-def chunked():
-    """Force multi-chunk dispatch regardless of the latency estimate."""
-    os.environ["TIDB_TPU_DISPATCH_CHUNK_ROWS"] = "2048"
-    yield
-    os.environ.pop("TIDB_TPU_DISPATCH_CHUNK_ROWS", None)
-
-
 def _approx_eq(a, b):
     if isinstance(a, float) or isinstance(b, float):
         return a == pytest.approx(b, rel=1e-9, abs=1e-9)
@@ -88,162 +73,50 @@ def _rows_eq(got, want, ctx=""):
 
 
 # ---------------------------------------------------------------------------
-# chunk_bounds unit behavior
+# KILL lands at the post-dispatch seam
 # ---------------------------------------------------------------------------
 
-def test_chunk_bounds_split_and_disabled():
-    from tidb_tpu.copr.chunking import chunk_bounds
-
-    # budget 0 => ONE chunk, bounds verbatim (the disabled path)
-    assert chunk_bounds([(0, 10), (20, 25)], 0) == [[(0, 10), (20, 25)]]
-    assert chunk_bounds([], 100) == []
-    # rows split across chunks, ranges stay disjoint + ascending
-    assert chunk_bounds([(0, 10)], 4) == [[(0, 4)], [(4, 8)], [(8, 10)]]
-    # max_slots caps ranges per chunk even under budget
-    out = chunk_bounds([(0, 1), (2, 3), (4, 5), (6, 7), (8, 9)], 100,
-                       max_slots=2)
-    assert all(len(c) <= 2 for c in out)
-    flat = [r for c in out for r in c]
-    assert flat == [(0, 1), (2, 3), (4, 5), (6, 7), (8, 9)]
-    # coverage is exact: no row lost or duplicated, order preserved
-    out = chunk_bounds([(3, 1000), (1500, 1501), (2000, 2500)], 137)
-    flat = [r for c in out for r in c]
-    assert sum(hi - lo for lo, hi in flat) == (1000 - 3) + 1 + 500
-    for (_, a1), (b0, _) in zip(flat, flat[1:]):
-        assert a1 <= b0
-
-
-def test_chunk_budget_settles_when_a_dispatch_scans_the_whole_table():
-    """A mesh program masks the rows outside a chunk's bounds, it does not
-    skip them: one dispatch costs one pass over the table.  Fed the rows
-    scanned, the budget settles near ceil(pass_ms / budget_ms) chunks; fed
-    the rows in bounds it shrank geometrically, run over run, whenever a
-    pass outlasted the budget."""
-    from tidb_tpu.copr.chunking import (chunk_bounds, chunk_budget_rows,
-                                        observe_chunk)
-
-    kind, table, pass_ms = "unit-whole-table-pass", 1 << 26, 250.0
-    counts = []
-    for _run in range(5):
-        n = len(chunk_bounds([(0, table)], chunk_budget_rows(kind)))
-        counts.append(n)
-        for _ in range(n):
-            observe_chunk(kind, pass_ms, table)
-    assert counts[0] == 82  # the cold-start guess: 8,192 rows/ms
-    assert counts[1:] == [counts[1]] * 4 and 1 <= counts[1] <= 4, counts
-
-
-def test_mesh_dispatch_reports_rows_scanned_not_rows_in_bounds(sess, chunked):
-    name = "dispatch_chunk_agg_rows"
-    h0 = REGISTRY.hist_stats(name) or {"count": 0, "sum": 0.0}
-    sess.query("select g, count(*), sum(x) from t group by g")
-    h1 = REGISTRY.hist_stats(name)
-    n = h1["count"] - h0["count"]
-    assert n > 1, "the query did not take the chunked path"
-    n_rows = sess.query("select count(*) from t")[0][0]
-    assert (h1["sum"] - h0["sum"]) / n >= n_rows > 2048
-
-
-# ---------------------------------------------------------------------------
-# parity: chunked == unchunked across the corpus
-# ---------------------------------------------------------------------------
-
-def test_chunked_parity_corpus(sess, chunked):
-    before = REGISTRY.snapshot().get("dispatch_chunks_total", 0)
-    got = {q: sess.query(q) for q in CORPUS}
-    after = REGISTRY.snapshot().get("dispatch_chunks_total", 0)
-    assert after > before + len(CORPUS), \
-        "queries did not take the chunked path"
-    os.environ.pop("TIDB_TPU_DISPATCH_CHUNK_ROWS", None)
-    os.environ["TIDB_TPU_DISPATCH_CHUNK"] = "0"
-    try:
-        for q, rows in got.items():
-            _rows_eq(rows, sess.query(q), ctx=q)
-    finally:
-        os.environ.pop("TIDB_TPU_DISPATCH_CHUNK", None)
-
-
-def test_chunked_filter_limit_parity(sess, chunked):
-    # LIMIT decrements across chunks: first-N selection must match the
-    # single-dispatch selection (ranges ascend, so order is global)
-    q = "select k from t where x < 50 limit 100"
-    got = sess.query(q)
-    os.environ["TIDB_TPU_DISPATCH_CHUNK_ROWS"] = "0"
-    assert got == sess.query(q)
-
-
-# ---------------------------------------------------------------------------
-# fingerprint invariance: chunking must never recompile
-# ---------------------------------------------------------------------------
-
-def test_chunk_budget_not_in_fingerprint(sess):
-    from tidb_tpu.copr import parallel as pl
-
-    for q in CORPUS:
-        keys = []
-        try:
-            for budget in ("2048", "4096", "0"):
-                os.environ["TIDB_TPU_DISPATCH_CHUNK_ROWS"] = budget
-                sess.query(q)
-                keys.append(set(pl._COMPILED._d.keys()))
-        finally:
-            os.environ.pop("TIDB_TPU_DISPATCH_CHUNK_ROWS", None)
-        assert keys[0] == keys[1] == keys[2], \
-            f"chunk budget leaked into a program fingerprint: {q}"
-
-
-# ---------------------------------------------------------------------------
-# KILL lands at the between-chunk seam
-# ---------------------------------------------------------------------------
-
-def test_kill_bounded_by_chunk_seam(sess, chunked):
-    """Kill fired from inside chunk 1's failpoint: the statement must
-    unwind at the NEXT seam — at most one more chunk dispatches after
-    the kill (the acceptance bound: within 2 chunk budgets)."""
+@pytest.mark.parametrize("kind,sql", [
+    ("agg", Q_AGG), ("filter", Q_FILTER), ("topn", Q_TOPN)],
+    ids=["agg", "streamed-filter", "topn"])
+def test_kill_at_dispatch_lands_at_post_dispatch_seam(sess, kind, sql):
+    """Kill fired from the pre-dispatch failpoint, as if it arrived
+    while the program was in flight: the one dispatch runs to its end
+    and the statement unwinds at the post-dispatch seam with the typed
+    error, before any host finishing."""
     d = sess.domain
     victim = d.new_session()
     victim.execute("set tidb_use_tpu = 1")
     hits = []
 
     def action(**ctx):
-        if ctx.get("kind") != "agg":
+        if ctx.get("kind") != kind:
             return
-        hits.append(ctx["chunk"])
-        if ctx["chunk"] == 1:
-            d.kill(victim.conn_id, True)
+        hits.append((ctx["chunk"], ctx["total"]))
+        d.kill(victim.conn_id, True)
 
     with failpoint("copr/chunk_dispatch", action):
         with pytest.raises(QueryKilledError):
-            victim.query(Q_AGG)
-    assert hits, "chunk failpoint never fired"
-    total_chunks = 20_000 // 2048 + 1
-    assert max(hits) <= 2, \
-        f"kill latency exceeded the chunk bound: chunks ran {hits}"
-    assert max(hits) < total_chunks - 1, "kill did not interrupt the scan"
+            victim.query(sql)
+    assert hits == [(0, 1)], f"a mesh statement is one dispatch: {hits}"
     # the session is healthy afterwards and re-running has full parity
-    _rows_eq(victim.query(Q_AGG), sess.query(Q_AGG))
+    oracle = d.new_session()
+    oracle.execute("set tidb_use_tpu = 0")
+    _rows_eq(victim.query(sql), oracle.query(sql), ctx=sql)
 
 
-def test_kill_mid_chunk_streaming_filter(sess, chunked):
-    """Same bound on the rows-streaming filter path: kill mid-sequence
-    produces the scope-bounded typed error, and a re-run full parity."""
-    d = sess.domain
-    victim = d.new_session()
-    victim.execute("set tidb_use_tpu = 1")
-    hits = []
-
-    def action(**ctx):
-        if ctx.get("kind") != "filter":
-            return
-        hits.append(ctx["chunk"])
-        if ctx["chunk"] == 1:
-            d.kill(victim.conn_id, True)
-
-    with failpoint("copr/chunk_dispatch", action):
-        with pytest.raises(QueryKilledError):
-            victim.query(Q_FILTER)
-    assert hits and max(hits) <= 2, hits
-    _rows_eq(victim.query(Q_FILTER), sess.query(Q_FILTER), ctx=Q_FILTER)
+def test_grouped_double_sum_is_bit_identical_run_over_run(sess):
+    """One dispatch a statement adds the double partial sums in one
+    order, whatever the clock says: ten runs return the same bits, and
+    they are the oracle's rows."""
+    q = "select g, sum(x), avg(x) from t group by g order by g"
+    runs = [sess.query(q) for _ in range(10)]
+    assert all(r == runs[0] for r in runs[1:]), runs
+    oracle = sess.domain.new_session()
+    oracle.execute("set tidb_use_tpu = 0")
+    want = oracle.query(q)
+    assert len(runs[0]) == len(want) == 5
+    _rows_eq(runs[0], want, ctx=q)
 
 
 def test_no_failpoint_leaks_after_kills(sess):
@@ -303,7 +176,7 @@ def test_resgroup_charge_and_refill():
 def test_dispatch_admission_bills_device_time_not_lock_wait():
     """RU accounting (ISSUE 20 satellite): the charge clock starts
     INSIDE the DISPATCH_LOCK — a tenant stuck behind another tenant's
-    chunk in the lock queue is not billed for the queue time."""
+    dispatch in the lock queue is not billed for the queue time."""
     from tidb_tpu.lifecycle import ResourceGroupRegistry
     from tidb_tpu.lifecycle.resgroup import dispatch_admission
     from tidb_tpu.lifecycle.scope import attach_scope
@@ -433,7 +306,7 @@ def test_query_limit_cancels_via_scope():
 # ---------------------------------------------------------------------------
 
 @pytest.mark.slow
-def test_two_group_fairness_ratio(sess, chunked):
+def test_two_group_fairness_ratio(sess):
     d = sess.domain
     adm = d.new_session()
     adm.execute("create resource group fair_a ru_per_sec = 40")
@@ -478,8 +351,7 @@ def test_two_group_fairness_ratio(sess, chunked):
         f"consumed RU ratio {ratio:.2f} strays from the 1:3 quotas"
 
 
-def test_depleted_group_throttles_while_other_proceeds(sess, chunked,
-                                                       monkeypatch):
+def test_depleted_group_throttles_while_other_proceeds(sess, monkeypatch):
     monkeypatch.setenv("TIDB_TPU_RESGROUP_MAX_WAIT_MS", "30")
     d = sess.domain
     adm = d.new_session()
@@ -488,7 +360,7 @@ def test_depleted_group_throttles_while_other_proceeds(sess, chunked,
         s_starved = d.new_session()
         s_starved.execute("set tidb_tpu_resource_group = 'starved'")
         s_starved.execute("set tidb_use_tpu = 1")
-        # burn the 1-RU budget, then a later chunk must throttle
+        # burn the 1-RU budget, then a later statement must throttle
         with pytest.raises(ResourceGroupThrottled):
             for _ in range(50):
                 s_starved.query(Q_AGG)
@@ -528,7 +400,7 @@ def test_resource_group_ddl_surface(sess):
                    "tidb_tpu_resource_groups") == [("default",)]
 
 
-def test_scope_carries_group_and_charges(sess, chunked):
+def test_scope_carries_group_and_charges(sess):
     d = sess.domain
     s = d.new_session()
     s.execute("create resource group rg_scope ru_per_sec = 100000")
@@ -540,20 +412,21 @@ def test_scope_carries_group_and_charges(sess, chunked):
         s.query(Q_AGG)
         after = REGISTRY.snapshot().get(
             "resgroup_rg_scope_ru_consumed_total", 0)
-        assert after > base, "chunk charges did not land on the group"
+        assert after > base, "the dispatch was not charged to the group"
     finally:
         s.execute("set tidb_tpu_resource_group = ''")
         s.execute("drop resource group rg_scope")
 
 
-def test_explain_analyze_reports_chunks(sess, chunked):
+def test_explain_analyze_reports_chunks(sess):
     sess.execute("set tidb_enable_slow_log = 1")
     try:
         rows = sess.query("explain analyze " + Q_AGG)
     finally:
         sess.execute("set tidb_enable_slow_log = 0")
     root_extra = rows[0][-1]
-    assert "chunks:" in root_extra, root_extra
+    # one mesh statement, one dispatch
+    assert root_extra.endswith("chunks: 1"), root_extra
 
 
 def test_status_and_snapshot_sections(sess):
@@ -611,8 +484,8 @@ def test_priority_gate_inert_without_differing_contention():
 
 def test_priority_two_to_one_admission_under_contention():
     """Sustained contention between a PRIORITY=2 and a PRIORITY=1 group
-    admits chunks ~2:1 — the weighted-fair finish tags advance at
-    1/priority per admitted chunk, so the device boundary crossings
+    admits dispatches ~2:1 — the weighted-fair finish tags advance at
+    1/priority per admitted dispatch, so the device boundary crossings
     track the weights."""
     from tidb_tpu.lifecycle import ResourceGroupRegistry
 

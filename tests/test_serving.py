@@ -214,6 +214,10 @@ def test_status_reports_compiled_caches(sess):
     import tidb_tpu.serving.batcher  # noqa: F401 — registers its cache
     from tidb_tpu.server.http_status import StatusServer
 
+    # a mesh statement of this test's own: whichever tests ran before
+    # on this worker, the mesh program cache holds at least its program
+    sess.execute("set tidb_use_tpu = 1")
+    assert len(sess.query("select g, sum(x) from t group by g")) == 5
     srv = StatusServer(sess.domain, port=0)
     host, port = srv.start()
     try:
@@ -224,7 +228,7 @@ def test_status_reports_compiled_caches(sess):
         srv.stop()
     caches = body["compiled_programs"]
     assert "tile" in caches and "mesh" in caches and "microbatch" in caches
-    assert caches["mesh"]["size"] >= 1  # the module's queries compiled
+    assert caches["mesh"]["size"] >= 1
 
 
 # ---------------------------------------------------------------------------

@@ -790,10 +790,7 @@ def test_streamed_filter_finishing_is_spanned(env, case):
 
 
 @pytest.mark.parametrize("kind", sorted(MESH) + ["root_selection"])
-def test_recorder_off_same_rows_nothing_recorded(env, kind, monkeypatch):
-    # one chunk in both runs: the chunk count follows the estimator's
-    # timing, and the last digit of a double sum follows the chunk count
-    monkeypatch.setenv("TIDB_TPU_DISPATCH_CHUNK_ROWS", "0")
+def test_recorder_off_same_rows_nothing_recorded(env, kind):
     d, s = env
     sql = MESH.get(kind) or STREAMED[kind][0]
     want = s.query(sql)

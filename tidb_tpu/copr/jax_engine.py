@@ -846,11 +846,8 @@ def run_base_jax(table, dag: DAG, start: int, end: int,
     topn_parts: List[Chunk] = []
     remaining_limit = an.limit
 
-    import time as _time
-
     from ..lifecycle import chunk_admission, scope_check
     from ..store.fault import FAILPOINTS
-    from .chunking import observe_chunk
 
     devices = _tile_devices()
     used_ids: set = set()
@@ -864,9 +861,9 @@ def run_base_jax(table, dag: DAG, start: int, end: int,
         if t0 >= t1:
             continue
         tile_idx = tile_start // TILE
-        # the tile loop IS the chunk sequence on the fallback path: each
-        # tile re-acquires resource-group admission and feeds the same
-        # chunk telemetry the mesh dispatcher uses
+        # each tile is a dispatch of its own on the fallback path: the
+        # mesh dispatcher's pre-dispatch failpoint, and resource-group
+        # admission re-acquired per tile below
         FAILPOINTS.hit("copr/chunk_dispatch", kind="tile", chunk=tile_idx,
                        total=0, start=t0, end=t1)
         # tiles are ALWAYS the aligned, device-cached arrays; the region
@@ -906,13 +903,10 @@ def run_base_jax(table, dag: DAG, start: int, end: int,
         dattr["hbm_bytes"] = DEVICE_CACHE._c._bytes
         compiled_now = False
         if kind == "filter":
-            td0 = _time.perf_counter()
             with span(dspan, kind=kind, tile=tile_idx, **dattr):
                 with chunk_admission():
                     m, outs = fn(datas, valids, lo, hi, del_mask,
                                  *pextra)
-            observe_chunk("tile", (_time.perf_counter() - td0) * 1000.0,
-                          int(t1 - t0))
             with span("copr.readback") as rsp:
                 mh = _np_tree(m)
                 rsp.set(bytes=mh.nbytes)
@@ -939,13 +933,10 @@ def run_base_jax(table, dag: DAG, start: int, end: int,
                 if remaining_limit <= 0:
                     break
         elif kind == "agg":
-            td0 = _time.perf_counter()
             with span(dspan, kind=kind, tile=tile_idx, **dattr):
                 with chunk_admission():
                     gcount, results = fn(datas, valids, lo, hi, del_mask,
                                          *pextra)
-            observe_chunk("tile", (_time.perf_counter() - td0) * 1000.0,
-                          int(t1 - t0))
             with span("copr.readback") as rsp:
                 gh = _np_tree(gcount)
                 rh = [(t, _np_tree(r)) for t, r in results]
@@ -955,13 +946,10 @@ def run_base_jax(table, dag: DAG, start: int, end: int,
             agg_accum = _merge_device_agg(agg_accum, gh, rh, table, an,
                                           base0)
         else:  # topn
-            td0 = _time.perf_counter()
             with span(dspan, kind=kind, tile=tile_idx, **dattr):
                 with chunk_admission():
                     idx, cnt = fn(datas, valids, lo, hi, del_mask,
                                   *pextra)
-            observe_chunk("tile", (_time.perf_counter() - td0) * 1000.0,
-                          int(t1 - t0))
             with span("copr.readback") as rsp:
                 idx = _np_tree(idx)[: int(cnt)]
                 rsp.set(bytes=idx.nbytes)

@@ -27,7 +27,6 @@ Tests run this on 8 virtual CPU devices (tests/conftest.py); the driver's
 from __future__ import annotations
 
 import threading
-import time
 import zlib
 from typing import Dict, List, Optional, Tuple
 
@@ -1885,104 +1884,42 @@ def _run_mesh_once(storage, req: CopRequest, tid: int,
                               lvals=lvals,
                               split_label=plan.reason_label)
 
-    from ..lifecycle import dispatch_admission, scope_check
-    from .chunking import chunk_bounds, chunk_budget_rows, observe_chunk
-
     chunks: List[Chunk] = []
-    agg_accum = None
     topn_parts: List[Chunk] = []
     if bounds:
-        # cancellation seam around the fused dispatch sequence (a
-        # dispatch in flight runs to completion; an expired statement
-        # must not start the next chunk or proceed to the host merge)
-        scope_check()
-        # deterministic mid-scan fault injection: the chaos harness kills
-        # virtual device k / exhausts HBM exactly here, pre-dispatch
-        FAILPOINTS.hit("mesh/device_error", kind=kind,
-                       device_ids=mesh_ids, start=bounds[0][0],
-                       end=bounds[-1][1])
-        FAILPOINTS.hit("mesh/hbm_oom", kind=kind, start=bounds[0][0],
-                       end=bounds[-1][1])
-        _check_membership_epoch()
-        # interruptible chunked dispatch (ISSUE 17): re-launch the SAME
-        # compiled program over range-slot sub-bounds sized to the
-        # tidb_tpu_dispatch_chunk_ms budget — the chunk count rides the
-        # runtime operands only, never the fingerprint.  Partial states
-        # fold across chunks exactly as multi-range results always did:
-        # sort-agg chunks are root-merged partials, dense agg
-        # accumulates via _merge_mesh_agg, TopN keeps every chunk's
-        # device top-k candidates for the host's final pick.
-        sub_bounds = chunk_bounds(bounds, chunk_budget_rows(kind),
-                                  MESH_RANGE_SLOTS)
-        n_chunks = len(sub_bounds)
-
-        def _chunk_dispatch(ci, sub):
-            if ci:
-                # between-chunk seam: KILL/timeout/mem-quota/shutdown
-                # interrupt here, bounding latency by one chunk budget
-                scope_check()
-            FAILPOINTS.hit("copr/chunk_dispatch", kind=kind, chunk=ci,
-                           total=n_chunks, start=sub[0][0],
-                           end=sub[-1][1])
-            rows = sum(hi - lo for lo, hi in sub)
-            t0 = time.perf_counter()
-            with span("copr.chunk", kind=kind, chunk=ci, rows=rows):
-                # admission re-acquired per chunk: a depleted resource
-                # group yields the device at every chunk boundary
-                with dispatch_admission(DISPATCH_LOCK):
-                    out = fn(datas, valids, del_mask, sub, lvals, pargs,
-                             scalars)
-            # the budget learns from the rows the device SCANNED: the
-            # program masks rows outside `sub`, it does not skip them, so
-            # a dispatch costs one pass over the resident table whatever
-            # its bounds (fed the chunk's rows the estimate shrank run
-            # over run whenever a pass outlasted the budget)
-            observe_chunk(kind, (time.perf_counter() - t0) * 1000.0,
-                          int(del_mask.size))
-            return out
-
+        out = _dispatch_once(kind, fn, datas, valids, del_mask, bounds,
+                             lvals, pargs, scalars, mesh_ids)
         if kind == "agg" and an.agg_mode == "sort":
             try:
-                for ci, sub in enumerate(sub_bounds):
-                    out = _chunk_dispatch(ci, sub)
-                    chunks.extend(_sort_agg_chunks(out, table, an))
+                chunks.extend(_sort_agg_chunks(out, table, an))
             except MeshAggOverflow as e:
                 # data-dependent, by-design: too many distinct groups per
                 # shard.  Re-enter the fused mesh with the AGG PEELED to
                 # the host tail (scan+selection stays device-resident and
                 # streamed) instead of dropping the whole fragment to the
                 # per-tile fan-out rung; fragments with no device-worthy
-                # head still take the old host-hash-agg demotion.  Any
-                # earlier chunks' partials are discarded with the local
-                # `chunks` list — the peel re-runs the WHOLE region.
+                # head still take the old host-hash-agg demotion.
                 peeled = _peel_agg_rerun(storage, req, tid, dag, str(e))
                 if peeled is not None:
                     return peeled
                 req.mesh_reject_reason = str(e)
                 return None
         elif kind == "agg":
-            for ci, sub in enumerate(sub_bounds):
-                # wrapped() already unpacked to numpy and merged shard
-                # partials; the accumulator folds disjoint chunk ranges
-                gcount, results = _chunk_dispatch(ci, sub)
-                agg_accum = _merge_mesh_agg(
-                    agg_accum, gcount, results, table, an,
-                )
+            # wrapped() already unpacked to numpy and merged shard
+            # partials
+            chunks.append(je._device_agg_to_chunk(
+                _mesh_agg_accum(*out, table, an), table, an))
         elif kind == "topn":
-            for ci, sub in enumerate(sub_bounds):
-                gidx, cnts, k = _chunk_dispatch(ci, sub)
-                picks = []
-                for s in range(S):
-                    c = int(cnts[s])
-                    if c:
-                        picks.append(gidx[s * k: s * k + c])
-                if picks:
-                    handles = np.concatenate(picks)
-                    topn_parts.append(
-                        table.gather_chunk(list(an.scan.columns),
-                                           handles)
-                    )
-        scope_check()  # post-dispatch seam: expired statements stop here
+            gidx, cnts, k = out
+            picks = []
+            for s in range(S):
+                c = int(cnts[s])
+                if c:
+                    picks.append(gidx[s * k: s * k + c])
+            if picks:
+                topn_parts.append(
+                    table.gather_chunk(list(an.scan.columns),
+                                       np.concatenate(picks)))
 
     # delta rows (committed inserts/updates) go through the CPU engine
     res = _delta_chunk(req, dag, an, inserted)
@@ -1992,17 +1929,13 @@ def _run_mesh_once(storage, req: CopRequest, tid: int,
         else:
             chunks.append(res)
 
-    if kind == "agg":
-        if agg_accum is not None:
-            chunks.insert(0, je._device_agg_to_chunk(agg_accum, table, an))
-    elif kind == "topn":
-        if topn_parts:
-            from .cpu_engine import run_topn
+    if topn_parts:
+        from .cpu_engine import run_topn
 
-            merged = topn_parts[0]
-            for p in topn_parts[1:]:
-                merged = merged.append(p)
-            chunks = [run_topn(an.topn.order_by, an.topn.limit, merged)]
+        merged = topn_parts[0]
+        for p in topn_parts[1:]:
+            merged = merged.append(p)
+        chunks = [run_topn(an.topn.order_by, an.topn.limit, merged)]
 
     from .engine import _merge_tail
 
@@ -2010,6 +1943,37 @@ def _run_mesh_once(storage, req: CopRequest, tid: int,
     # and close any half-open breaker that just survived its probe
     DEVICE_HEALTH.record_success(mesh_ids)
     return [c for c in _merge_tail(dag, chunks) if c.num_rows > 0]
+
+
+def _dispatch_once(kind, fn, datas, valids, del_mask, bounds, lvals, pargs,
+                   scalars, mesh_ids):
+    """The ONE dispatch of a mesh statement: the compiled program over
+    all of the statement's range slots, between two cancellation seams.
+    A dispatch in flight runs to completion and costs one pass over the
+    resident table whatever its bounds (the program masks rows outside
+    them, it does not skip them), so KILL / timeout / a depleted
+    resource group land before it or after it."""
+    from ..lifecycle import dispatch_admission, scope_check
+    from ..trace import span
+
+    scope_check()
+    # deterministic mid-scan fault injection: the chaos harness kills
+    # virtual device k / exhausts HBM exactly here, pre-dispatch
+    start, end = bounds[0][0], bounds[-1][1]
+    FAILPOINTS.hit("mesh/device_error", kind=kind, device_ids=mesh_ids,
+                   start=start, end=end)
+    FAILPOINTS.hit("mesh/hbm_oom", kind=kind, start=start, end=end)
+    _check_membership_epoch()
+    FAILPOINTS.hit("copr/chunk_dispatch", kind=kind, chunk=0, total=1,
+                   start=start, end=end)
+    with span("copr.chunk", kind=kind, chunk=0,
+              rows=sum(hi - lo for lo, hi in bounds)):
+        # resource-group admission per dispatch: a depleted group
+        # waits here, and the dispatch's device time is its charge
+        with dispatch_admission(DISPATCH_LOCK):
+            out = fn(datas, valids, del_mask, bounds, lvals, pargs, scalars)
+    scope_check()  # post-dispatch seam: expired statements stop here
+    return out
 
 
 def _stream_filter(req, table, an, fn, datas, valids, del_mask, inserted,
@@ -2021,84 +1985,52 @@ def _stream_filter(req, table, an, fn, datas, valids, del_mask, inserted,
     kv/kv.go:270).  When the fusion splitter peeled a host tail off the
     fragment, each streamed scan-layout chunk runs the tail through the
     CPU interpreter before it is yielded (copr/fusion.py ladder)."""
-    from ..lifecycle import dispatch_admission, scope_check
+    from ..lifecycle import scope_check
     from ..metrics import REGISTRY
     from ..trace import span
-    from .chunking import chunk_bounds, chunk_budget_rows, observe_chunk
     from .fusion import run_tail
 
-    remaining = an.limit
     if bounds:
-        scope_check()  # seam before the fused dispatch sequence
-        FAILPOINTS.hit("mesh/device_error", kind="filter",
-                       device_ids=mesh_ids, start=bounds[0][0],
-                       end=bounds[-1][1])
-        FAILPOINTS.hit("mesh/hbm_oom", kind="filter", start=bounds[0][0],
-                       end=bounds[-1][1])
-        _check_membership_epoch()
         if tail:
             from .fusion import note_split
 
             note_split(split_label, type(tail[0]).__name__)
-        # interruptible chunked dispatch (ISSUE 17): the packed-mask
-        # program re-launches per sub-bound group — ranges stay
-        # ascending and disjoint, so per-chunk concatenation preserves
-        # handle order and the LIMIT decrements monotonically.
-        sub_bounds = chunk_bounds(bounds, chunk_budget_rows("filter"),
-                                  MESH_RANGE_SLOTS)
-        n_chunks = len(sub_bounds)
-        for ci, sub in enumerate(sub_bounds):
-            if ci:
-                scope_check()  # between-chunk cancellation seam
-            FAILPOINTS.hit("copr/chunk_dispatch", kind="filter",
-                           chunk=ci, total=n_chunks, start=sub[0][0],
-                           end=sub[-1][1])
-            crows = sum(hi - lo for lo, hi in sub)
-            t0 = time.perf_counter()
-            with span("copr.chunk", kind="filter", chunk=ci, rows=crows):
-                with dispatch_admission(DISPATCH_LOCK):
-                    mask = fn(datas, valids, del_mask, sub, lvals, pargs,
-                              scalars)
-            # rows scanned, not rows in bounds: see _chunk_dispatch
-            observe_chunk("filter", (time.perf_counter() - t0) * 1000.0,
-                          int(del_mask.size))
-            # the host's finishing of the chunk, each step under a span
-            # of its own (none of them wraps a yield: the consumer's time
-            # between two slices is the queue's, not this thread's work)
-            with span("copr.select", rows_in=int(mask.size)) as sp:
-                handles = np.flatnonzero(mask)
-                if remaining is not None:
-                    handles = handles[:remaining]
-                    remaining -= len(handles)
-                sp.set(rows=len(handles))
-            for off in range(0, len(handles), STREAM_ROWS):
-                scope_check()  # between streamed host gathers
-                hsub = handles[off: off + STREAM_ROWS]
-                with span("copr.gather", rows=len(hsub)) as sp:
-                    chunk = table.gather_chunk(list(an.scan.columns), hsub)
-                    if an.proj_exprs is not None:
-                        # dict-rewritten exprs expect coded strings; gather
-                        # decodes, so project from the original projection
-                        # IR
-                        chunk = Chunk([
-                            _eval_to_column(p, chunk)
-                            for p in an.projection.exprs
-                        ])
-                    # the arrays' own bytes (an object column counts its
-                    # pointers: no walk over the values)
-                    sp.set(bytes=sum(c.data.nbytes for c in chunk.columns))
-                if tail:
-                    with span("copr.tail", rows_in=chunk.num_rows) as sp:
-                        tcs = run_tail(dag, tail, [chunk], req.aux)
-                        sp.set(rows=sum(tc.num_rows for tc in tcs))
-                    for tc in tcs:
-                        REGISTRY.inc("mesh_stream_chunks_total")
-                        yield tc
-                    continue
-                REGISTRY.inc("mesh_stream_chunks_total")
-                yield chunk
-            if remaining is not None and remaining <= 0:
-                break
+        mask = _dispatch_once("filter", fn, datas, valids, del_mask, bounds,
+                              lvals, pargs, scalars, mesh_ids)
+        # the host's finishing of the dispatch, each step under a span
+        # of its own (none of them wraps a yield: the consumer's time
+        # between two slices is the queue's, not this thread's work)
+        with span("copr.select", rows_in=int(mask.size)) as sp:
+            handles = np.flatnonzero(mask)
+            if an.limit is not None:
+                handles = handles[:an.limit]
+            sp.set(rows=len(handles))
+        for off in range(0, len(handles), STREAM_ROWS):
+            scope_check()  # between streamed host gathers
+            hsub = handles[off: off + STREAM_ROWS]
+            with span("copr.gather", rows=len(hsub)) as sp:
+                chunk = table.gather_chunk(list(an.scan.columns), hsub)
+                if an.proj_exprs is not None:
+                    # dict-rewritten exprs expect coded strings; gather
+                    # decodes, so project from the original projection
+                    # IR
+                    chunk = Chunk([
+                        _eval_to_column(p, chunk)
+                        for p in an.projection.exprs
+                    ])
+                # the arrays' own bytes (an object column counts its
+                # pointers: no walk over the values)
+                sp.set(bytes=sum(c.data.nbytes for c in chunk.columns))
+            if tail:
+                with span("copr.tail", rows_in=chunk.num_rows) as sp:
+                    tcs = run_tail(dag, tail, [chunk], req.aux)
+                    sp.set(rows=sum(tc.num_rows for tc in tcs))
+                for tc in tcs:
+                    REGISTRY.inc("mesh_stream_chunks_total")
+                    yield tc
+                continue
+            REGISTRY.inc("mesh_stream_chunks_total")
+            yield chunk
     DEVICE_HEALTH.record_success(mesh_ids)
     res = _delta_chunk(req, None, an, inserted)
     if res is not None:
@@ -2135,58 +2067,21 @@ def _eval_to_column(expr, chunk: Chunk) -> Column:
     return Column(expr.ftype, v.data, v.validity())
 
 
-def _merge_mesh_agg(accum, gcount: np.ndarray, results, table, an: _Analyzed):
-    """Fold one mesh-run's final arrays into the accum layout
-    `_device_agg_to_chunk` expects (multiple ranges accumulate)."""
-    if accum is None:
-        accum = {"gcount": gcount.copy(), "states": []}
-        first = True
-    else:
-        accum["gcount"] += gcount
-        first = False
-    for si, (tag, r) in enumerate(results):
-        if first:
-            accum["states"].append([tag, None, None])
-        slot = accum["states"][si]
+def _mesh_agg_accum(gcount: np.ndarray, results, table, an: _Analyzed):
+    """One mesh dispatch's final arrays in the accum layout
+    `je._device_agg_to_chunk` expects."""
+    states = []
+    for a, (tag, r) in zip(an.agg.aggs, results):
         if tag == "count":
-            slot[1] = r if slot[1] is None else slot[1] + r
-        elif tag == "sumcount":
-            s, c = r
-            if slot[1] is None:
-                slot[1], slot[2] = s.copy(), c.copy()
-            else:
-                slot[1] += s
-                slot[2] += c
-        elif tag == "minmax":
-            v, c = r
-            if slot[1] is None:
-                slot[1], slot[2] = v.copy(), c.copy()
-            else:
-                a = an.agg.aggs[si]
-                pick = np.minimum if a.name == "min" else np.maximum
-                have_old = slot[2] > 0
-                have_new = c > 0
-                both = have_old & have_new
-                slot[1] = np.where(both, pick(slot[1], v),
-                                   np.where(have_new, v, slot[1]))
-                slot[2] += c
+            states.append([tag, r, None])
         elif tag == "argfirst":
-            # r: per-group min global row index (sentinel >= base_rows when
-            # the group is empty in this range)
-            arg = an.agg.aggs[si].args[0]
-            vals, valid = _resolve_first_global(table, an, arg, r)
-            if slot[1] is None:
-                slot[1], slot[2] = vals, valid
-            else:
-                need = ~slot[2] & valid
-                slot[1] = np.where(need, vals, slot[1])
-                slot[2] = slot[2] | valid
-    return accum
-
-
-def _resolve_first_global(table, an: _Analyzed, arg, idx: np.ndarray):
-    """Resolve global first-row indices to values (host gather)."""
-    return _gather_first_values(table, an, arg, idx, an.num_groups)
+            # r: per-group min global row index (sentinel >= base_rows
+            # when the group is empty)
+            states.append([tag, *_gather_first_values(
+                table, an, a.args[0], r, an.num_groups)])
+        else:  # sumcount / minmax: (values, counts)
+            states.append([tag, *r])
+    return {"gcount": gcount, "states": states}
 
 
 def _gather_first_values(table, an: _Analyzed, arg, idx: np.ndarray, G: int):

@@ -5,7 +5,7 @@ The mechanics deliberately reuse the storage engine instead of growing a
 parallel one: partition p of table T becomes a REAL `TableStore` under a
 synthetic table id, attached to the host's `BlockStorage` — so the
 device scan path, the CPU oracle, delta overlays, region routing and the
-chunked dispatch seams all work on partitions unchanged
+dispatch seams all work on partitions unchanged
 (`run_dag_on_region` resolves the store from the range's table id, never
 the DAG's).  The partition slice keeps the source table's sorted string
 dictionaries and ingests pre-coded int32 codes (`bulk_load_arrays`

@@ -3,9 +3,10 @@
 Reference: TiDB's resource-control subsystem (`CREATE RESOURCE GROUP
 ... RU_PER_SEC = n [BURSTABLE]`, user->group binding, the runaway
 QUERY_LIMIT watchdog) — here the contended resource is the accelerator
-itself, so one RU is one *device chunk-millisecond*.  Every chunked
-dispatch (copr mesh/tile loops, MPP rungs, the serving micro-batcher)
-passes through `dispatch_admission` between chunks:
+itself, so one RU is one *device millisecond*.  Every device dispatch
+(a mesh statement's one program, each tile of the tile loop, each MPP
+rung, each micro-batch) passes through `dispatch_admission`, so groups
+are admitted and charged per dispatch:
 
 * **admit** — refill the statement's group by wall-clock elapsed x
   RU_PER_SEC and require a non-negative balance.  A depleted
@@ -14,7 +15,7 @@ passes through `dispatch_admission` between chunks:
   statement) up to a bounded budget, then raises the typed retriable
   `ResourceGroupThrottled`.  A depleted *burstable* group proceeds on
   debt — unless another group with a positive balance is waiting to
-  dispatch, in which case it yields the device at this chunk boundary
+  dispatch, in which case it yields the device before this dispatch
   (the weighted-fair property: when quotas bind, device share tracks
   the RU_PER_SEC ratio because each group can only spend what its
   refill rate grants).
@@ -55,13 +56,13 @@ DEFAULT_GROUP = "default"
 _MAX_WAIT_MS_ENV = "TIDB_TPU_RESGROUP_MAX_WAIT_MS"
 _DEFAULT_MAX_WAIT_MS = 2000.0
 
-#: admission poll period — short enough that KILL latency stays
-#: chunk-budget-bounded, long enough to not spin
+#: admission poll period — short enough that a KILL reaches a parked
+#: statement within milliseconds, long enough to not spin
 _POLL_S = 0.005
 
 #: a group counts as *contending* for the weighted-fair gate while a
 #: thread is parked at its admission OR it dispatched this recently —
-#: back-to-back chunk loops never park, so recency is what makes two
+#: back-to-back dispatches never park, so recency is what makes two
 #: busy statements visible to each other
 _CONTEND_S = 0.05
 
@@ -79,8 +80,8 @@ class ResourceGroup:
 
     Token state is guarded by the owning registry's mutex (one lock for
     the whole control plane: group counts are tiny and the hot path
-    touches it twice per chunk).  Balance may go negative — burstable
-    debt and the unavoidable overshoot of charging *after* a chunk
+    touches it twice per dispatch).  Balance may go negative — burstable
+    debt and the unavoidable overshoot of charging *after* a dispatch
     completes — and is repaid from refill before new work admits.
     """
 
@@ -125,7 +126,7 @@ class ResourceGroup:
         if self._tokens > 0:
             return True
         if self.burstable:
-            # debt allowed — but yield the chunk boundary to any group
+            # debt allowed — but yield this dispatch to any group
             # that has budget and is waiting for the device
             return not self._reg._tokenful_waiters_locked(self)
         return False
@@ -145,8 +146,8 @@ class ResourceGroup:
 
     # ---- admission / charge ---------------------------------------------
     def admit(self, scope) -> float:
-        """Block (interruptibly) until this group may dispatch one more
-        chunk; returns the milliseconds spent throttled.  Raises the
+        """Block (interruptibly) until this group may dispatch once
+        more; returns the milliseconds spent throttled.  Raises the
         scope's termination error if cancelled while waiting, or
         ResourceGroupThrottled past the bounded refill wait."""
         mu = self._reg._mu
@@ -246,8 +247,8 @@ class ResourceGroupRegistry:
     def _priority_turn_locked(self, g: ResourceGroup,
                               now: float) -> bool:
         """Weighted-fair admission order (start-time fair queueing over
-        unit chunks): a request's start tag is max(virtual clock, the
-        group's finish tag), each admitted chunk advances the finish
+        unit dispatches): a request's start tag is max(virtual clock, the
+        group's finish tag), each admitted dispatch advances the finish
         tag by 1/PRIORITY, and a group dispatches only while no
         *contending* group holds a smaller start tag — so under
         sustained contention admissions track the priority ratio, and a
@@ -466,7 +467,7 @@ def scope_group(scope) -> Optional[ResourceGroup]:
 
 @contextmanager
 def dispatch_admission(lock):
-    """ONE chunk's trip through the device door: weighted-fair
+    """ONE dispatch's trip through the device door: weighted-fair
     admission against the statement's resource group, then `lock`
     (DISPATCH_LOCK), then — after release — charge the measured device
     time.  With no group bound this degenerates to `with lock:` plus
@@ -477,8 +478,8 @@ def dispatch_admission(lock):
     lock-order edges appear.
 
     The clock starts INSIDE the lock: the tenant is billed for measured
-    device time on its chunk, never for sitting in the DISPATCH_LOCK
-    queue behind other tenants' chunks — queue time is the scheduler's
+    device time on its dispatch, never for sitting in the DISPATCH_LOCK
+    queue behind other tenants' dispatches — queue time is the scheduler's
     cost, and billing it would make one tenant's burst drain everyone
     else's RU budget.  That queue time is the trace's
     ``copr.dispatch.wait`` span: from asking for `lock` to holding it

@@ -11,8 +11,8 @@ Here the TCR is a black-box batch device (PAPERS.md, "Query Processing
 on Tensor Computation Runtimes"): an in-flight XLA dispatch cannot be
 interrupted, so the *host-side* seams around each dispatch are the only
 cancellation points we control.  Every blocking seam — backoff sleeps,
-the distsql per-task loop, copr mesh/tile chunk loops, MPP rung
-transitions, 2PC prewrite batches, DDL backfill batches — checks ONE
+the distsql per-task loop, the copr mesh dispatch and tile loop, MPP
+rung transitions, 2PC prewrite batches, DDL backfill batches — checks ONE
 QueryScope between units of device work, so `KILL`, max_execution_time,
 memory cancel, admission overload and server drain all ride the same
 mechanism and report one termination reason.
@@ -66,7 +66,7 @@ class QueryScope:
         # resource-group binding (lifecycle/resgroup.py): the session
         # resolves the statement's group once at execute() and fan-out
         # workers inherit it via attach_scope — the dispatcher charges
-        # device time against it per chunk
+        # device time against it per dispatch
         self.resgroup: Optional[str] = None
         self._device_ms = 0.0
 

@@ -651,9 +651,7 @@ def _clone_expr(e):
 def _run_tree_once(storage, spec: MPPJoinTreeSpec, modes: List[str],
                    boosts: List[int]) -> List[Chunk]:
     import os as _os
-    import time as _time
 
-    from ..copr.chunking import observe_chunk
     from ..lifecycle import dispatch_admission, scope_check
     from ..trace import annotate, span
 
@@ -786,14 +784,11 @@ def _run_tree_once(storage, spec: MPPJoinTreeSpec, modes: List[str],
                        _bounds_args(bs.bounds))
         _check_membership_epoch()
         scope_check()
-        t0 = _time.perf_counter()
         with span("mpp.rung", idx=r, rung=mode, kind=rung.kind,
                   elided=int(elide), build_table=bs.side.table_id):
             with dispatch_admission(DISPATCH_LOCK):
                 overflow, jover, out_slots, keep = fn(*args)
             overflow, jover = int(overflow), int(jover)
-        observe_chunk("mpp", (_time.perf_counter() - t0) * 1000.0,
-                      OUT_CHUNK_ROWS)
         if overflow:
             raise MPPTreeOverflow(
                 r, "partition",

@@ -280,15 +280,11 @@ def _run_batch(ctx: dict, live: List[_Member]):
             # time is charged to the LEADER's group — followers ride
             # free (matching TiDB, where the runaway/RU ledger bills
             # the session that issued the physical request)
-            from ..copr.chunking import observe_chunk
             from ..lifecycle import chunk_admission
 
-            bt0 = time.perf_counter()
             with span("copr.device.execute", batch=B, tile=tile_idx):
                 with chunk_admission():
                     out = vfn(datas, valids, lo, hi, del_mask, PI, PF)
-            observe_chunk("batch", (time.perf_counter() - bt0) * 1000.0,
-                          int(t1 - t0))
             if kind == "agg":
                 gcount, results = out
                 with span("copr.readback") as rsp:

@@ -166,7 +166,7 @@ class Session:
             # per-statement resource group (ISSUE 17): resolved ONCE at
             # scope creation (sysvar wins, then the user's ALTER USER
             # binding, then default); the group OBJECT rides the scope
-            # so chunked dispatchers and fan-out workers never need a
+            # so dispatchers and fan-out workers never need a
             # domain lookup
             sc.resgroup = self.domain.resgroups.resolve(
                 self.user, self.vars.get("tidb_tpu_resource_group") or "")
@@ -864,8 +864,8 @@ class Session:
                     extra = (extra + " " if extra else "") \
                         + f"hbm_peak:{peak}"
                     rows[0] = (nm, est, task, info, extra)
-                # chunked-dispatch visibility (ISSUE 17): how many
-                # device launches the statement's fragments split into
+                # how many mesh dispatches the statement made (one per
+                # mesh program run, so one per partition store)
                 nchunks = tot.get("chunks", 0)
                 if nchunks:
                     nm, est, task, info, extra = rows[0]
@@ -945,16 +945,6 @@ class Session:
                 # serving knobs configure a process-wide resource (the
                 # batcher / bucket policy), mirroring max_connections
                 serving.refresh_from_vars(self.vars)
-            if name.lower() == "tidb_tpu_dispatch_chunk_ms":
-                # the dispatchers read a process knob (like the serving
-                # sysvars): GLOBAL or SESSION set both retarget it —
-                # chunking guards a shared device, not a session
-                from ..copr.chunking import set_dispatch_chunk_ms
-
-                try:
-                    set_dispatch_chunk_ms(float(value))
-                except (TypeError, ValueError):
-                    pass
         return ResultSet()
 
     def _snapshot_write_guard(self, s):

@@ -132,14 +132,9 @@ SYSVAR_DEFAULTS = {
     # server-level resource, like max_connections).
     "tidb_tpu_microbatch_window_ms": ("0", "int"),
     "tidb_tpu_microbatch_max": ("32", "int"),
-    # interruptible chunked dispatch (ISSUE 17): target device ms per
-    # chunk; oversized fragments split into range-slot re-launches of
-    # the same compiled program, with KILL/quota checks and
-    # resource-group admission between chunks.  0 disables (one
-    # dispatch per fragment, the pre-chunking behavior).
-    "tidb_tpu_dispatch_chunk_ms": ("100", "int"),
-    # the session's resource group; empty = the user's binding
-    # (ALTER USER ... RESOURCE GROUP) or "default"
+    # the session's resource group (admitted and charged per dispatch);
+    # empty = the user's binding (ALTER USER ... RESOURCE GROUP) or
+    # "default"
     "tidb_tpu_resource_group": ("", "str"),
     # --- TPU-native knobs ---------------------------------------------
     "tidb_use_tpu": ("1", "bool"),  # per-session engine routing (cpu|tpu)

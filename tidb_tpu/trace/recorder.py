@@ -253,8 +253,8 @@ class QueryTrace:
             elif n == "cop.task":
                 tot["cop_tasks"] += 1
             elif n == "copr.chunk":
-                # chunked-dispatch visibility (ISSUE 17): per-statement
-                # device-launch count for EXPLAIN ANALYZE / slow log
+                # the statement's mesh dispatches (one per mesh program
+                # run) for EXPLAIN ANALYZE / slow log
                 tot["chunks"] += 1
             elif n.startswith("wire."):
                 tot["wire_bytes"] += int(a.get("bytes", 0))
@@ -302,7 +302,7 @@ PHASES = {
     "txn.commit": "commit_ms",
     # online DDL index builds (ddl.backfill spans per batch)
     "ddl.backfill": "backfill_ms",
-    # resource-group admission wait between chunked dispatches
+    # resource-group admission wait before a dispatch
     "resgroup.throttle": "throttle_ms",
 }
 
