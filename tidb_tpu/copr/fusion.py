@@ -109,14 +109,19 @@ class RegionContext:
 
     an: object                  # jax_engine._Analyzed of the fused region
     cols: dict                  # col index -> (data, valid) device arrays
-    n: int                      # vector length (TILE or shard-local rows)
-    mask: object                # live-row bool vector
+    n: object                   # the rows' shape: TILE, the shard-local
+    #                             row count, or a shard's row view (a tuple)
+    mask: object                # live-row bool array of that shape
     axis: Optional[str] = None  # collective axis under shard_map, else None
     gofs: object = None         # global row offsets (mesh), else None
     n_global: int = 0           # total rows across shards (argfirst sentinel)
+    flat: object = None         # a row-shaped array in row order, 1-D
 
     def psum(self, x):
         return jax.lax.psum(x, self.axis) if self.axis is not None else x
+
+    def in_row_order(self, x):
+        return x.reshape(-1) if self.flat is None else self.flat(x)
 
 
 def selection_mask(ctx: RegionContext):
@@ -132,19 +137,248 @@ def selection_mask(ctx: RegionContext):
 
 def dense_group_codes(ctx: RegionContext):
     """Emit mixed-radix dense group codes; NULL key rows drop from the
-    mask (NULL keys are excluded by _Analyzed's dense-mode gate)."""
+    mask (NULL keys are excluded by _Analyzed's dense-mode gate).  The
+    codes are int32 (the code space is capped at MAX_GROUPS), taken
+    straight from the column's wire array where its statistics allow."""
     an = ctx.an
-    gidx = jnp.zeros(ctx.n, dtype=jnp.int64)
+    wire = ctx.cols.get("__wire__", {})
+    gidx = jnp.zeros(ctx.n, dtype=jnp.int32)
     stride = 1
     m = ctx.mask
     for kcol, (klo, card) in zip(an.group_cols, an.group_card):
         d, v = ctx.cols[kcol]
-        code = jnp.clip(d.astype(jnp.int64) - klo, 0, card - 1)
+        w = wire.get(kcol)
+        if w is not None and max(abs(klo), abs(klo + card)) < 1 << 30:
+            code = jnp.clip(w.astype(jnp.int32) - klo, 0, card - 1)
+        else:
+            code = jnp.clip(d.astype(jnp.int64) - klo, 0,
+                            card - 1).astype(jnp.int32)
         gidx = gidx + code * stride
         m = m & v
         stride *= card
     ctx.mask = m
     return gidx
+
+
+#: rows in one block of a dense aggregate's first level.  A limb is an
+#: int32 within +-AGG_LIMB, so a block's sum cannot pass int32.
+AGG_BLOCK = 1 << 14
+AGG_LIMB = ((1 << 31) - 1) // AGG_BLOCK
+#: operands of one variadic reduce.  The v5e compiler keeps TPC-H Q1's 60
+#: one fusion that reads the columns once; given 220 it cuts the fusion
+#: itself and writes operands out in full (the program no longer fits the
+#: chip).  Past the cap an aggregate is a few reduces: a pass each over
+#: the columns its operands read, not over all.
+AGG_REDUCE_OPERANDS = 64
+
+
+def _agg_wire(an, arrays=None) -> dict:
+    """What `jax_eval.bounded_int` reads: scan column -> (wire array, lo,
+    hi, nullable) over `an.agg_bounds`; without `arrays`, the dry run."""
+    from .jax_eval import DRY
+
+    return {ci: (DRY if arrays is None else arrays[ci], lo, hi, nullable)
+            for ci, (lo, hi, nullable) in an.agg_bounds.items()
+            if arrays is None or ci in arrays}
+
+
+def _int_state(a) -> bool:
+    from ..types import TypeKind
+
+    return (a.name in ("sum", "avg")
+            and a.partial_types()[0].kind != TypeKind.FLOAT)
+
+
+def agg_lanes(an) -> str:
+    """How a dense aggregate's integer sums ride the first level, one
+    entry a distinct argument in the order the statement names them:
+    `i32:<limbs>` where the column statistics bound the argument (int32
+    arithmetic from the wire arrays), `i64:4` where they do not (int64
+    arithmetic, four 16-bit limbs).  Part of the program's fingerprint;
+    'scatter' where the group space is past `ops.UNROLL_G` and the sums
+    stay `jax.ops.segment_sum`."""
+    from .ir import serialize_expr
+    from .jax_eval import bounded_int, lane_limbs
+
+    if an.agg_lanes is not None:
+        return an.agg_lanes
+    if an.num_groups > ops.UNROLL_G:
+        out = "scatter"
+    else:
+        wire, seen = _agg_wire(an), {}
+        for a in an.agg.aggs:
+            if not _int_state(a):
+                continue
+            key = str(serialize_expr(a.args[0]))
+            if key not in seen:
+                v = bounded_int(a.args[0], wire)
+                seen[key] = ("i64:4" if v is None else
+                             f"i32:{len(lane_limbs(v, AGG_LIMB))}")
+        out = ",".join(seen.values())
+    an.agg_lanes = out
+    return out
+
+
+def compile_attrs(an, kind: str) -> dict:
+    """What a `copr.compile` span says of the program beyond its kind:
+    a dense aggregate's lanes."""
+    if kind == "agg" and an.agg_mode == "dense":
+        return {"agg_lanes": agg_lanes(an)}
+    return {}
+
+
+def note_agg_dispatch(an):
+    """Count one dispatched dense aggregate by whether every summed
+    argument was bounded."""
+    from ..metrics import REGISTRY
+
+    lanes = agg_lanes(an)
+    if "i64" in lanes or lanes == "scatter":
+        REGISTRY.inc("copr_agg_wide_total")
+    else:
+        REGISTRY.inc("copr_agg_narrow_total")
+
+
+def _add_lanes(a, b):
+    """The variadic reduce's computation (module-level: its name is in
+    the jaxpr's text, which kernelcheck compares between traces)."""
+    return tuple(x + y for x, y in zip(a, b))
+
+
+class _BlockSums:
+    """The integer sums and counts of one small-G dense aggregate as ONE
+    two-level reduction.  First level: every limb of every distinct
+    (argument, validity), masked to each group, in one variadic
+    `jax.lax.reduce` over blocks of at most AGG_BLOCK rows with int32
+    accumulators, so the columns are read once.  Second level: the block
+    partials as one stacked array, widened to int64, summed, the limbs
+    recombined by their weights, one psum.
+
+    An operand of a variadic reduce is merged with no other by anyone,
+    so equal requests share a slot here: `sum(x)` and `avg(x)`, and every
+    count over rows that cannot be NULL."""
+
+    def __init__(self, ctx: RegionContext, gidx):
+        self.ctx, self.gidx = ctx, gidx
+        self.wire = _agg_wire(ctx.an, ctx.cols.get("__wire__", {}))
+        self.valids = {frozenset(): None}   # validity key -> array
+        self.counts = {}                    # validity key -> slot
+        self.sums = {}                      # (argument, scale) -> slot
+        self.slots = []                     # (limbs, const, mul, vkey)
+        self.out = None
+        self.count(frozenset())
+
+    def _vkey(self, vcols):
+        """Validity of a bounded value: the AND of its NULLable columns'."""
+        if vcols not in self.valids:
+            v = None
+            for ci in sorted(vcols):
+                cv = self.ctx.cols[ci][1]
+                v = cv if v is None else v & cv
+            self.valids[vcols] = v
+        return vcols
+
+    def valid_of(self, expr, v):
+        """Key of the validity `v` that compile_expr gave `expr`: a plain
+        column that cannot be NULL shares the row count."""
+        from ..expr.expression import ColumnExpr
+        from .ir import serialize_expr
+
+        if isinstance(expr, ColumnExpr) and expr.index in self.wire:
+            return self._vkey(frozenset([expr.index])
+                              if self.wire[expr.index][3] else frozenset())
+        key = str(serialize_expr(expr))
+        self.valids.setdefault(key, v)
+        return key
+
+    def count(self, vkey) -> int:
+        if vkey not in self.counts:
+            self.counts[vkey] = len(self.slots)
+            self.slots.append(([(1, 0)], 0, 1, vkey))
+        return self.counts[vkey]
+
+    def sum(self, expr, state_ft):
+        """Slots of (sum of `expr` in the state's scale, its count)."""
+        from ..types import TypeKind
+        from .ir import serialize_expr
+        from .jax_eval import bounded_int, lane_limbs
+
+        ft = expr.ftype
+        mul = 10 ** (state_ft.scale
+                     - (ft.scale if ft.kind == TypeKind.DECIMAL else 0))
+        key = (str(serialize_expr(expr)), mul)
+        if key not in self.sums:
+            v = bounded_int(expr, self.wire)
+            if v is not None:
+                vkey = self._vkey(v.vcols)
+                limbs, const = lane_limbs(v, AGG_LIMB), v.const
+            else:
+                d, valid = compile_expr(expr, self.ctx.cols, self.ctx.n)
+                d = d.astype(jnp.int64)
+                vkey = self.valid_of(expr, valid)
+                limbs = [(((d >> s) & 0xFFFF if s < 48 else d >> s)
+                          .astype(jnp.int32), s) for s in (0, 16, 32, 48)]
+                const = 0
+            cnt = self.count(vkey)   # may take the next slot itself
+            self.sums[key] = (len(self.slots), cnt)
+            self.slots.append((limbs, const, mul, vkey))
+        return self.sums[key]
+
+    def run(self):
+        ctx, G = self.ctx, self.ctx.an.num_groups
+        sel = {}
+        for vkey, v in self.valids.items():
+            mv = ctx.mask if v is None else ctx.mask & v
+            sel[vkey] = [mv if G == 1 else mv & (self.gidx == g)
+                         for g in range(G)]
+        operands = []
+        for limbs, _c, _m, vkey in self.slots:
+            for x, _s in limbs:
+                for g in range(G):
+                    s = sel[vkey][g]
+                    operands.append(_blocks(
+                        s.astype(jnp.int32) if isinstance(x, int)
+                        else jnp.where(s, x, 0)))
+        parts = []
+        for i in range(0, len(operands), AGG_REDUCE_OPERANDS):
+            some = tuple(operands[i: i + AGG_REDUCE_OPERANDS])
+            parts += jax.lax.reduce(some, (jnp.int32(0),) * len(some),
+                                    _add_lanes, [some[0].ndim - 1])
+        # second level, tiny: [limbs * G, blocks] -> [limbs, G], then every
+        # slot at once as weights @ sums, the weights being each limb's
+        # 2**shift, the slot's constant on its count's limb, and the
+        # state's rescale (int64 wraps as the sums it replaces did)
+        sums = jnp.stack([p.reshape(-1) for p in parts]) \
+            .reshape(-1, G, parts[0].size).astype(jnp.int64).sum(axis=2)
+        weights = [[0] * sums.shape[0] for _ in self.slots]
+        first, at = [], 0
+        for row, (limbs, const, mul, vkey) in zip(weights, self.slots):
+            first.append(at)
+            for _x, s in limbs:
+                row[at] = mul << s
+                at += 1
+            row[first[self.counts[vkey]]] += mul * const
+        weights = np.array(
+            [[(w + (1 << 63)) % (1 << 64) - (1 << 63) for w in row]
+             for row in weights], dtype=np.int64)
+        weights = np.broadcast_to(weights[:, :, None], weights.shape + (G,))
+        out = ctx.psum((weights * sums[None]).sum(axis=1))
+        self.out = [o.reshape(G) for o in jnp.split(out, len(self.slots))]
+
+    def __getitem__(self, slot):
+        return self.out[slot]
+
+
+def _blocks(x):
+    """Rows as [..., blocks, rows of a block]: the last axis is cut where
+    it is longer than AGG_BLOCK (a 1-D tile, or a shard's rows that the
+    engine did not view as blocks itself)."""
+    import math
+
+    last = x.shape[-1]
+    if last <= AGG_BLOCK:
+        return x if x.ndim > 1 else x.reshape(1, last)
+    return x.reshape(x.shape[:-1] + (-1, math.gcd(last, AGG_BLOCK)))
 
 
 def dense_agg_results(ctx: RegionContext, gidx):
@@ -156,55 +390,74 @@ def dense_agg_results(ctx: RegionContext, gidx):
     row indices.  Per tile (`axis=None`) psum is the identity and
     first_row emits tile-local argfirst indices — the exact layouts each
     engine's host merge consumes.
+
+    With at most `ops.UNROLL_G` groups every count and every sum of an
+    integer or decimal state is a slot of ONE `_BlockSums` reduction;
+    past that they are `jax.ops.segment_sum` scatters, one each.  Float
+    sums, min, max and first_row are reductions of their own either way.
     """
+    from ..types import TypeKind
     from .jax_engine import _to_state_dtype
 
     an = ctx.an
     agg_ir = an.agg
     G = an.num_groups
     m = ctx.mask
-    gcount = ctx.psum(ops.masked_segment_count(gidx, m, G))
-    results = []
+    block = _BlockSums(ctx, gidx) if G <= ops.UNROLL_G else None
+
+    def count_of(expr, v):
+        """A thunk of the count of rows where `expr` is not NULL."""
+        if block is not None:
+            slot = block.count(block.valid_of(expr, v))
+            return lambda: block[slot]
+        c = ctx.psum(ops.masked_segment_count(gidx, m & v, G))
+        return lambda: c
+
+    if block is None:
+        gcount = ctx.psum(ops.masked_segment_count(gidx, m, G))
+    thunks = []
     for a in agg_ir.aggs:
-        if a.name == "count":
-            if a.args:
-                d, v = compile_expr(a.args[0], ctx.cols, ctx.n)
-                results.append(
-                    ctx.psum(ops.masked_segment_count(gidx, m & v, G)))
-            else:
-                results.append(gcount)
+        if a.name == "count" and not a.args:
+            thunks.append(None)
+            continue
+        st = a.partial_types()[0]
+        if block is not None and _int_state(a):
+            si, ci = block.sum(a.args[0], st)
+            thunks.append(lambda si=si, ci=ci: (block[si], block[ci]))
             continue
         d, v = compile_expr(a.args[0], ctx.cols, ctx.n)
         mv = m & v
-        if a.name in ("sum", "avg"):
-            st = a.partial_types()[0]
-            # NOTE: int64 accumulation measured FASTER than f64 on v5e
-            # (192ms vs 244ms Q1@64M in-process A/B) — keep the
-            # carry-chain emulation, it beats convert+f64 adds
+        if a.name != "first_row":
+            cnt = count_of(a.args[0], v)
+        if a.name == "count":
+            thunks.append(cnt)
+        elif a.name in ("sum", "avg"):
             dd = _to_state_dtype(d, a.args[0].ftype, st)
-            results.append((
-                ctx.psum(ops.masked_segment_sum(dd, gidx, mv, G)),
-                ctx.psum(ops.masked_segment_count(gidx, mv, G)),
-            ))
-        elif a.name == "min":
-            results.append((
-                ops.masked_segment_min(d, gidx, mv, G),
-                ctx.psum(ops.masked_segment_count(gidx, mv, G)),
-            ))
-        elif a.name == "max":
-            results.append((
-                ops.masked_segment_max(d, gidx, mv, G),
-                ctx.psum(ops.masked_segment_count(gidx, mv, G)),
-            ))
+            if st.kind == TypeKind.FLOAT:
+                # a float sum keeps its order of additions: row order
+                dd, gi, mv = (ctx.in_row_order(x) for x in (dd, gidx, mv))
+            else:
+                gi = gidx
+            s = ctx.psum(ops.masked_segment_sum(dd, gi, mv, G))
+            thunks.append(lambda s=s, cnt=cnt: (s, cnt()))
+        elif a.name in ("min", "max"):
+            red = (ops.masked_segment_min if a.name == "min"
+                   else ops.masked_segment_max)
+            part = red(d, gidx, mv, G)
+            thunks.append(lambda part=part, cnt=cnt: (part, cnt()))
         elif a.name == "first_row":
             if ctx.gofs is not None:
                 # per-shard first GLOBAL row index (sentinel n_global when
                 # the shard has none); host takes the min across shards
                 contrib = jnp.where(mv, ctx.gofs, ctx.n_global)
-                results.append(ops.segment_min(contrib, gidx, G))
+                r = ops.segment_min(contrib, gidx, G)
             else:
-                results.append(ops.masked_segment_argfirst(gidx, mv, G))
-    return gcount, results
+                r = ops.masked_segment_argfirst(gidx, mv, G)
+            thunks.append(lambda r=r: r)
+    if block is not None:
+        block.run()
+        gcount = block[0]
+    return gcount, [gcount if t is None else t() for t in thunks]
 
 
 def topn_key(ctx: RegionContext):

@@ -430,6 +430,11 @@ class _Analyzed:
         self.key_remaps = None
         #: packed lexicographic multi-column TopN spec, else None
         self.topn_pack = None
+        #: dense mode: scan column -> (lo, hi, nullable) for the integer
+        #: columns the aggregates' arguments read (_bound_agg_args)
+        self.agg_bounds: dict = {}
+        #: what the dense emitter makes of them (fusion.agg_lanes), lazily
+        self.agg_lanes: Optional[str] = None
         if self.agg is not None:
             width = len(self.scan.columns)
             for a in self.agg.aggs:
@@ -448,6 +453,7 @@ class _Analyzed:
                         raise JaxUnsupported("first_row over join payload")
             try:
                 self._analyze_dense_keys(table)
+                self._bound_agg_args(table)
             except JaxUnsupported:
                 # high-NDV / float / NULLable / non-column keys: the mesh
                 # engine groups by sorting — keys only need to be
@@ -482,6 +488,7 @@ class _Analyzed:
                 self.num_groups = 0
                 self.group_cols = []
                 self.group_card = []
+                self.agg_bounds = {}
         if self.topn is not None:
             if len(self.topn.order_by) != 1:
                 # exact compound ordering: pack every key's stats-bounded
@@ -554,6 +561,29 @@ class _Analyzed:
         self.group_card = group_card
         self.num_groups = max(g, 1)
 
+    def _bound_agg_args(self, table):
+        """Bounds of the columns under the aggregates' arguments, from the
+        same statistics `_wire_dtype` narrows by, each rounded out to a
+        power of two: the dense emitter (fusion.dense_agg_results) picks
+        int32 or int64 arithmetic and the limbs of every sum from them,
+        so they are in the fingerprint, and a load compiles a new program
+        only when it carries a column past a power of two."""
+        refs: set = set()
+        for a in self.agg.aggs:
+            for x in a.args:
+                x.collect_columns(refs)
+        for i in sorted(refs):
+            if i >= len(self.scan.columns) or self.scan.ftypes[i].kind in (
+                    TypeKind.FLOAT, TypeKind.STRING):
+                continue
+            lo, hi, has_null = table.column_stats(self.scan.columns[i])
+            if hi < lo:
+                lo = hi = 0
+            self.agg_bounds[i] = (
+                min(-(1 << (-lo - 1).bit_length()), 0) if lo < 0 else 0,
+                (1 << hi.bit_length()) - 1 if hi > 0 else 0,
+                bool(has_null))
+
     def needed_cols(self) -> List[int]:
         """Scan-output col indices the device actually needs (payload
         indices from join lookups are aux-fed, not scanned — dropped)."""
@@ -622,6 +652,12 @@ def _fingerprint(an: _Analyzed, kind: str) -> str:
                 for a in an.agg.aggs
             ],
         }
+        if an.agg_mode == "dense":
+            from .fusion import agg_lanes
+
+            # the lanes follow from the bounds; both shape the program
+            payload["agg"]["bounds"] = sorted(an.agg_bounds.items())
+            payload["agg"]["lanes"] = agg_lanes(an)
     if an.topn is not None:
         from ..serving import topn_budget
 
@@ -690,12 +726,16 @@ def _tile_core(an: _Analyzed, kind: str, col_order: List[int],
         env = {
             ci: (datas[j], valids[j]) for j, ci in enumerate(col_order)
         }
+        env["__wire__"] = {ci: datas[j] for j, ci in enumerate(col_order)}
         if with_params and params is not None:
             env["__params__"] = params
-        ar = jnp.arange(n, dtype=jnp.int64)
+        # tile-local row numbers fit int32 lanes (an int64 comparison is
+        # several lane operations on the TPU)
+        ar = jnp.arange(n, dtype=jnp.int32)
         ctx = fusion.RegionContext(
             an=an, cols=env, n=n,
-            mask=(ar >= lo) & (ar < hi) & del_mask)
+            mask=((ar >= jnp.asarray(lo).astype(jnp.int32))
+                  & (ar < jnp.asarray(hi).astype(jnp.int32)) & del_mask))
         fusion.selection_mask(ctx)
         return ctx
 
@@ -828,6 +868,11 @@ def run_base_jax(table, dag: DAG, start: int, end: int,
     fp = (_fingerprint(an, kind) + f"|cols={col_order}"
           + (f"|hp={len(hoisted[0])},{len(hoisted[1])}"
              if hoisted is not None else ""))
+    from .fusion import compile_attrs, note_agg_dispatch
+
+    cattrs = compile_attrs(an, kind)
+    if cattrs:
+        note_agg_dispatch(an)
     fn = _COMPILED.get(fp)
     compiled_now = fn is None
     if fn is None:
@@ -836,7 +881,7 @@ def run_base_jax(table, dag: DAG, start: int, end: int,
         _COMPILED.put(fp, fn)
     else:
         # zero-duration marker: the DAG fingerprint hit the program cache
-        with span("copr.compile", cache="hit", kind=kind):
+        with span("copr.compile", cache="hit", kind=kind, **cattrs):
             pass
 
     del_arr = np.fromiter(sorted(deleted), dtype=np.int64,
@@ -897,7 +942,7 @@ def run_base_jax(table, dag: DAG, start: int, end: int,
         # first post-miss dispatch IS the XLA compile (jit compiles
         # lazily): label it so compile time lands in the compile phase
         dspan = ("copr.compile" if compiled_now else "copr.device.execute")
-        dattr = {"cache": "miss"} if compiled_now else {}
+        dattr = {"cache": "miss", **cattrs} if compiled_now else {}
         # per-trace HBM attribution (ISSUE 13): resident tile-cache
         # bytes at dispatch time ride the execute span
         dattr["hbm_bytes"] = DEVICE_CACHE._c._bytes

@@ -44,7 +44,9 @@ def _np_dtype_for(ft: FieldType):
     return jnp.int64
 
 
-def compile_expr(e: Expression, cols: Dict[int, JVal], n: int) -> JVal:
+def compile_expr(e: Expression, cols: Dict[int, JVal], n) -> JVal:
+    """`e` over the column environment `cols`; `n` is the rows' shape, a
+    length or (a dense aggregate's row view) a tuple."""
     if isinstance(e, ColumnExpr):
         if e.index not in cols:
             raise JaxUnsupported(f"column {e.index} not device-resident")
@@ -73,7 +75,8 @@ def _param_const(e: Constant, slot, params, n: int) -> JVal:
     pi, pf = params
     src = pf[idx] if which == "f" else pi[idx]
     return (
-        jnp.broadcast_to(src.astype(_np_dtype_for(e.ftype)), (n,)),
+        jnp.broadcast_to(src.astype(_np_dtype_for(e.ftype)),
+                         n if isinstance(n, tuple) else (n,)),
         jnp.ones(n, dtype=jnp.bool_),
     )
 
@@ -371,7 +374,7 @@ def _and(e, args, n):
     ta, tb = _truth(a), _truth(b)
     is_false = (a[1] & ~ta) | (b[1] & ~tb)
     valid = is_false | (a[1] & b[1])
-    return jnp.where(is_false, 0, 1).astype(jnp.int64), valid
+    return (~is_false).astype(jnp.int64), valid
 
 
 @_reg("or")
@@ -772,3 +775,231 @@ def _date_addsub(e, args, n):
     if e.ftype.kind == TypeKind.DATE:
         return _floordiv_const(us, 86_400_000_000).astype(jnp.int32), valid
     return us, valid
+
+
+# ---- bounded integer evaluation (dense aggregate arguments) ---------------
+#
+# The sums of a dense aggregate run on native 32-bit lanes (fusion.
+# dense_agg_results): an `int64` on the TPU is a pair of u32 arrays, and
+# every widened column or product is a full-length temporary.  Where the
+# column statistics bound an argument, it is evaluated here as an exact
+# sum of int32 terms instead; where they do not, `bounded_int` declines
+# and the caller keeps `compile_expr`'s int64 value.
+
+_I32_MAX = (1 << 31) - 1
+#: a bounded value past this is left to the wrapping int64 arithmetic
+_BOUND_MAX = 1 << 62
+
+
+class _Dry:
+    """Stands in for an array where only the bounds are wanted: the
+    dry run that names a program's lanes (fusion.agg_lanes) and the
+    trace that emits them are one code."""
+
+    def _same(self, *_a, **_k):
+        return self
+
+    __add__ = __radd__ = __sub__ = __mul__ = __rmul__ = _same
+    __and__ = __rshift__ = __lshift__ = __neg__ = astype = _same
+
+
+DRY = _Dry()
+
+
+def _fits_i32(lo: int, hi: int) -> bool:
+    return -_I32_MAX <= lo and hi <= _I32_MAX
+
+
+class Lanes:
+    """An exact integer, `const + sum(x << shift)` over int32 `terms`
+    (x, shift, lo, hi) with static bounds lo <= x <= hi.  `vcols` are the
+    NULLable scan columns whose validity the value inherits."""
+
+    __slots__ = ("terms", "const", "vcols")
+
+    def __init__(self, terms, const=0, vcols=frozenset()):
+        self.terms = terms
+        self.const = const
+        self.vcols = vcols
+
+    def bounds(self):
+        lo = self.const + sum(t[2] << t[1] for t in self.terms)
+        hi = self.const + sum(t[3] << t[1] for t in self.terms)
+        return lo, hi
+
+    def single(self):
+        """The whole value as one int32 (x, lo, hi), or None."""
+        lo, hi = self.bounds()
+        if not self.terms or not _fits_i32(lo, hi):
+            return None
+        x = None
+        for t, s, _lo, _hi in self.terms:
+            t = t << s if s else t
+            x = t if x is None else x + t
+        return (x + self.const if self.const else x), lo, hi
+
+
+def _split16(term):
+    """One int32 term as its low 16 bits and the arithmetic rest: exact
+    in two's complement for negative values too."""
+    x, s, lo, hi = term
+    return [(x & 0xFFFF, s, 0, 0xFFFF), (x >> 16, s + 16, lo >> 16, hi >> 16)]
+
+
+def _merged(terms, limit: int):
+    """Add up the terms of equal shift while the sum stays within
+    +-limit: one add a row, one lane fewer."""
+    out = []
+    for t in sorted(terms, key=lambda t: t[1]):
+        if t[2] == t[3] == 0:
+            continue
+        p = out[-1] if out else None
+        if p is not None and p[1] == t[1] \
+                and -limit <= p[2] + t[2] and p[3] + t[3] <= limit:
+            out[-1] = (p[0] + t[0], t[1], p[2] + t[2], p[3] + t[3])
+        else:
+            out.append(t)
+    return out
+
+
+def _norm(terms, const, vcols):
+    v = Lanes(_merged(terms, _I32_MAX), const, vcols)
+    lo, hi = v.bounds()
+    return v if -_BOUND_MAX < lo and hi < _BOUND_MAX else None
+
+
+def _scale_term(term, y, ylo: int, yhi: int):
+    """term * y as int32 terms, or None: y is a Python int (ylo == yhi)
+    or an int32 array within [ylo, yhi].  A product past int32 is formed
+    from the term's 16-bit halves."""
+    x, s, lo, hi = term
+    if lo == hi == 0:
+        return []
+    if not _fits_i32(ylo, yhi):
+        return None
+    c = (lo * ylo, lo * yhi, hi * ylo, hi * yhi)
+    if _fits_i32(min(c), max(c)):
+        return [(x * y, s, min(c), max(c))]
+    if 0 <= lo and hi <= 0xFFFF:
+        return None
+    out = []
+    for half in _split16(term):
+        part = _scale_term(half, y, ylo, yhi)
+        if part is None:
+            return None
+        out += part
+    return out
+
+
+def _mul_lanes(a: Lanes, b: Lanes):
+    vcols = a.vcols | b.vcols
+    if not a.terms:
+        a, b = b, a
+    if not b.terms:
+        c, terms = b.const, []
+        for t in a.terms:
+            part = _scale_term(t, c, c, c)
+            if part is None:
+                return None
+            terms += part
+        return _norm(terms, a.const * c, vcols)
+    # the multiplier is the side that is one int32; the narrower first
+    sides = sorted(((a, b), (b, a)),
+                   key=lambda p: max(map(abs, p[1].bounds())))
+    for wide, narrow in sides:
+        one = narrow.single()
+        if one is None:
+            continue
+        y, ylo, yhi = one
+        terms = []
+        parts = list(wide.terms)
+        if wide.const:
+            if not _fits_i32(wide.const, wide.const):
+                continue
+            parts.append((wide.const, 0, wide.const, wide.const))
+        for t in parts:
+            part = _scale_term(t, y, ylo, yhi)
+            if part is None:
+                break
+            terms += part
+        else:
+            return _norm(terms, 0, vcols)
+    return None
+
+
+def _rescaled(v, ft: FieldType, scale: int):
+    """`_to_scaled` for an upward rescale by a power of ten; None for a
+    rounding one and for floats."""
+    if v is None or ft.kind == TypeKind.FLOAT:
+        return None
+    ds = scale - (ft.scale if ft.kind == TypeKind.DECIMAL else 0)
+    if ds < 0:
+        return None
+    return v if ds == 0 else _mul_lanes(v, Lanes([], 10 ** ds))
+
+
+_BOUNDED_KINDS = (TypeKind.INT, TypeKind.UINT, TypeKind.DECIMAL,
+                  TypeKind.BOOL)
+
+
+def bounded_int(e: Expression, wire: dict):
+    """`e` as exact int32 `Lanes`, or None where it cannot be had: the
+    part of `_arith` and `_to_scaled` that is `+`, `-`, `*`, unary minus
+    and an upward rescale by a power of ten, over integer and decimal
+    columns whose statistics fit int32 and literal constants.  `wire`
+    maps a scan column to (array or DRY, lo, hi, nullable)."""
+    if e.ftype.kind not in _BOUNDED_KINDS:
+        return None
+    if isinstance(e, ColumnExpr):
+        w = wire.get(e.index)
+        if w is None or not _fits_i32(w[1], w[2]):
+            return None
+        x, lo, hi, nullable = w
+        return Lanes([(x.astype(jnp.int32), 0, lo, hi)], 0,
+                     frozenset([e.index]) if nullable else frozenset())
+    if isinstance(e, Constant):
+        if (getattr(e, "param_slot", None) is not None
+                or isinstance(e.value, bool)
+                or not isinstance(e.value, int)):
+            return None
+        return Lanes([], int(e.value))
+    if not isinstance(e, ScalarFunc):
+        return None
+    if e.name == "unaryminus":
+        v = bounded_int(e.args[0], wire)
+        return None if v is None else _mul_lanes(v, Lanes([], -1))
+    if e.name not in ("+", "-", "*"):
+        return None
+    a, b = (bounded_int(x, wire) for x in e.args)
+    fa, fb = e.args[0].ftype, e.args[1].ftype
+    out_scale = e.ftype.scale if e.ftype.kind == TypeKind.DECIMAL else 0
+    if e.name == "*":
+        if a is None or b is None:
+            return None
+        r = _mul_lanes(a, b)
+        drop = sum(f.scale for f in (fa, fb)
+                   if f.kind == TypeKind.DECIMAL) - out_scale
+        if r is None or drop > 0:
+            return None
+        return r if drop == 0 else _mul_lanes(r, Lanes([], 10 ** -drop))
+    a, b = _rescaled(a, fa, out_scale), _rescaled(b, fb, out_scale)
+    if a is None or b is None:
+        return None
+    if e.name == "-":
+        b = _mul_lanes(b, Lanes([], -1))
+        if b is None:
+            return None
+    return _norm(a.terms + b.terms, a.const + b.const, a.vcols | b.vcols)
+
+
+def lane_limbs(v: Lanes, limit: int):
+    """The terms of `v` as limbs within +-limit (limit >= 0xFFFF), each
+    (x, shift): what a block sum of 2^31 // (limit + 1) rows holds in an
+    int32 accumulator."""
+    terms = []
+    for t in v.terms:
+        while not (-limit <= t[2] and t[3] <= limit):
+            low, t = _split16(t)
+            terms.append(low)
+        terms.append(t)
+    return [(t[0], t[1]) for t in _merged(terms, limit)]

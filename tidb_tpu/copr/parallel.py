@@ -659,14 +659,95 @@ def _all_true(mesh: Mesh, n_pad: int):
 # sharded programs
 # ---------------------------------------------------------------------------
 
+class _RowView:
+    """How a shard program sees its local tiles of rows.
+
+    Flat (`shape == (n_local,)`) everywhere but in a small-G dense
+    aggregate, whose emitters are all elementwise or whole-array
+    reductions.  There the [Tl, TILE] arrays are never flattened: in the
+    TPU's (8, 128)-tiled layout a flatten is a physical transpose of
+    every column and mask (the parent's full-length `copy` operations).
+    The rows are viewed as [Tl/8, blocks, 8, rows of a block], which is
+    the order the tiles already lie in, so the view is free and the dense
+    emitter's block sums (fusion.AGG_BLOCK) reduce the last axis as it
+    stands.  Shards that are not whole groups of 8 tiles (tables under 8
+    tiles a shard) stay flat."""
+
+    def __init__(self, n_local: int, an: Optional[_Analyzed] = None,
+                 kind: str = "", col_layout=None):
+        from . import fusion
+
+        T = je.TILE
+        self.Tl = Tl = n_local // T
+        self.n_local = n_local
+        b = min(T, fusion.AGG_BLOCK)
+        #: rows of a block; 0 for the flat view
+        self.block = b if (
+            kind == "agg" and an.agg_mode == "dense"
+            and an.num_groups <= ops.UNROLL_G
+            and not an.probes and not an.lookups
+            and not any(col_layout or ())
+            and Tl % 8 == 0 and T % b == 0) else 0
+        self.shape = ((Tl // 8, T // b, 8, b) if self.block
+                      else (n_local,))
+
+    def __call__(self, x):
+        """A [Tl, TILE] operand in the view's shape (as it is, if there
+        already)."""
+        if self.block and x.ndim == 2:
+            return x.reshape(self.Tl // 8, 8, -1, self.block) \
+                .transpose(0, 2, 1, 3)
+        return x.reshape(self.shape)
+
+    def hold(self, datas, valids, del_mask):
+        """The program's row operands in the view's shape.  A blocked
+        view is pinned to them by an optimization barrier: without it XLA
+        sinks the view below the arithmetic, computes masks and limbs in
+        the [Tl, TILE] shape and writes each out in full for the
+        reduction on the far side of the bitcast (0.64 of 3.7 GB moved in
+        the Q1 program of ISSUE 35, 1.9 of 2.6 GB in Q6's; with the
+        barrier the programs read their arguments once and write nothing
+        of full length)."""
+        if not self.block:
+            return datas, valids, del_mask
+        held = iter(jax.lax.optimization_barrier(tuple(
+            self(x) for x in (*datas, *valids, del_mask) if x is not None)))
+
+        def take(x):
+            return None if x is None else next(held)
+
+        return ([take(x) for x in datas], [take(x) for x in valids],
+                take(del_mask))
+
+    def flat(self, x):
+        """A view-shaped array back in row order."""
+        if self.block:
+            x = x.transpose(0, 2, 1, 3)
+        return x.reshape(self.n_local)
+
+    def local_rows(self, dtype=jnp.int64):
+        """Shard-local row number of every element."""
+        if not self.block:
+            return jnp.arange(self.n_local, dtype=dtype)
+
+        def iota(d):
+            return jax.lax.broadcasted_iota(dtype, self.shape, d)
+
+        return ((iota(0) * 8 + iota(2)) * je.TILE
+                + iota(1) * self.block + iota(3))
+
+
 def _cols_env(an: _Analyzed, col_order: List[int], datas, valids,
-              n_local: int, params=None, col_layout=None, lvals=()):
+              view: _RowView, params=None, col_layout=None, lvals=()):
     """Per-shard column environment for compile_expr: widen the narrow
     wire arrays to the canonical dtype in-register (XLA fuses the convert
     into every consumer — HBM reads stay narrow), and substitute a traced
     constant mask for columns cached without a validity array (no NULLs:
     zero transfer, zero HBM).  `params` carries the hoisted predicate
-    parameter vectors (pi, pf) for ParamConst slots.
+    parameter vectors (pi, pf) for ParamConst slots.  Beside the widened
+    arrays, under "__wire__", stand the arrays as they came: the dense
+    aggregate computes in int32 from those (jax_eval.bounded_int), and
+    XLA drops whichever of the two nothing reads.
 
     `col_layout[j]` = (bits, cap, kind) marks column j COLD: datas[j] is
     the shard-local bit-packed code bytes and the matching `lvals` entry
@@ -676,26 +757,28 @@ def _cols_env(an: _Analyzed, col_order: List[int], datas, valids,
     NULL-free by the tuner's contract."""
     from . import fusion
 
-    env = {}
+    env, wire = {}, {}
     lv = 0
     for j, ci in enumerate(col_order):
         lay = col_layout[j] if col_layout is not None else None
         if lay is not None:
             bits, _cap, kind = lay
-            d = fusion.decode_packed(datas[j], lvals[lv], bits, n_local,
-                                     kind=kind)
+            d = fusion.decode_packed(datas[j], lvals[lv], bits,
+                                     view.n_local, kind=kind)
             lv += 1
-            v = jnp.ones(n_local, dtype=jnp.bool_)
+            v = jnp.ones(view.n_local, dtype=jnp.bool_)
             env[ci] = (d, v)
+            wire[ci] = d
             continue
-        d = datas[j].reshape(n_local)
+        d = wire[ci] = view(datas[j])
         target = _full_dtype(an.scan.ftypes[ci].kind)
         if d.dtype != target:
             d = d.astype(target)
         v = valids[j]
-        v = (jnp.ones(n_local, dtype=jnp.bool_) if v is None
-             else v.reshape(n_local))
+        v = (jnp.ones(view.shape, dtype=jnp.bool_) if v is None
+             else view(v))
         env[ci] = (d, v)
+    env["__wire__"] = wire
     if params is not None:
         env["__params__"] = params
     return env
@@ -769,17 +852,26 @@ def _bounds_args(bounds, scalars=()):
     return ints
 
 
-def _mesh_masks(del_mask, bounds, n_local: int):
+def _mesh_masks(del_mask, bounds, view: _RowView):
     """(global row offsets, live-row mask) for one shard: the union of
     every range slot's [lo, hi) clip, ANDed with the deletion mask.
     `bounds` is the operand vector of `_bounds_args`; only its range
-    slots are read here."""
-    shard = jax.lax.axis_index("dp").astype(jnp.int64)
-    gofs = shard * n_local + jnp.arange(n_local, dtype=jnp.int64)
-    m = jnp.zeros(n_local, dtype=jnp.bool_)
+    slots are read here.  The sixteen comparisons a row are made on
+    int32 lanes, between the shard-local row number and each slot's
+    bounds moved into the shard and clipped to it (an int64 comparison
+    is several lane operations on the TPU); the int64 offsets are for
+    the emitters that hand row numbers out."""
+    n = view.n_local
+    base = jax.lax.axis_index("dp").astype(jnp.int64) * n
+    gofs = base + view.local_rows()
+    narrow = jnp.int32 if n < 1 << 31 else jnp.int64
+    rows = view.local_rows(narrow)
+    slots = jnp.clip(bounds[: 2 * MESH_RANGE_SLOTS] - base, 0, n) \
+        .astype(narrow)
+    m = jnp.zeros(view.shape, dtype=jnp.bool_)
     for r in range(MESH_RANGE_SLOTS):
-        m = m | ((gofs >= bounds[2 * r]) & (gofs < bounds[2 * r + 1]))
-    return gofs, m & del_mask.reshape(n_local)
+        m = m | ((rows >= slots[2 * r]) & (rows < slots[2 * r + 1]))
+    return gofs, m & view(del_mask)
 
 
 def _key_device(d):
@@ -1020,17 +1112,21 @@ def _build_mesh_core(an: _Analyzed, kind: str, col_order: List[int],
         return _build_sort_agg_core(an, col_order, mesh, tiles_per_shard,
                                     hoisted=hoisted, col_layout=col_layout)
 
+    view = _RowView(n_local, an, kind, col_layout)
+
     def region_ctx(datas, valids, del_mask, ints, lvals, pargs):
         counts, pargs, params = _split_operands(an, ints, pargs, hoisted)
-        cols = _cols_env(an, col_order, datas, valids, n_local, params,
+        datas, valids, del_mask = view.hold(datas, valids, del_mask)
+        cols = _cols_env(an, col_order, datas, valids, view, params,
                          col_layout=col_layout, lvals=lvals)
-        gofs, row_mask = _mesh_masks(del_mask, ints, n_local)
-        ctx = fusion.RegionContext(an=an, cols=cols, n=n_local,
+        gofs, row_mask = _mesh_masks(del_mask, ints, view)
+        ctx = fusion.RegionContext(an=an, cols=cols, n=view.shape,
                                    mask=row_mask, axis="dp", gofs=gofs,
-                                   n_global=n_global)
+                                   n_global=n_global, flat=view.flat)
         fusion.selection_mask(ctx)
-        ctx.mask = _apply_probes(an, cols, ctx.mask, pargs, counts,
-                                 n_local)
+        if an.probes or an.lookups:
+            ctx.mask = _apply_probes(an, cols, ctx.mask, pargs, counts,
+                                     n_local)
         return ctx
 
     if kind == "agg":
@@ -1165,10 +1261,11 @@ def _build_mesh_fn(an: _Analyzed, kind: str, col_order: List[int],
     return wrapped
 
 
-def _compile_labeled(fn, kind: str):
+def _compile_labeled(fn, kind: str, attrs: dict):
     """Wrap a freshly built mesh program so its first dispatch records a
-    copr.compile span (cache=miss); later calls pass straight through —
-    _packed_jit's execute/readback spans nest inside either way."""
+    copr.compile span (cache=miss, and `attrs`); later calls pass
+    straight through — _packed_jit's execute/readback spans nest inside
+    either way."""
     state = {"first": True}
 
     def call(*args, **kwargs):
@@ -1176,7 +1273,7 @@ def _compile_labeled(fn, kind: str):
             state["first"] = False
             from ..trace import span
 
-            with span("copr.compile", cache="miss", kind=kind):
+            with span("copr.compile", cache="miss", kind=kind, **attrs):
                 return fn(*args, **kwargs)
         return fn(*args, **kwargs)
 
@@ -1242,12 +1339,13 @@ def _build_sort_agg_core(an: _Analyzed, col_order: List[int], mesh: Mesh,
     n_cold = sum(1 for c in (col_layout or ()) if c is not None)
     remaps = getattr(an, "key_remaps", None)
     n_lvals = n_cold + _n_remaps(an)
+    view = _RowView(n_local)
 
     def shard_fn(datas, valids, del_mask, ints, lvals, *pargs):
         counts, pargs, params = _split_operands(an, ints, pargs, hoisted)
-        cols = _cols_env(an, col_order, datas, valids, n_local, params,
+        cols = _cols_env(an, col_order, datas, valids, view, params,
                         col_layout=col_layout, lvals=lvals)
-        gofs, m = _mesh_masks(del_mask, ints, n_local)
+        gofs, m = _mesh_masks(del_mask, ints, view)
         ctx = fusion.RegionContext(an=an, cols=cols, n=n_local, mask=m,
                                    axis="dp", gofs=gofs, n_global=n_global)
         fusion.selection_mask(ctx)
@@ -1825,6 +1923,9 @@ def _run_mesh_once(storage, req: CopRequest, tid: int,
     from ..trace import annotate, span
 
     annotate(device_ids=list(mesh_ids))
+    from .fusion import compile_attrs, note_agg_dispatch
+
+    cattrs = compile_attrs(an, kind)
     fn = _COMPILED.get(fp)
     if fn is None:
         fn = _build_mesh_fn(an, kind, col_order, mesh, Tl,
@@ -1835,9 +1936,9 @@ def _run_mesh_once(storage, req: CopRequest, tid: int,
         _COMPILED.put(fp, fn)
         # label this query's FIRST dispatch as the compile: jit compiles
         # lazily, so the program-cache miss pays XLA compilation there
-        fn = _compile_labeled(fn, kind)
+        fn = _compile_labeled(fn, kind, cattrs)
     else:
-        with span("copr.compile", cache="hit", kind=kind):
+        with span("copr.compile", cache="hit", kind=kind, **cattrs):
             pass
     pargs = tuple(pargs)
     # the statement's int64 scalars ride the operand vector behind each
@@ -1864,6 +1965,8 @@ def _run_mesh_once(storage, req: CopRequest, tid: int,
     from ..metrics import REGISTRY
 
     REGISTRY.inc("mesh_scans_total")
+    if cattrs:
+        note_agg_dispatch(an)
 
     # every requested range runs in ONE fused dispatch: clip the bounds
     # host-side and hand them to the program's range slots — no per-range

@@ -52,6 +52,7 @@ from ..copr.parallel import (
     _all_true,
     _bounds_args,
     _check_membership_epoch,
+    _RowView,
     _cols_env,
     _handle_mesh_failure,
     _layout,
@@ -314,7 +315,7 @@ def _shard_side(an: _Analyzed, col_order, n_local: int, n_ranges: int):
     row mask) for one side, evaluated per shard pre-exchange."""
 
     def prep(datas, valids, del_mask, bounds):
-        cols = _cols_env(an, col_order, datas, valids, n_local)
+        cols = _cols_env(an, col_order, datas, valids, _RowView(n_local))
         shard = jax.lax.axis_index("dp").astype(jnp.int64)
         gofs = shard * n_local + jnp.arange(n_local, dtype=jnp.int64)
         m = jnp.zeros(n_local, dtype=jnp.bool_)

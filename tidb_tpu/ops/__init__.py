@@ -25,6 +25,7 @@ if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
 from .segment import (  # noqa: E402
+    UNROLL_G,
     masked_segment_sum,
     masked_segment_count,
     masked_segment_min,
@@ -35,6 +36,7 @@ from .segment import (  # noqa: E402
 from .topk import masked_top_k  # noqa: E402
 
 __all__ = [
+    "UNROLL_G",
     "masked_segment_sum",
     "masked_segment_count",
     "masked_segment_min",

@@ -16,13 +16,16 @@ import jax.numpy as jnp
 
 
 # Group-count threshold below which segment reductions unroll into one
-# masked full reduction per group instead of a scatter.  TPU scatter over
-# millions of colliding updates is catastrophically slow on v5e (~300-500ms
-# per 4M-row 64-bit scatter measured through the XLA emulation path), while
-# XLA fuses G unrolled where+reduce passes into a single data traversal
-# (~10ms for a full Q1-shaped aggregation at G=6).  Typical analytical GROUP
-# BYs (TPC-H Q1/Q12/Q14...) have tiny G; high-NDV aggregations take the
-# sort-based mesh path instead.
+# masked full reduction per group instead of a scatter (a TPU scatter over
+# millions of colliding updates serializes).  The unrolled reductions are
+# NOT one traversal: the v5e compiler keeps each `where + reduce` a fusion
+# of its own, a full pass over its operands (30 `select_reduce_fusion` for
+# the five int64 sums of TPC-H Q1 at G=6; ISSUE 35, PERF.md section 5).  So
+# the counts and integer sums of a dense aggregate do not come here any
+# more: copr/fusion.py::_BlockSums emits them all as one variadic reduce.
+# What still unrolls here is min, max, first_row and float sums (whose
+# order of additions must not change), one pass each.  High-NDV
+# aggregations take the sort-based mesh path instead.
 UNROLL_G = 32
 
 
