@@ -65,7 +65,11 @@ def test_broken_mesh_program_fails_the_smoke(monkeypatch):
 
     monkeypatch.setattr(parallel, "_build_mesh_core", broken)
     # the rehearsal above left the programs in the cache: start cold
-    monkeypatch.setattr(parallel, "_COMPILED", parallel.ProgramCache("mesh"))
+    fresh = parallel.ProgramCache("mesh")
+    # not in /status's registry: a second "mesh" entry left there would
+    # hide the real cache from every later test of this worker
+    parallel.PROGRAM_CACHES.remove(fresh)
+    monkeypatch.setattr(parallel, "_COMPILED", fresh)
     smoke = chip_smoke.Smoke()
     with pytest.raises(chip_smoke.SmokeFailed, match="TypeError|shard_map"):
         chip_smoke.run_one_chip(smoke, ROWS, Q3_ROWS)

@@ -80,7 +80,8 @@ def q3_pair():
     return s, s.domain.storage.table(t.id)
 
 
-def _fragment_args(sess, table, sql, mesh, n_tiles, build_pad=16):
+def _fragment_args(sess, table, sql, mesh, n_tiles, build_pad=16,
+                   wire=None):
     """(core, abstract args) of the fused mesh program for `sql`'s cop DAG,
     built the way fusion.trace_fused_fragment builds it, but over `mesh`
     and with ShapeDtypeStructs carrying NamedShardings: columns in their
@@ -120,14 +121,20 @@ def _fragment_args(sess, table, sql, mesh, n_tiles, build_pad=16):
     for ci in col_order:
         store_ci = an.scan.columns[ci]
         dt = par._wire_dtype(table, store_ci)
-        if table.cols[store_ci].name == "l_orderkey":
+        if wire is not None:
+            dt = np.dtype(wire[table.cols[store_ci].name])
+        elif table.cols[store_ci].name == "l_orderkey":
             dt = np.dtype(np.int32)  # SF10's key range; 2,048 rows fit int16
         datas.append(sds((n_tiles, PROD_TILE), dt, sharded))
         valids.append(None)
     pargs = []
     for lk in an.lookups:
-        pargs.append(sds((build_pad,), np.int64))
-        for ft in lk.payload_ftypes:
+        # where the groups are the build rows the keys alone are sent,
+        # in 32 bits beside a probe key cached that narrow
+        fd = par._fd_lookup(an)
+        pargs.append(sds((build_pad,),
+                         np.int32 if fd and wire is not None else np.int64))
+        for ft in () if fd else lk.payload_ftypes:
             pargs += [sds((build_pad,), par._full_dtype(ft.kind)),
                       sds((build_pad,), np.bool_)]
     # the operand vector as _run_mesh_once fills it: range slots, one key
@@ -290,3 +297,155 @@ def test_pallas_kernels_compile_to_mosaic_at_the_production_tile(
         fn = lambda p: kernels.unpack_codes(p, bits, n)  # noqa: E731
         args = (sds((n * bits // 8,), np.uint8),)
     assert "tpu_custom_call" in _compile(fn, args).as_text()
+
+
+# ---------------------------------------------------------------------------
+# the one-chip Q3 of the benchmark's cell `sf1-q3`
+# ---------------------------------------------------------------------------
+
+#: SF1's shapes: tiles of 2^20 rows a table (padded to a power of two),
+#: the wire dtypes its column statistics give, the directory over
+#: c_custkey's 150,000 values, 2^18 slots for the ~147 k joined rows
+SF1_TILES = {"customer": 1, "orders": 2, "lineitem": 8}
+SF1_WIRE = {"c_custkey": np.int32, "c_mktsegment": np.int8,
+            "o_orderkey": np.int32, "o_custkey": np.int32,
+            "o_orderdate": np.int16, "o_shippriority": np.int8,
+            "l_orderkey": np.int32, "l_extendedprice": np.int32,
+            "l_discount": np.int8, "l_shipdate": np.int16}
+SF1_DIRECTORY = 1 << 18
+SF1_JOINED = 1 << 18
+
+
+@pytest.fixture(scope="module")
+def q3_bench():
+    """(session, Q3's text, its MPP join's spec, storage) over the
+    benchmark's own generator at SF 0.02, analysed: the plan of the cell."""
+    import json
+    import os
+    import sys
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    sys.path.insert(0, bench)
+    try:
+        from harness import serve, traffic
+
+        config = json.load(open(os.path.join(
+            bench, "configs", "tpch-sf1-q3.json")))
+        query = json.load(open(os.path.join(bench, "queries", "q3.json")))
+        domain, _, _ = serve.load(config, 7, 0.02, lambda _n: None)
+        return domain.new_session(), traffic.render(query, 0), domain
+    finally:
+        sys.path.remove(bench)
+
+
+def _sf1_side(state, table_name, mesh):
+    """A `_SideState` of the small table given SF1's shapes, and its
+    abstract (datas, valids, del_mask, bounds) operands."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from tidb_tpu.copr import parallel as par
+
+    tiles = SF1_TILES[table_name]
+    state.Tl = state.n_tiles = state.n_pad = tiles
+    state.n_local = tiles * PROD_TILE
+    sharded = NamedSharding(mesh, P("dp"))
+    names = [state.table.cols[state.an.scan.columns[ci]].name
+             for ci in state.col_order]
+    state.datas = [jax.ShapeDtypeStruct((tiles, PROD_TILE), SF1_WIRE[n],
+                                        sharding=sharded) for n in names]
+    state.valids = [None] * len(names)
+    state.del_mask = jax.ShapeDtypeStruct((tiles, PROD_TILE), np.bool_,
+                                          sharding=sharded)
+    bounds = par._bounds_args([], (0, 0))
+    return (tuple(state.datas), tuple(state.valids), state.del_mask,
+            jax.ShapeDtypeStruct(bounds.shape, bounds.dtype,
+                                 sharding=NamedSharding(mesh, P())))
+
+
+def _timed_compile(fn, args):
+    import time
+
+    t0 = time.perf_counter()
+    compiled = fn.lower(*args).compile()
+    took = time.perf_counter() - t0
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, (list, tuple)) else cost
+    return compiled, took, cost["bytes accessed"]
+
+
+def test_one_chip_q3_join_programs_at_sf1_shapes(meshes, q3_bench):
+    """The three device programs a Q3 statement of `sf1-q3` runs on one
+    chip (the directory join's probe and emit, the lineitem aggregate
+    with its lookup join), compiled for the described v5e at SF1's
+    shapes: no sort and no one-level prefix sum over a table's length in
+    the customer-orders join (a full-length int64 `argsort` alone
+    compiles for over a minute), and the whole of a statement's cold
+    compile bounded."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from tidb_tpu.mpp import engine as mpp
+    from tidb_tpu.parser import parse_one
+    from tidb_tpu.planner.physical import PhysMPPJoin
+
+    sess, sql, domain = q3_bench
+    mesh = meshes[1]
+    def walk(p):
+        yield p
+        for c in list(getattr(p, "children", ()) or ()) + [
+                getattr(p, a, None) for a in ("reader", "build_plan")]:
+            if c is not None:
+                yield from walk(c)
+
+    join = next(p for p in walk(sess._plan(parse_one(sql)))
+                if isinstance(p, PhysMPPJoin))
+    spec = join.build(None).spec
+    names = {}
+    for side in (spec.probe, spec.build):
+        names[side.table_id] = next(
+            n for n in SF1_TILES if domain.catalog.info_schema()
+            .table("test", n).id == side.table_id)
+    ps = mpp._SideState(domain.storage, spec.probe, 1 << 62, mesh)
+    bs = mpp._SideState(domain.storage, spec.build, 1 << 62, mesh)
+    p_args = _sf1_side(ps, names[spec.probe.table_id], mesh)
+    b_args = _sf1_side(bs, names[spec.build.table_id], mesh)
+    plan = mpp._directory_plan(spec, ps, bs)
+    assert plan is not None and plan[0], "customer is the directory"
+    ds, ss, d_args, s_args = (ps, bs, p_args, b_args)
+
+    probe = mpp._build_directory_probe(ps, bs, True, False, mesh,
+                                       SF1_DIRECTORY, "mpp_shuffle_probe")
+    compiled, took_a, bytes_a = _timed_compile(probe, d_args + s_args)
+    text = compiled.as_text()
+    assert " sort(" not in text and "all-to-all" not in text
+
+    sharded = NamedSharding(mesh, P("dp"))
+    emit = mpp._build_directory_emit(spec, ps, bs, True, mesh, SF1_JOINED,
+                                     0, None, "mpp_shuffle_emit")
+    rows = (jax.ShapeDtypeStruct((ss.n_local,), np.int32, sharding=sharded),
+            jax.ShapeDtypeStruct((ss.n_local,), np.bool_, sharding=sharded))
+    compiled, took_b, bytes_b = _timed_compile(
+        emit, (p_args[0], p_args[1], b_args[0], b_args[1]) + rows)
+    assert " sort(" not in compiled.as_text()
+
+    table = domain.storage.table(
+        domain.catalog.info_schema().table("test", "lineitem").id)
+    core, args = _fragment_args(sess, table, sql, mesh,
+                                SF1_TILES["lineitem"],
+                                build_pad=SF1_JOINED, wire=SF1_WIRE)
+    assert len(args) > 5, "the join's build-side operand is missing"
+    compiled, took_c, bytes_c = _timed_compile(jax.jit(core), args)
+    text = compiled.as_text()
+    # a merge: its two sorts, and no binary search (a `while` of 18
+    # rounds of gathers took 4.9 s a statement on the chip)
+    assert text.count(" sort(") == 2 and " while(" not in text
+    # readings on this sandbox (PR 36): 4.3 s / 13.4 GB, 2.2 s / 5.8 GB,
+    # 56.7 s / 9.8 GB (the compiler counts a gather's whole operand)
+    print(f"probe {took_a:.1f}s {bytes_a / 1e9:.3f}GB, emit {took_b:.1f}s "
+          f"{bytes_b / 1e9:.3f}GB, lineitem {took_c:.1f}s "
+          f"{bytes_c / 1e9:.3f}GB")
+    assert took_a < 20 and bytes_a < 20e9
+    assert took_b < 15 and bytes_b < 9e9
+    assert took_c < 170 and bytes_c < 15e9

@@ -159,6 +159,26 @@ def run_topn(order_by, limit: int, chunk: Chunk) -> Chunk:
     return chunk.take(idx[: limit if limit >= 0 else len(idx)])
 
 
+def _object_ranks(data: np.ndarray) -> np.ndarray:
+    """int64 sort keys of an object column: exact Python ints (a wide
+    decimal's scaled values, an escalated integer sum) order by value,
+    strings as text."""
+    if all(isinstance(x, (int, np.integer)) for x in data):
+        try:
+            out = data.astype(np.int64)
+            if not len(out) or out.min() > np.iinfo(np.int64).min:
+                return out  # negating it for DESC cannot wrap
+        except OverflowError:
+            pass
+        uniq = sorted(set(data))
+    else:
+        data = [str(x) for x in data]
+        uniq = sorted(set(data))
+    rank = {s: i for i, s in enumerate(uniq)}
+    return np.fromiter((rank[x] for x in data), dtype=np.int64,
+                       count=len(data))
+
+
 def sort_indices(order_by, chunk: Chunk) -> np.ndarray:
     n = chunk.num_rows
     keys = []  # np.lexsort takes last key as primary -> reverse order
@@ -166,12 +186,7 @@ def sort_indices(order_by, chunk: Chunk) -> np.ndarray:
         v = e.eval(chunk)
         data = v.data
         if data.dtype == object:
-            # strings: rank via sorted unique values
-            uniq = sorted(set(str(x) for x in data))
-            rank = {s: i for i, s in enumerate(uniq)}
-            data = np.fromiter(
-                (rank[str(x)] for x in data), dtype=np.int64, count=n
-            )
+            data = _object_ranks(data)
         else:
             data = data.astype(np.float64) if data.dtype == np.float64 else data
         valid = v.validity()

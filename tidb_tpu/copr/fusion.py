@@ -776,40 +776,94 @@ def decode_packed(packed, dict_arg, bits: int, n: int,
 # ---------------------------------------------------------------------------
 
 
-def sort_group_segments(key_bits, key_flags, mask, cap, order=None,
-                        diff=None):
+def sort_group_segments(key_bits, key_flags, mask, cap):
     """Sort-based grouping into a static `cap`-slot budget.
 
     lexsorts rows by (key bits..., null flags..., selected-last), marks
-    group boundaries, and clips segment ids to [0, cap).  Callers with a
-    cheaper total order (e.g. the fd-lookup single-int sort) pass their
-    own `order` + boundary `diff` and reuse only the segment layout.
+    group boundaries, and clips segment ids to [0, cap).
 
-    Returns (order, sm, skeys, seg, pos, n_uniq): the sort permutation,
-    sorted selection mask, sorted key arrays, per-row segment ids, the
-    compacted first-row-per-group positions, and the TRUE distinct-group
-    count — n_uniq > cap means the budget blew and slots past cap-1 hold
-    merged garbage; the caller must treat the result as overflowed.
+    Returns (order, sm, out_keys, seg, n_uniq): the sort permutation,
+    sorted selection mask, every key array at the first row of each
+    group ([cap] each; slots past the groups hold the last row's), per-row
+    segment ids, and the TRUE distinct-group count — n_uniq > cap means
+    the budget blew and slots past cap-1 hold merged garbage; the caller
+    must treat the result as overflowed.
     """
     n = mask.shape[0]
-    ar = jnp.arange(n, dtype=jnp.int64)
-    if order is None:
-        # lexsort: LAST key is primary -> selected rows first, grouped
-        # by key
-        order = jnp.lexsort(
-            tuple(key_bits + key_flags + [(~mask).astype(jnp.int64)])
-        )
+    keys = key_bits + key_flags
+    # lexsort: LAST key is primary -> selected rows first, grouped by key
+    order = jnp.lexsort(tuple(keys + [(~mask).astype(jnp.int64)]))
     sm = mask[order]
-    skeys = [k[order] for k in key_bits + key_flags]
-    if diff is None:
-        diff = ar == 0
-        for k in skeys:
-            diff = diff | (k != jnp.roll(k, 1))
+    skeys = [k[order] for k in keys]
+    diff = jnp.arange(n, dtype=jnp.int64) == 0
+    for k in skeys:
+        diff = diff | (k != jnp.roll(k, 1))
     boundary = sm & diff
     n_uniq = boundary.sum().astype(jnp.int64)
-    seg = jnp.clip(jnp.cumsum(boundary.astype(jnp.int64)) - 1, 0, cap - 1)
-    pos = jnp.nonzero(boundary, size=cap, fill_value=n - 1)[0]
-    return order, sm, skeys, seg, pos, n_uniq
+    counts = ops.prefix_counts(boundary)
+    seg = jnp.clip(counts - 1, 0, cap - 1)
+    pos = ops.first_marked(boundary, cap, n - 1, counts)
+    return order, sm, [k[pos] for k in skeys], seg, n_uniq
+
+
+def lookup_group_sums(bkeys, keys, live, values):
+    """Counts and exact sums of the probe rows by the build row they
+    join to, for a lookup join whose build keys are unique and sorted
+    (`bkeys`, padded with the type's maximum): the aggregate of a shape
+    in which every group key is fixed by the build row, so a group IS a
+    build row.  A merge, because this chip sorts a row in a few
+    nanoseconds and gathers or scatters one in thirty: build and probe
+    keys ride ONE stable sort with the values as payload, so each build
+    row lands in front of the probe rows of its key; a probe row joins
+    where the nearest build key before it is its own; running sums read
+    at the build rows' places (a second, two-operand sort finds those)
+    and differenced give each build row's totals.  Nothing of the probe
+    side's length is gathered or scattered.
+
+    `values` is one (data or None for count(*), valid) pair an
+    aggregate; sums must be integers (a difference of running sums is
+    exact modulo 2^64, and would cancel catastrophically in floats).
+    Returns (matched probe rows a build row, [(sum or None, count) an
+    aggregate]), each of `bkeys`' length.
+    """
+    K, n = bkeys.shape[0], keys.shape[0]
+    big = jnp.iinfo(bkeys.dtype).max
+    # tag: 0 a build row; a probe row has bit 0 set, and bit a + 1 where
+    # aggregate a's argument is not NULL
+    tag = jnp.ones(n, dtype=jnp.int32)
+    for a, (_d, v) in enumerate(values):
+        tag = tag | (v.astype(jnp.int32) << (a + 1))
+    operands = [jnp.concatenate([bkeys, jnp.where(live, keys, big)]),
+                jnp.concatenate([jnp.zeros(K, jnp.int32), tag])]
+    summed = [a for a, (d, _v) in enumerate(values) if d is not None]
+    for a in summed:
+        d = values[a][0]
+        operands.append(jnp.concatenate([jnp.zeros(K, d.dtype), d]))
+    skey, stag, *svals = jax.lax.sort(operands, num_keys=1)
+    is_build = stag == 0
+    nearest = ops.prefix_max(jnp.where(is_build, skey,
+                                       jnp.iinfo(skey.dtype).min))
+    match = ~is_build & (skey == nearest) & (skey != big)
+    _, places = jax.lax.sort(
+        ((~is_build).astype(jnp.int32),
+         jnp.arange(K + n, dtype=jnp.int32)), num_keys=1)
+    places = places[:K]
+
+    def by_build_row(contrib):
+        run = ops.prefix_sums(contrib)
+        at = run[places]
+        return jnp.concatenate([at[1:], run[-1:]]) - at
+
+    rows = by_build_row(match.astype(jnp.int32))
+    out = []
+    for a, (d, _v) in enumerate(values):
+        ok = match & ((stag >> (a + 1)) & 1 == 1)
+        total = None
+        if d is not None:
+            sv = svals[summed.index(a)]
+            total = by_build_row(jnp.where(ok, sv, jnp.zeros((), sv.dtype)))
+        out.append((total, by_build_row(ok.astype(jnp.int32))))
+    return rows, out
 
 
 def grouped_partial_states(aggs, arg_fn, order, sm, seg, cap,
@@ -873,7 +927,7 @@ def merge_grouped_partials(aggs, key_bits, key_flags, row_valid, states,
     (n_uniq, out_keys, merged_states); n_uniq > cap means the merged
     group count blew the budget.
     """
-    order, sm, skeys, seg, pos, n_uniq = sort_group_segments(
+    order, sm, out_keys, seg, n_uniq = sort_group_segments(
         key_bits, key_flags, row_valid, cap)
     merged = []
     for a, st in zip(aggs, states):
@@ -898,7 +952,7 @@ def merge_grouped_partials(aggs, key_bits, key_flags, row_valid, states,
         else:  # first_row: the smallest global row index wins
             merged.append(
                 ops.masked_segment_min(st[order], seg, sm, cap))
-    out_keys = tuple(k[pos] for k in skeys)
+    out_keys = tuple(out_keys)
     return n_uniq, out_keys, merged
 
 

@@ -42,6 +42,7 @@ from jax import shard_map
 
 from ..chunk import Chunk, Column
 from ..coord import CoordEpochMismatch
+from ..metrics import REGISTRY
 from ..store.fault import FAILPOINTS
 from ..store.kv import CopRequest
 from ..types import TypeKind
@@ -637,6 +638,17 @@ def prefetch_table(storage, table_id: int, min_rows: int = 1 << 20):
 _ONES_CACHE = None
 
 
+def _named_jit(fn, name: Optional[str], **jit_kw):
+    """`jax.jit(fn)` under `name`: the XLA module is `jit_<name>`, which
+    is how the device trace tells the programs apart."""
+    def named(*args):
+        return fn(*args)
+
+    if name:
+        named.__name__ = named.__qualname__ = name
+    return jax.jit(named, **jit_kw)
+
+
 def _all_true(mesh: Mesh, n_pad: int):
     global _ONES_CACHE
     if _ONES_CACHE is None:
@@ -803,7 +815,7 @@ def _split_operands(an: _Analyzed, ints, pargs, hoisted):
     return counts, pargs, (pi, jnp.zeros(0, dtype=jnp.float64))
 
 
-from .cache import ProgramCache  # noqa: E402
+from .cache import PROGRAM_CACHES, ProgramCache  # noqa: E402,F401
 
 _COMPILED = ProgramCache("mesh")
 
@@ -929,7 +941,10 @@ def _apply_probes(an: _Analyzed, cols, m, pargs, counts, n_local: int):
 def _probe_specs(an: _Analyzed, hoisted=None):
     specs = [P()] * len(an.probes)
     for lk in an.lookups:
-        specs += [P()] + [P(), P()] * len(lk.payload_ftypes)
+        # the keys; the payload too, unless the groups ARE the build
+        # rows and the host adds it to them (`_fd_lookup`)
+        specs += [P()] + ([] if _fd_lookup(an)
+                          else [P(), P()] * len(lk.payload_ftypes))
     if hoisted is not None and hoisted[1]:
         specs += [P()]  # the replicated pf parameter vector
     return tuple(specs)
@@ -953,7 +968,12 @@ def _launch(jitted, program: Optional[str], args):
         return jitted(*args)
 
 
-def _read_back(out) -> np.ndarray:
+#: bytes that join programs (MPP exchange and join-tree programs, mesh
+#: programs that carry a lookup join) handed back to the host
+JOIN_READBACK = "join_readback_bytes_total"
+
+
+def _read_back(out, join: bool = False) -> np.ndarray:
     """One device result to the host.  `copr.readback` is the wait for
     the device plus the copy; its child `copr.device.wait` ends when the
     device has finished, so `copr.device.execute` + `copr.device.wait` is
@@ -966,7 +986,27 @@ def _read_back(out) -> np.ndarray:
             out.block_until_ready()
         buf = np.asarray(out)
         sp.set(bytes=buf.nbytes)
+    if join:
+        REGISTRY.inc(JOIN_READBACK, float(buf.nbytes))
     return buf
+
+
+def _read_back_tree(out):
+    """A join program's result to the host, leaf by leaf as it is (rows
+    and states in their narrow types, where `_packed_jit`'s one float64
+    buffer would be 16 bytes an integer): one `copr.readback` for all the
+    leaves, its `bytes` their sum."""
+    from ..trace import span
+
+    leaves, treedef = jax.tree_util.tree_flatten(out)
+    with span("copr.readback") as sp:
+        with span("copr.device.wait"):
+            jax.block_until_ready(leaves)
+        got = jax.device_get(leaves)
+        nbytes = sum(a.nbytes for a in got)
+        sp.set(bytes=nbytes)
+    REGISTRY.inc(JOIN_READBACK, float(nbytes))
+    return jax.tree_util.tree_unflatten(treedef, got)
 
 
 def _call_args(datas, valids, del_mask, bounds, lvals=(), pargs=(),
@@ -998,7 +1038,8 @@ def _program_name(kind: str, fp: str) -> str:
     return f"mesh_{kind}_{zlib.crc32(fp.encode()) & 0xFFFFFFFF:08x}"
 
 
-def _packed_jit(fn, mesh: Mesh, name: Optional[str] = None, merge=None):
+def _packed_jit(fn, mesh: Mesh, name: Optional[str] = None, merge=None,
+                join: bool = False):
     """jit `fn` (whose output is a pytree of 64-bit-wide arrays) so the whole
     result crosses device->host as ONE flat float64 buffer.
 
@@ -1012,11 +1053,12 @@ def _packed_jit(fn, mesh: Mesh, name: Optional[str] = None, merge=None):
     in f64, and no 64-bit bitcast-convert is needed (assumed unsafe under
     the TPU's x64 emulation; not re-tested on the attached chip).
 
-    `name` names the jitted callable (see `_program_name`; the MPP callers
-    give none and stay `jit_packed` in the device trace); `merge`, where
+    `name` names the jitted callable (see `_program_name`, and
+    `mpp.engine.program_name` for the MPP callers); `merge`, where
     given, is applied to the unpacked pytree inside the `copr.unpack` span
     (the caller's per-shard merge is part of getting from the packed
-    buffer to what the caller receives).
+    buffer to what the caller receives); `join` counts the buffer's
+    bytes under JOIN_READBACK.
     """
     meta = {}
 
@@ -1048,7 +1090,7 @@ def _packed_jit(fn, mesh: Mesh, name: Optional[str] = None, merge=None):
     def call(*args):
         from ..trace import span
 
-        buf = _read_back(_launch(jitted, name, args))
+        buf = _read_back(_launch(jitted, name, args), join)
         with span("copr.unpack", rows=int(buf.size), bytes=buf.nbytes):
             leaves, off = [], 0
             for shape, dt in meta["specs"]:
@@ -1109,8 +1151,10 @@ def _build_mesh_core(an: _Analyzed, kind: str, col_order: List[int],
         + _n_remaps(an)
 
     if kind == "agg" and an.agg_mode == "sort":
-        return _build_sort_agg_core(an, col_order, mesh, tiles_per_shard,
-                                    hoisted=hoisted, col_layout=col_layout)
+        build = (_build_lookup_agg_core if _fd_lookup(an)
+                 else _build_sort_agg_core)
+        return build(an, col_order, mesh, tiles_per_shard,
+                     hoisted=hoisted, col_layout=col_layout)
 
     view = _RowView(n_local, an, kind, col_layout)
 
@@ -1201,6 +1245,8 @@ def _build_mesh_fn(an: _Analyzed, kind: str, col_order: List[int],
                             hoisted=hoisted, col_layout=col_layout)
 
     if kind == "agg" and an.agg_mode == "sort":
+        if _fd_lookup(an):
+            return _wrap_lookup_agg(an, core, mesh, S, name)
         return _wrap_sort_agg(an, core, mesh, S, n_local, name)
 
     if kind == "agg":
@@ -1224,7 +1270,8 @@ def _build_mesh_fn(an: _Analyzed, kind: str, col_order: List[int],
                     merged.append((tag, r))
             return gcount, merged
 
-        packed = _packed_jit(core, mesh, name, merge=merge_shards)
+        packed = _packed_jit(core, mesh, name, merge=merge_shards,
+                             join=bool(an.lookups))
 
         def wrapped(*operands):  # the arguments of _call_args
             return packed(*_call_args(*operands))
@@ -1285,31 +1332,152 @@ class MeshAggOverflow(Exception):
     the caller falls back to the host hash aggregation."""
 
 
-def _fd_sort_lookup(an: _Analyzed):
+def _fd_lookup(an: _Analyzed) -> bool:
     """True when the single unique-key lookup FUNCTIONALLY DETERMINES
-    every group key (the TPC-H Q3 shape: GROUP BY join_key, payload...):
-    the matched build-row index then serves as the one sort key, so the
-    per-shard sort is a single int argsort instead of a lexsort over
-    every key column + null flag."""
+    every group key (the TPC-H Q3 shape: GROUP BY join_key, payload...)
+    and every aggregate is a count or an exact sum over the scanned
+    columns: a group then IS a build row, and the aggregate is
+    `fusion.lookup_group_sums`'s merge instead of a lookup, a sort by
+    every key and a segment reduction."""
+    if "fd_lookup" not in an.__dict__:
+        an.fd_lookup = _groups_are_build_rows(an)
+    return an.fd_lookup
+
+
+def _groups_are_build_rows(an: _Analyzed) -> bool:
     import json as _json
 
+    from ..expr.expression import ColumnExpr
     from .ir import serialize_expr
 
-    if len(an.lookups) != 1 or an.probes or an.agg is None:
+    if len(an.lookups) != 1 or an.probes or an.agg is None \
+            or an.agg_mode != "sort" or getattr(an, "key_remaps", None):
         return False
     lk = an.lookups[0]
     key_ser = _json.dumps(serialize_expr(lk.key), sort_keys=True)
     width = len(an.scan.columns)
     lo, hi = width, width + len(lk.payload_ftypes)
-    from ..expr.expression import ColumnExpr
-
     for g in an.agg.group_by:
         if isinstance(g, ColumnExpr) and lo <= g.index < hi:
             continue  # payload column: fixed per matched build row
         if _json.dumps(serialize_expr(g), sort_keys=True) == key_ser:
             continue  # the join key itself (unique per build row)
         return False
+    for a in an.agg.aggs:
+        if a.name not in ("count", "sum", "avg"):
+            return False
+        if a.name != "count" and \
+                a.partial_types()[0].kind == TypeKind.FLOAT:
+            return False
+        refs: set = set()
+        for x in a.args:
+            x.collect_columns(refs)
+        if any(i >= width for i in refs):
+            return False  # an argument from the build side's payload
     return True
+
+
+def _build_lookup_agg_core(an: _Analyzed, col_order: List[int], mesh: Mesh,
+                           tiles_per_shard: int, hoisted=None,
+                           col_layout=None):
+    """The shard_map'd core of an aggregate whose groups are the build
+    rows of its one lookup join (`_fd_lookup`): scan, selection, then
+    `fusion.lookup_group_sums` over the shard's rows against the
+    replicated build keys.  A shard returns, for each build row, the
+    probe rows it matched and every aggregate's state; the host forms
+    the partial chunks from the build side it holds
+    (`_lookup_agg_chunks`), so no key leaves the device."""
+    from . import fusion
+
+    S = len(mesh.devices.ravel())
+    n_local = tiles_per_shard * je.TILE
+    n_cold = sum(1 for c in (col_layout or ()) if c is not None)
+    view = _RowView(n_local)
+    lk = an.lookups[0]
+
+    def shard_fn(datas, valids, del_mask, ints, lvals, *pargs):
+        _counts, pargs, params = _split_operands(an, ints, pargs, hoisted)
+        cols = _cols_env(an, col_order, datas, valids, view, params,
+                        col_layout=col_layout, lvals=lvals)
+        gofs, m = _mesh_masks(del_mask, ints, view)
+        ctx = fusion.RegionContext(an=an, cols=cols, n=n_local, mask=m,
+                                   axis="dp", gofs=gofs,
+                                   n_global=S * n_local)
+        fusion.selection_mask(ctx)
+        bkeys = pargs[0]
+        dk, vk = compile_expr(lk.key, cols, n_local)
+        values = []
+        for a in an.agg.aggs:
+            if not a.args:
+                values.append((None, jnp.ones(n_local, dtype=jnp.bool_)))
+                continue
+            d, v = compile_expr(a.args[0], cols, n_local)
+            if a.name == "count":
+                d = None
+            else:
+                d = _to_state_dtype(d, a.args[0].ftype,
+                                    a.partial_types()[0])
+            values.append((d, v))
+        rows, states = fusion.lookup_group_sums(
+            bkeys, dk.astype(bkeys.dtype), ctx.mask & vk, values)
+        return rows, tuple(c if t is None else (t, c) for t, c in states)
+
+    return _shard_map_norep(shard_fn, mesh,
+                            _mesh_in_specs(an, hoisted, n_cold), P("dp"))
+
+
+def _wrap_lookup_agg(an: _Analyzed, core, mesh: Mesh, S: int, name: str):
+    jitted = _named_jit(core, name, out_shardings=_readback_sharding(mesh))
+
+    def wrapped(*operands):
+        rows, states = _read_back_tree(
+            _launch(jitted, name, _call_args(*operands)))
+        return {"mode": "lookup", "S": S, "rows": rows, "states": states}
+
+    return wrapped
+
+
+def _lookup_agg_chunks(out: dict, an: _Analyzed, aux: dict) -> List[Chunk]:
+    """A shard's per-build-row states -> one partial chunk [keys...,
+    states...] over the build rows that matched any probe row, the key
+    columns taken from the build side the host holds."""
+    from ..expr.expression import ColumnExpr
+
+    lk = an.lookups[0]
+    keys = aux[f"probe_keys_{lk.filter_id}"]
+    payload = aux[f"payload_{lk.filter_id}"]
+    pvalids = aux.get(f"payload_valid_{lk.filter_id}")
+    width = len(an.scan.columns)
+    k, K = len(keys), len(out["rows"]) // out["S"]
+    chunks: List[Chunk] = []
+    for s in range(out["S"]):
+        at = slice(s * K, s * K + k)
+        sel = np.flatnonzero(out["rows"][at])
+        if not len(sel):
+            continue
+        cols: List[Column] = []
+        for g in an.agg.group_by:
+            if isinstance(g, ColumnExpr) and g.index >= width:
+                j = g.index - width
+                pv = pvalids[j] if pvalids is not None else None
+                cols.append(Column(g.ftype,
+                                   payload[j][sel].astype(g.ftype.np_dtype),
+                                   None if pv is None else pv[sel]))
+            else:
+                cols.append(Column(g.ftype,
+                                   keys[sel].astype(g.ftype.np_dtype)))
+        for a, st in zip(an.agg.aggs, out["states"]):
+            pts = a.partial_types()
+            if a.name == "count":
+                cols.append(Column(pts[0], st[at][sel].astype(np.int64)))
+                continue
+            total, c = st[0][at][sel], st[1][at][sel]
+            cols.append(Column(pts[0], total.astype(pts[0].np_dtype),
+                               c > 0))
+            if a.name == "avg":
+                cols.append(Column(pts[1], c.astype(np.int64)))
+        chunks.append(Chunk(cols))
+    return chunks
 
 
 def _build_sort_agg_core(an: _Analyzed, col_order: List[int], mesh: Mesh,
@@ -1335,7 +1503,6 @@ def _build_sort_agg_core(an: _Analyzed, col_order: List[int], mesh: Mesh,
     n_global = S * n_local
     OUT = min(int(_os.environ.get("TIDB_TPU_AGG_OUT", 1 << 17)), n_local)
     agg_ir = an.agg
-    fd_lookup = _fd_sort_lookup(an)
     n_cold = sum(1 for c in (col_layout or ()) if c is not None)
     remaps = getattr(an, "key_remaps", None)
     n_lvals = n_cold + _n_remaps(an)
@@ -1372,24 +1539,9 @@ def _build_sort_agg_core(an: _Analyzed, col_order: List[int], mesh: Mesh,
             zero = jnp.float64(0.0) if k.dtype == jnp.float64 else jnp.int64(0)
             key_bits.append(jnp.where(v, k, zero))
             key_flags.append(v.astype(jnp.int64))
-        order = diff = None
-        if fd_lookup:
-            # every group key is determined by the matched build row: one
-            # int argsort on the build-row index replaces the full lexsort
-            # (XLA CSE folds this searchsorted into _apply_probes' one)
-            ar = jnp.arange(n_local, dtype=jnp.int64)
-            lk = an.lookups[0]
-            bkeys = pargs[len(an.probes)]
-            dk, _vk = compile_expr(lk.key, cols, n_local)
-            posk = jnp.clip(jnp.searchsorted(bkeys, dk.astype(jnp.int64)),
-                            0, bkeys.shape[0] - 1)
-            sortk = jnp.where(m, posk, bkeys.shape[0])  # unselected last
-            order = jnp.argsort(sortk)
-            ssort = sortk[order]
-            diff = (ar == 0) | (ssort != jnp.roll(ssort, 1))
-        order, sm, skeys, seg, pos, n_uniq = fusion.sort_group_segments(
-            key_bits, key_flags, m, OUT, order=order, diff=diff)
-        out_keys = tuple(k[pos] for k in skeys)
+        order, sm, out_keys, seg, n_uniq = fusion.sort_group_segments(
+            key_bits, key_flags, m, OUT)
+        out_keys = tuple(out_keys)
         results = fusion.grouped_partial_states(
             agg_ir.aggs, lambda e: compile_expr(e, cols, n_local),
             order, sm, seg, OUT, sgofs=gofs[order], n_global=n_global)
@@ -1406,7 +1558,7 @@ def _wrap_sort_agg(an: _Analyzed, core, mesh: Mesh, S: int,
 
     OUT = min(int(_os.environ.get("TIDB_TPU_AGG_OUT", 1 << 17)), n_local)
     tags = je._agg_tags(an.agg)
-    packed = _packed_jit(core, mesh, name)
+    packed = _packed_jit(core, mesh, name, join=bool(an.lookups))
 
     def wrapped(*operands):
         n_uniq, keys, results = packed(*_call_args(*operands))
@@ -1841,6 +1993,11 @@ def _run_mesh_once(storage, req: CopRequest, tid: int,
         counts.append(k)
         kpads.append(kpad)
 
+    from ..expr.expression import ColumnExpr
+    from ..trace import annotate, span
+
+    if an.lookups:
+        annotate(join=len(an.lookups))  # on `distsql.fanout`
     for lk in an.lookups:
         arr = (req.aux or {}).get(f"probe_keys_{lk.filter_id}")
         payload = (req.aux or {}).get(f"payload_{lk.filter_id}")
@@ -1856,19 +2013,32 @@ def _run_mesh_once(storage, req: CopRequest, tid: int,
         kpad = 16
         while kpad < k:
             kpad <<= 1
-        padded = np.full(kpad, np.iinfo(np.int64).max, dtype=np.int64)
-        padded[:k] = arr
-        pargs.append(jnp.asarray(padded))
+        fd = _fd_lookup(an)
+        # a merge's sort key in 32 bits where both sides fit: the probe
+        # key is a column cached that narrow, the build keys lie inside
+        # (the type's maximum stays free for the pad)
+        kdt = np.dtype(np.int64)
+        if fd and k and isinstance(lk.key, ColumnExpr) \
+                and _wire_dtype(table, an.scan.columns[lk.key.index]
+                                ).itemsize <= 4 \
+                and np.iinfo(np.int32).min <= arr[0] \
+                and arr[-1] < np.iinfo(np.int32).max:
+            kdt = np.dtype(np.int32)
+        with span("join.build", phase="upload", rows=k) as sp:
+            padded = np.full(kpad, np.iinfo(kdt).max, dtype=kdt)
+            padded[:k] = arr
+            sent = [padded]
+            for j, ft in enumerate(() if fd else lk.payload_ftypes):
+                pl = np.zeros(kpad, dtype=_full_dtype(ft.kind))
+                pl[:k] = payload[j]
+                pv = np.zeros(kpad, dtype=np.bool_)
+                src_v = pvalids[j] if pvalids is not None else None
+                pv[:k] = True if src_v is None else src_v
+                sent += [pl, pv]
+            pargs += [jnp.asarray(a) for a in sent]
+            sp.set(bytes=sum(a.nbytes for a in sent))
         counts.append(k)
-        for j, ft in enumerate(lk.payload_ftypes):
-            pl = np.zeros(kpad, dtype=_full_dtype(ft.kind))
-            pl[:k] = payload[j]
-            pv = np.zeros(kpad, dtype=np.bool_)
-            src_v = pvalids[j] if pvalids is not None else None
-            pv[:k] = True if src_v is None else src_v
-            pargs.append(jnp.asarray(pl))
-            pargs.append(jnp.asarray(pv))
-        kpads.append(kpad)
+        kpads.append((kpad, kdt.name) if fd else kpad)
 
     # column arrays load BEFORE the program lookup: the compiled program
     # is specialized on each column's wire dtype/null pattern AND its
@@ -1920,8 +2090,6 @@ def _run_mesh_once(storage, req: CopRequest, tid: int,
         import os as _os
 
         fp += "|aggout=" + _os.environ.get("TIDB_TPU_AGG_OUT", "")
-    from ..trace import annotate, span
-
     annotate(device_ids=list(mesh_ids))
     from .fusion import compile_attrs, note_agg_dispatch
 
@@ -1992,7 +2160,10 @@ def _run_mesh_once(storage, req: CopRequest, tid: int,
     if bounds:
         out = _dispatch_once(kind, fn, datas, valids, del_mask, bounds,
                              lvals, pargs, scalars, mesh_ids)
-        if kind == "agg" and an.agg_mode == "sort":
+        if kind == "agg" and an.agg_mode == "sort" \
+                and out["mode"] == "lookup":
+            chunks.extend(_lookup_agg_chunks(out, an, req.aux))
+        elif kind == "agg" and an.agg_mode == "sort":
             try:
                 chunks.extend(_sort_agg_chunks(out, table, an))
             except MeshAggOverflow as e:

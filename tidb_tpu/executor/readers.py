@@ -376,31 +376,39 @@ class DeviceJoinReaderExec(Executor):
             if c.num_rows:
                 chunks.append(c)
         self.build.close()
-        if chunks:
-            built = concat_chunks(chunks)
-            kcol = built.col(self.build_key_pos)
-            valid = kcol.validity()
-            if not valid.all():
-                built = built.filter(valid)  # NULL keys never match (inner)
+        from ..trace import span
+
+        # the build side's host work between the rows it drained and the
+        # probe program: sort here, upload in the mesh dispatch
+        # (`join.build` phase="upload", copr/parallel.py)
+        with span("join.build", phase="sort") as sp:
+            if chunks:
+                built = concat_chunks(chunks)
                 kcol = built.col(self.build_key_pos)
-            bits = key_bits_int64(kcol.data)
-            order = np.argsort(bits, kind="stable")
-            keys = bits[order]
-            if len(keys) > 1 and (keys[1:] == keys[:-1]).any():
-                raise ExecutorError(
-                    "device join: build keys not unique (planner "
-                    "uniqueness inference violated)")
-            payload, pvalid = [], []
-            for pos in self.payload_pos:
-                col = built.col(pos)
-                payload.append(col.data[order])
-                v = col.validity()
-                pvalid.append(None if v.all() else v[order])
-        else:
-            keys = np.zeros(0, dtype=np.int64)
-            payload = [np.zeros(0, dtype=np.int64)
-                       for _ in self.payload_pos]
-            pvalid = [None for _ in self.payload_pos]
+                valid = kcol.validity()
+                if not valid.all():
+                    built = built.filter(valid)  # NULL keys never match
+                    kcol = built.col(self.build_key_pos)
+                bits = key_bits_int64(kcol.data)
+                order = np.argsort(bits, kind="stable")
+                keys = bits[order]
+                if len(keys) > 1 and (keys[1:] == keys[:-1]).any():
+                    raise ExecutorError(
+                        "device join: build keys not unique (planner "
+                        "uniqueness inference violated)")
+                payload, pvalid = [], []
+                for pos in self.payload_pos:
+                    col = built.col(pos)
+                    payload.append(col.data[order])
+                    v = col.validity()
+                    pvalid.append(None if v.all() else v[order])
+            else:
+                keys = np.zeros(0, dtype=np.int64)
+                payload = [np.zeros(0, dtype=np.int64)
+                           for _ in self.payload_pos]
+                pvalid = [None for _ in self.payload_pos]
+            sp.set(rows=len(keys),
+                   bytes=keys.nbytes + sum(p.nbytes for p in payload))
         fid = self.filter_id
         self.reader.set_runtime_aux({
             f"probe_keys_{fid}": np.ascontiguousarray(keys, dtype=np.int64),

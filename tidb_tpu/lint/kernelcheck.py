@@ -56,7 +56,8 @@ CANONICAL_KERNEL_QUERIES = [
 #: devices the harness exposes; covers the partition/all_to_all shuffle
 #: and the all_gather broadcast rung of the partitioned join (both with
 #: the two-pass count+emit expansion).
-MPP_EXCHANGE_KERNELS = ("mpp-shuffle-join", "mpp-broadcast-join")
+MPP_EXCHANGE_KERNELS = ("mpp-shuffle-join", "mpp-broadcast-join",
+                        "mpp-directory-join")
 
 #: the grouped-partial + on-device-merge kernel (mpp/exchange.py
 #: trace_grouped_agg_kernel): per-shard sort-group, all_gather of
@@ -345,7 +346,7 @@ def lint_kernels(baseline_kernels: Optional[Dict[str, dict]] = None,
 
     # -- MPP exchange / partitioned-join kernels ------------------------
     for name in MPP_EXCHANGE_KERNELS:
-        mode = "shuffle" if "shuffle" in name else "broadcast"
+        mode = name.split("-")[1]
         try:
             from ..mpp.exchange import trace_exchange_kernel
 

@@ -56,6 +56,7 @@ from ..copr.parallel import (
     _cols_env,
     _handle_mesh_failure,
     _layout,
+    _named_jit,
     _no_eligible_devices,
     _packed_jit,
     get_mesh,
@@ -330,9 +331,150 @@ def _shard_side(an: _Analyzed, col_order, n_local: int, n_ranges: int):
     return prep
 
 
+def _agg_specs(spec: MPPJoinSpec) -> tuple:
+    """shard_map out_specs of what `_agg_tail` returns."""
+    if spec.group_by is not None:
+        states = tuple(P("dp") if a.name == "count" else (P("dp"), P("dp"))
+                       for a in spec.aggs)
+        return (P(), P("dp"), P("dp"),
+                tuple(P("dp") for _ in range(2 * len(spec.group_by))),
+                states)
+    states = tuple(P() if a.name == "count"
+                   else ((P(), P()) if a.name in ("sum", "avg")
+                         else (P("dp"), P()))
+                   for a in spec.aggs)
+    return (states,)
+
+
+def _agg_tail(spec: MPPJoinSpec, p_order, b_order, probe_out, build_out,
+              row_mask, cap_out: int, cap_g: int, S: int, remaps, extra):
+    """Partial aggregation over the joined rows of one shard (inner
+    join): `probe_out` / `build_out` are the (data, valid) pairs of the
+    joined layout over `cap_out` slots, `row_mask` the slots that hold a
+    joined row.  Scalar: psum'd states.  Grouped: per-shard sort-group
+    into the static `cap_g` budget, then the cross-shard merge ON
+    DEVICE, so only O(G) group rows leave; `extra` is the runtime group
+    budget, then the remap mapping operands."""
+    from ..copr import fusion
+    from ..copr.fusion import (grouped_partial_states,
+                               merge_grouped_partials,
+                               sort_group_segments)
+    from ..copr.parallel import _key_device
+
+    aggs, group_by = spec.aggs, spec.group_by
+    grouped = group_by is not None
+    nk = len(group_by) if grouped else 0
+    gchunk = cap_g // S if grouped else 0
+    gbudget = extra[0] if grouped else None
+    rvals = extra[1:] if grouped else ()
+    wp = len(p_order)
+    env = {ci: probe_out[j] for j, ci in enumerate(p_order)}
+    for j in range(len(b_order)):
+        env[wp + j] = build_out[j]
+
+    if grouped:
+        # -- grouped partial aggregation below the exchange --------
+        # per-shard sort-group into the static cap_g budget, then
+        # merge partials ACROSS shards on device: all_gather the
+        # compacted (key, state) rows, second sort-merge (identical
+        # on every shard), and each shard emits its 1/S slice — the
+        # readback is O(cap_g), never O(joined rows)
+        key_bits, key_flags = [], []
+        rslot = 0
+        for gi, g in enumerate(group_by):
+            rem = remaps[gi] if remaps is not None else None
+            if rem is not None:
+                # computed string key: post-join code-space gather
+                # through the runtime mapping operand
+                d0, v = env[rem.src_idx]
+                d = fusion.remap_codes(d0, rvals[rslot], cap_out)
+                rslot += 1
+            else:
+                d, v = compile_expr(g, env, cap_out)
+            k = _key_device(d)
+            zero = (jnp.float64(0.0) if k.dtype == jnp.float64
+                    else jnp.int64(0))
+            key_bits.append(jnp.where(v, k, zero))
+            key_flags.append(v.astype(jnp.int64))
+        order, sm, out_keys, seg, n_uniq = sort_group_segments(
+            key_bits, key_flags, row_mask, cap_g)
+        states = grouped_partial_states(
+            aggs, lambda e: compile_expr(e, env, cap_out),
+            order, sm, seg, cap_g)
+        # the BUDGET is a runtime scalar slot: overflow is detected
+        # on device against it, but only the pow2 capacity shapes
+        # the compiled program
+        over_l = jax.lax.psum(
+            jnp.maximum(n_uniq - gbudget, 0), "dp")
+        slot_ok = jnp.arange(cap_g, dtype=jnp.int64) \
+            < jnp.minimum(n_uniq, cap_g)
+        g_keys = [ex.replicate(k) for k in out_keys]
+        g_ok = ex.replicate(slot_ok)
+        g_states = jax.tree_util.tree_map(ex.replicate, states)
+        mn_uniq, m_keys, m_states = merge_grouped_partials(
+            aggs, g_keys[:nk], g_keys[nk:], g_ok, g_states, cap_g)
+        over_m = jnp.maximum(mn_uniq - gbudget, 0)
+        shard = jax.lax.axis_index("dp")
+
+        def slc(y):
+            return jax.lax.dynamic_slice(y, (shard * gchunk,),
+                                         (gchunk,))
+
+        return (over_l, over_m.reshape(1),
+                mn_uniq.reshape(1), tuple(slc(k) for k in m_keys),
+                tuple(jax.tree_util.tree_map(slc, m_states)))
+
+    # -- scalar partial aggregation --------------------------------
+    states = []
+    for a in aggs:
+        if a.name == "count":
+            if a.args:
+                d, v = compile_expr(a.args[0], env, cap_out)
+                states.append(jax.lax.psum(
+                    (row_mask & v).sum().astype(jnp.int64), "dp"))
+            else:
+                states.append(jax.lax.psum(
+                    row_mask.sum().astype(jnp.int64), "dp"))
+            continue
+        d, v = compile_expr(a.args[0], env, cap_out)
+        mv = row_mask & v
+        if a.name in ("sum", "avg"):
+            st = a.partial_types()[0]
+            dd = _to_state_dtype(d, a.args[0].ftype, st)
+            states.append((
+                jax.lax.psum(jnp.where(mv, dd, 0).sum(), "dp"),
+                jax.lax.psum(mv.sum().astype(jnp.int64), "dp"),
+            ))
+        else:  # min / max: per-shard partial, host merges (only
+            # Sum all-reduces are used on device)
+            if a.name == "min":
+                sent = (jnp.inf if jnp.issubdtype(d.dtype, jnp.floating)
+                        else ex.I64_MAX)
+                part = jnp.where(mv, d, sent).min()
+            else:
+                sent = (-jnp.inf if jnp.issubdtype(d.dtype, jnp.floating)
+                        else -ex.I64_MAX - 1)
+                part = jnp.where(mv, d, sent).max()
+            states.append((
+                part.reshape(1),
+                jax.lax.psum(mv.sum().astype(jnp.int64), "dp"),
+            ))
+    return (tuple(states),)
+
+
+def program_name(kind: str, fp: str) -> str:
+    """`mpp_<kind>_<crc32 of the program-cache fingerprint>`: the name of
+    an MPP program's jitted callable (its XLA module is `jit_<name>`) and
+    of its `copr.device.execute` span, as `parallel._program_name` names
+    the mesh programs."""
+    import zlib
+
+    return f"mpp_{kind}_{zlib.crc32(fp.encode()) & 0xFFFFFFFF:08x}"
+
+
 def _build_mpp_fn(spec: MPPJoinSpec, ps: _SideState, bs: _SideState,
                   mode: str, mesh, cap_p: int, cap_b: int, cap_out: int,
-                  cap_g: int, pack=None, remaps=None):
+                  cap_g: int, pack=None, remaps=None, name=None):
     """One shard_map program: per-shard scan+filter on both sides,
     partition exchange (or build broadcast), two-pass count+emit local
     join (non-unique and multi-column keys), then row emission, scalar
@@ -363,8 +505,6 @@ def _build_mpp_fn(spec: MPPJoinSpec, ps: _SideState, bs: _SideState,
     aggs = spec.aggs
     group_by = spec.group_by
     grouped = aggs is not None and group_by is not None
-    nk = len(group_by) if grouped else 0
-    gchunk = cap_g // S if grouped else 0
 
     def mk_keys(cols_env, key_pos):
         """(join key, partition key): the join key is the EXACT packed
@@ -381,15 +521,6 @@ def _build_mpp_fn(spec: MPPJoinSpec, ps: _SideState, bs: _SideState,
 
     def shard_fn(p_datas, p_valids, p_del, p_bounds,
                  b_datas, b_valids, b_del, b_bounds, *extra):
-        from ..copr import fusion
-        from ..copr.fusion import (grouped_partial_states,
-                                   merge_grouped_partials,
-                                   sort_group_segments)
-        from ..copr.parallel import _key_device
-
-        gbudget = extra[0] if grouped else None
-        rvals = extra[1:] if grouped else ()
-
         # ---- build side: filter, partition, exchange ------------------
         b_cols, bm = b_prep(b_datas, b_valids, b_del, b_bounds)
         bk, bmix = mk_keys(b_cols, b_key_pos)
@@ -478,125 +609,15 @@ def _build_mpp_fn(spec: MPPJoinSpec, ps: _SideState, bs: _SideState,
             return (overflow, jover, keep, tuple(flat))
 
         # ---- partial aggregation (inner join only) -------------------
-        wp = len(p_order)
-        env = {ci: probe_out[j] for j, ci in enumerate(p_order)}
-        for j in range(len(b_order)):
-            env[wp + j] = build_out[j]
-        row_mask = out_valid & hit
-
-        if grouped:
-            # -- grouped partial aggregation below the exchange --------
-            # per-shard sort-group into the static cap_g budget, then
-            # merge partials ACROSS shards on device: all_gather the
-            # compacted (key, state) rows, second sort-merge (identical
-            # on every shard), and each shard emits its 1/S slice — the
-            # readback is O(cap_g), never O(joined rows)
-            key_bits, key_flags = [], []
-            rslot = 0
-            for gi, g in enumerate(group_by):
-                rem = remaps[gi] if remaps is not None else None
-                if rem is not None:
-                    # computed string key: post-join code-space gather
-                    # through the runtime mapping operand
-                    d0, v = env[rem.src_idx]
-                    d = fusion.remap_codes(d0, rvals[rslot], cap_out)
-                    rslot += 1
-                else:
-                    d, v = compile_expr(g, env, cap_out)
-                k = _key_device(d)
-                zero = (jnp.float64(0.0) if k.dtype == jnp.float64
-                        else jnp.int64(0))
-                key_bits.append(jnp.where(v, k, zero))
-                key_flags.append(v.astype(jnp.int64))
-            order, sm, skeys, seg, pos, n_uniq = sort_group_segments(
-                key_bits, key_flags, row_mask, cap_g)
-            states = grouped_partial_states(
-                aggs, lambda e: compile_expr(e, env, cap_out),
-                order, sm, seg, cap_g)
-            out_keys = [k[pos] for k in skeys]
-            # the BUDGET is a runtime scalar slot: overflow is detected
-            # on device against it, but only the pow2 capacity shapes
-            # the compiled program
-            over_l = jax.lax.psum(
-                jnp.maximum(n_uniq - gbudget, 0), "dp")
-            slot_ok = jnp.arange(cap_g, dtype=jnp.int64) \
-                < jnp.minimum(n_uniq, cap_g)
-            g_keys = [ex.replicate(k) for k in out_keys]
-            g_ok = ex.replicate(slot_ok)
-            g_states = jax.tree_util.tree_map(ex.replicate, states)
-            mn_uniq, m_keys, m_states = merge_grouped_partials(
-                aggs, g_keys[:nk], g_keys[nk:], g_ok, g_states, cap_g)
-            over_m = jnp.maximum(mn_uniq - gbudget, 0)
-            shard = jax.lax.axis_index("dp")
-
-            def slc(y):
-                return jax.lax.dynamic_slice(y, (shard * gchunk,),
-                                             (gchunk,))
-
-            return (overflow, jover, over_l, over_m.reshape(1),
-                    mn_uniq.reshape(1), tuple(slc(k) for k in m_keys),
-                    tuple(jax.tree_util.tree_map(slc, m_states)))
-
-        # -- scalar partial aggregation --------------------------------
-        states = []
-        for a in aggs:
-            if a.name == "count":
-                if a.args:
-                    d, v = compile_expr(a.args[0], env, cap_out)
-                    states.append(jax.lax.psum(
-                        (row_mask & v).sum().astype(jnp.int64), "dp"))
-                else:
-                    states.append(jax.lax.psum(
-                        row_mask.sum().astype(jnp.int64), "dp"))
-                continue
-            d, v = compile_expr(a.args[0], env, cap_out)
-            mv = row_mask & v
-            if a.name in ("sum", "avg"):
-                st = a.partial_types()[0]
-                dd = _to_state_dtype(d, a.args[0].ftype, st)
-                states.append((
-                    jax.lax.psum(jnp.where(mv, dd, 0).sum(), "dp"),
-                    jax.lax.psum(mv.sum().astype(jnp.int64), "dp"),
-                ))
-            else:  # min / max: per-shard partial, host merges (only
-                # Sum all-reduces are used on device)
-                if a.name == "min":
-                    sent = (jnp.inf if jnp.issubdtype(d.dtype, jnp.floating)
-                            else ex.I64_MAX)
-                    part = jnp.where(mv, d, sent).min()
-                else:
-                    sent = (-jnp.inf if jnp.issubdtype(d.dtype, jnp.floating)
-                            else -ex.I64_MAX - 1)
-                    part = jnp.where(mv, d, sent).max()
-                states.append((
-                    part.reshape(1),
-                    jax.lax.psum(mv.sum().astype(jnp.int64), "dp"),
-                ))
-        return (overflow, jover, tuple(states))
+        return (overflow, jover) + _agg_tail(
+            spec, p_order, b_order, probe_out, build_out, out_valid & hit,
+            cap_out, cap_g, S, remaps, extra)
 
     if aggs is None:
         out_specs = (P(), P(), P("dp"), tuple(
             P("dp") for _ in range(2 * (len(p_order) + len(b_order)))))
-    elif grouped:
-        out_states = []
-        for a in aggs:
-            if a.name == "count":
-                out_states.append(P("dp"))
-            else:
-                out_states.append((P("dp"), P("dp")))
-        out_specs = (P(), P(), P(), P("dp"), P("dp"),
-                     tuple(P("dp") for _ in range(2 * nk)),
-                     tuple(out_states))
     else:
-        out_states = []
-        for a in aggs:
-            if a.name == "count":
-                out_states.append(P())
-            elif a.name in ("sum", "avg"):
-                out_states.append((P(), P()))
-            else:
-                out_states.append((P("dp"), P()))
-        out_specs = (P(), P(), tuple(out_states))
+        out_specs = (P(), P()) + _agg_specs(spec)
 
     # each side's range slots are one replicated int64 vector
     # (parallel._bounds_args)
@@ -609,7 +630,196 @@ def _build_mpp_fn(spec: MPPJoinSpec, ps: _SideState, bs: _SideState,
             P() for r in (remaps or ()) if r is not None)
     fn = shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
                    out_specs=out_specs, check_vma=False)
-    return _packed_jit(fn, mesh)
+    return _packed_jit(fn, mesh, name, join=True)
+
+
+# ---------------------------------------------------------------------------
+# one shard: the exchange is the identity, and the join a directory lookup
+# ---------------------------------------------------------------------------
+
+#: widest key range (hi - lo + 1 of the column statistics) a one-shard
+#: join addresses directly: an int32 directory of at most this many slots
+DIRECTORY_MAX = 1 << 26
+
+#: (table id, store column, base version) of the key columns in which a
+#: directory found two selected rows of one key: statements after the
+#: first go straight to the sorted two-pass join
+_NOT_UNIQUE: set = set()
+
+
+def _directory_plan(spec: MPPJoinSpec, ps: _SideState, bs: _SideState):
+    """How a join of one shard with itself can skip partition, exchange
+    and sort: (the directory side is the probe side, lo, hi, static
+    directory capacity, `_NOT_UNIQUE` key), or None.  The directory is
+    the smaller side whose single key column the column statistics bound
+    to at most DIRECTORY_MAX values and which may hold one row a key
+    (the build side only for a left outer join, whose probe rows all
+    emit); that it does hold one is checked on the device each run."""
+    if len(spec.probe.key_pos) != 1:
+        return None
+    sides = [(False, bs)] + ([(True, ps)] if spec.kind == "inner" else [])
+    for probe_is_dir, st in sorted(sides, key=lambda x: x[1].table.base_rows):
+        store_ci = st.an.scan.columns[st.side.key_pos[0]]
+        lo, hi, _null = st.table.column_stats(store_ci)
+        key = (st.side.table_id, store_ci, st.table.base_version)
+        span = int(hi) - int(lo) + 1
+        if not (st.table.base_rows <= span <= DIRECTORY_MAX) \
+                or key in _NOT_UNIQUE:
+            continue
+        return probe_is_dir, int(lo), int(hi), _pow2ceil(span), key
+    return None
+
+
+def _build_directory_probe(ps: _SideState, bs: _SideState,
+                           probe_is_dir: bool, louter: bool, mesh,
+                           capacity: int, name: str):
+    """First program of the one-shard join: both sides' scan + filter,
+    the directory, and every row of the other side's match in it.
+    Returns ([unsound, rows to emit], matched directory row a row, the
+    rows to emit); the counts are what the host sizes the second program
+    from, the two row vectors stay on the device."""
+    ds, ss = (ps, bs) if probe_is_dir else (bs, ps)
+    d_prep = _shard_side(ds.an, list(ds.col_order), ds.n_local,
+                         MESH_RANGE_SLOTS)
+    s_prep = _shard_side(ss.an, list(ss.col_order), ss.n_local,
+                         MESH_RANGE_SLOTS)
+    d_kp, s_kp = ds.side.key_pos[0], ss.side.key_pos[0]
+
+    def shard_fn(d_datas, d_valids, d_del, d_bounds,
+                 s_datas, s_valids, s_del, s_bounds):
+        d_cols, dm = d_prep(d_datas, d_valids, d_del, d_bounds)
+        s_cols, sm = s_prep(s_datas, s_valids, s_del, s_bounds)
+        dk, dk_v = d_cols[d_kp]
+        sk, sk_v = s_cols[s_kp]
+        # the key bounds ride behind the directory side's range slots
+        lo, hi = d_bounds[2 * MESH_RANGE_SLOTS: 2 * MESH_RANGE_SLOTS + 2]
+        j, unsound = ex.directory_join(dk, dm & dk_v, sk, sm, sk_v,
+                                       lo, hi, capacity)
+        # left outer: every selected probe row emits, NULL keys too
+        emit = sm if louter else j >= 0
+        counts = jnp.stack([unsound, emit.sum().astype(jnp.int64)])
+        return jax.lax.psum(counts, "dp"), j, emit
+
+    side = (P("dp"), P("dp"), P("dp"), P())
+    fn = shard_map(shard_fn, mesh=mesh, in_specs=side + side,
+                   out_specs=(P(), P("dp"), P("dp")), check_vma=False)
+    return _named_jit(fn, name)
+
+
+def _build_directory_emit(spec: MPPJoinSpec, ps: _SideState,
+                          bs: _SideState, probe_is_dir: bool, mesh,
+                          cap_out: int, cap_g: int, remaps, name: str):
+    """Second program of the one-shard join: the rows to emit compacted
+    into `cap_out` slots (the power of two over the count the first
+    program read back), each side's columns gathered there from the
+    arrays as they are cached, then the joined rows themselves in those
+    narrow types, validity only where a column has any, or the partial
+    aggregation of `_agg_tail`."""
+    from ..copr.parallel import _full_dtype
+
+    louter = spec.kind == "left_outer"
+    p_order, b_order = list(ps.col_order), list(bs.col_order)
+    p_n, b_n = ps.n_local, bs.n_local
+    p_full = [_full_dtype(ps.an.scan.ftypes[ci].kind) for ci in p_order]
+    b_full = [_full_dtype(bs.an.scan.ftypes[ci].kind) for ci in b_order]
+
+    def shard_fn(p_datas, p_valids, b_datas, b_valids, j, emit, *extra):
+        # the caller sized cap_out from the count the first program
+        # read back, so no emitted row is dropped
+        out_row = ops.first_marked(emit, cap_out, 0)
+        live = jnp.arange(cap_out, dtype=jnp.int32) \
+            < emit.sum().astype(jnp.int32)
+        jj = j[out_row]
+        matched = live & (jj >= 0)
+        d_row = jnp.maximum(jj, 0)
+        p_row, b_row = (d_row, out_row) if probe_is_dir \
+            else (out_row, d_row)
+
+        def take(datas, valids, n_local, rows):
+            return [(d.reshape(n_local)[rows],
+                     None if v is None else v.reshape(n_local)[rows])
+                    for d, v in zip(datas, valids)]
+
+        probe_out = take(p_datas, p_valids, p_n, p_row)
+        build_out = take(b_datas, b_valids, b_n, b_row)
+        if spec.aggs is None:
+            cols = tuple(x for d, v in probe_out + build_out
+                         for x in ((d,) if v is None else (d, v)))
+            return ((matched,) if louter else ()) + cols
+
+        def widen(pairs, full, ok):
+            return [(d.astype(t), ok if v is None else ok & v)
+                    for (d, v), t in zip(pairs, full)]
+
+        return _agg_tail(spec, p_order, b_order,
+                         widen(probe_out, p_full, live),
+                         widen(build_out, b_full, matched), matched,
+                         cap_out, cap_g, 1, remaps, extra)
+
+    rows = (P("dp"),) * 6
+    if spec.aggs is None:
+        n_out = int(louter) + sum(
+            1 + (v is not None) for v in list(ps.valids) + list(bs.valids))
+        fn = shard_map(shard_fn, mesh=mesh, in_specs=rows,
+                       out_specs=(P("dp"),) * n_out, check_vma=False)
+        return _named_jit(fn, name)
+    extra = ()
+    if spec.group_by is not None:
+        extra = (P(),) * (1 + sum(1 for r in (remaps or ())
+                                  if r is not None))
+    fn = shard_map(shard_fn, mesh=mesh, in_specs=rows + extra,
+                   out_specs=_agg_specs(spec), check_vma=False)
+    return _packed_jit(fn, mesh, name, join=True)
+
+
+def _run_directory(spec: MPPJoinSpec, ps: _SideState, bs: _SideState,
+                   plan, mesh, base_fp: str, mode: str, cap_g: int,
+                   extra: tuple, remaps):
+    """The one-shard join by directory: two programs with one small
+    readback between them (is the directory sound; how many rows emit),
+    so the second is compiled for the size of the result and nothing can
+    overflow.  Returns (what the emit program returned, rows emitted,
+    cap_out), or None when the directory met a second row of one key:
+    the caller then runs the sorted two-pass join."""
+    from ..copr.parallel import (DISPATCH_LOCK, _launch, _read_back,
+                                 _read_back_tree)
+    from ..lifecycle import dispatch_admission
+
+    probe_is_dir, lo, hi, capacity, key = plan
+    louter = spec.kind == "left_outer"
+    fp = f"{base_fp}|directory={'probe' if probe_is_dir else 'build'}" \
+        f",{capacity}"
+    name = program_name(mode, fp)
+    probe = _COMPILED.get(fp)
+    if probe is None:
+        probe = _build_directory_probe(ps, bs, probe_is_dir, louter, mesh,
+                                       capacity, name)
+        _COMPILED.put(fp, probe)
+    ds, ss = (ps, bs) if probe_is_dir else (bs, ps)
+    _check_membership_epoch()
+    with dispatch_admission(DISPATCH_LOCK):
+        counts, j, emit = _launch(probe, name, (
+            tuple(ds.datas), tuple(ds.valids), ds.del_mask,
+            _bounds_args(ds.bounds, (lo, hi)),
+            tuple(ss.datas), tuple(ss.valids), ss.del_mask,
+            _bounds_args(ss.bounds)))
+        unsound, total = (int(x) for x in _read_back(counts, join=True))
+        if unsound:
+            _NOT_UNIQUE.add(key)
+            return None
+        cap_out = _pow2ceil(total)
+        fp = f"{fp}|emit={cap_out}"
+        name = program_name(mode, fp)
+        fn = _COMPILED.get(fp)
+        if fn is None:
+            fn = _build_directory_emit(spec, ps, bs, probe_is_dir, mesh,
+                                       cap_out, cap_g, remaps, name)
+            _COMPILED.put(fp, fn)
+        args = (tuple(ps.datas), tuple(ps.valids),
+                tuple(bs.datas), tuple(bs.valids), j, emit) + extra
+        if spec.aggs is not None:
+            return fn(*args), total, cap_out
+        return _read_back_tree(_launch(fn, name, args)), total, cap_out
 
 
 # ---------------------------------------------------------------------------
@@ -634,7 +844,8 @@ def _to_column(table, an: _Analyzed, pos: int, ft, data: np.ndarray,
 def _assemble_rows(spec: MPPJoinSpec, ps: _SideState, bs: _SideState,
                    keep, flat) -> List[Chunk]:
     louter = spec.kind == "left_outer"
-    sel = np.flatnonzero(keep)
+    # `keep` None: the rows come compacted, every one of them live
+    sel = slice(None) if keep is None else np.flatnonzero(keep)
     wp = len(ps.col_order)
     probe_cols, build_cols = [], []
     for j, ci in enumerate(ps.col_order):
@@ -653,6 +864,23 @@ def _assemble_rows(spec: MPPJoinSpec, ps: _SideState, bs: _SideState,
             else build_cols + probe_cols)
     big = Chunk(cols)
     return [c for c in big.split(OUT_CHUNK_ROWS) if c.num_rows]
+
+
+def _directory_columns(spec: MPPJoinSpec, ps: _SideState, bs: _SideState,
+                       leaves, total: int) -> list:
+    """What the directory's emit program handed back (matched mask for
+    a left outer join; then each column's data, and its validity where
+    the column has any) as `_assemble_rows`'s flat (data, valid) pairs
+    over the `total` live rows."""
+    leaves = [x[:total] for x in leaves]  # host arrays already
+    live = np.ones(total, dtype=np.bool_)
+    matched = leaves.pop(0) if spec.kind == "left_outer" else live
+    flat = []
+    for st, ok in ((ps, live), (bs, matched)):
+        for v in st.valids:
+            flat.append(leaves.pop(0))
+            flat.append(ok if v is None else ok & leaves.pop(0))
+    return flat
 
 
 def _assemble_partials(spec: MPPJoinSpec, states, S: int) -> List[Chunk]:
@@ -826,21 +1054,16 @@ def _run_once(storage, spec: MPPJoinSpec, mode: str) -> List[Chunk]:
     if grouped:
         group_sig = _json.dumps(
             [serialize_expr(g) for g in spec.group_by], sort_keys=True)
-    fp = (f"mpp|{mode}|{spec.kind}|pil={spec.probe_is_left}"
-          f"|S={S} devs={mesh_ids} caps={cap_p},{cap_b},{cap_out}"
-          f"|p:{_fingerprint(ps.an, 'filter')}|Tl={ps.Tl}"
-          f"|k={spec.probe.key_pos}|wire={ps.wire_sig}"
-          f"|b:{_fingerprint(bs.an, 'filter')}|Tl={bs.Tl}"
-          f"|k={spec.build.key_pos}|wire={bs.wire_sig}"
-          f"|aggs={agg_sig}|gb={group_sig}|capg={cap_g}"
-          f"|pack={pack}"
-          + (f"|rcaps={[r.cap if r else None for r in remaps]}"
-             if remaps else ""))
-    fn = _COMPILED.get(fp)
-    if fn is None:
-        fn = _build_mpp_fn(spec, ps, bs, mode, mesh, cap_p, cap_b,
-                           cap_out, cap_g, pack=pack, remaps=remaps)
-        _COMPILED.put(fp, fn)
+    base_fp = (f"mpp|{mode}|{spec.kind}|pil={spec.probe_is_left}"
+               f"|S={S} devs={mesh_ids}"
+               f"|p:{_fingerprint(ps.an, 'filter')}|Tl={ps.Tl}"
+               f"|k={spec.probe.key_pos}|wire={ps.wire_sig}"
+               f"|b:{_fingerprint(bs.an, 'filter')}|Tl={bs.Tl}"
+               f"|k={spec.build.key_pos}|wire={bs.wire_sig}"
+               f"|aggs={agg_sig}|gb={group_sig}|capg={cap_g}"
+               f"|pack={pack}"
+               + (f"|rcaps={[r.cap if r else None for r in remaps]}"
+                  if remaps else ""))
 
     # deterministic mid-shuffle fault injection (chaos harness): fires
     # after both sides are device-resident, before the exchange program
@@ -852,23 +1075,40 @@ def _run_once(storage, spec: MPPJoinSpec, mode: str) -> List[Chunk]:
         # genuine on-device budget overflow takes
         FAILPOINTS.hit("mpp/grouped_agg_overflow", mode=mode,
                        budget=budget, cap_g=cap_g)
+    # the grouped tail's runtime operands: the group budget, then the
+    # remap mappings
+    extra = ()
+    if grouped:
+        extra = (jnp.int64(budget),) + tuple(
+            jnp.asarray(r.mapping) for r in (remaps or ())
+            if r is not None)
 
-    def bounds_args(st: _SideState):
-        # the mesh scan's slot padding, verbatim (one pad policy)
-        return _bounds_args(st.bounds)
+    # one shard exchanges with nobody: where the statistics bound a key
+    # column the join is a directory lookup, sized by what it finds
+    plan = _directory_plan(spec, ps, bs) if S == 1 else None
+    found = plan and _run_directory(spec, ps, bs, plan, mesh, base_fp,
+                                    mode, cap_g, extra, remaps)
+    if found:
+        out, rows_out, cap_out = found
+        return _finish(spec, ps, bs, mesh_ids, S, out, budget, remaps,
+                       nbytes=0, rows_out=rows_out, cap_out=cap_out)
+
+    fp = f"{base_fp}|caps={cap_p},{cap_b},{cap_out}"
+    fn = _COMPILED.get(fp)
+    if fn is None:
+        fn = _build_mpp_fn(spec, ps, bs, mode, mesh, cap_p, cap_b,
+                           cap_out, cap_g, pack=pack, remaps=remaps,
+                           name=program_name(mode, fp))
+        _COMPILED.put(fp, fn)
 
     from ..copr.parallel import DISPATCH_LOCK
     from ..lifecycle import dispatch_admission
 
+    # each side's range slots: the mesh scan's slot padding, verbatim
     args = (tuple(ps.datas), tuple(ps.valids), ps.del_mask,
-            bounds_args(ps),
+            _bounds_args(ps.bounds),
             tuple(bs.datas), tuple(bs.valids), bs.del_mask,
-            bounds_args(bs))
-    if grouped:
-        args = args + (jnp.int64(budget),)
-        for r in (remaps or ()):
-            if r is not None:
-                args = args + (jnp.asarray(r.mapping),)
+            _bounds_args(bs.bounds)) + extra
     # dispatch-time membership guard (coordination follow-up (a)): a
     # cross-host membership move between mesh build and this exchange
     # program raises the typed retriable CoordEpochMismatch — the rung
@@ -891,13 +1131,6 @@ def _run_once(storage, spec: MPPJoinSpec, mode: str) -> List[Chunk]:
             f"{jover} joined rows over the emission buffer "
             f"(cap_out={cap_out}, mode={mode}): duplicate-key expansion "
             "outgrew the two-pass emit budget")
-    if grouped:
-        over_l, over_m = int(out[2]), int(np.max(out[3]))
-        if over_l or over_m:
-            raise MPPGroupedAggOverflow(
-                f"distinct groups over budget {budget} "
-                f"(per-shard over {over_l}, merged over {over_m})")
-
     # exchange traffic accounting (static shapes: what the program moved)
     if mode == "shuffle":
         per_pair = 8 + 1  # key + bucket validity
@@ -913,22 +1146,51 @@ def _run_once(storage, spec: MPPJoinSpec, mode: str) -> List[Chunk]:
         for _ci, isz in bs.exchange_cols():
             per_row += isz + 1
         nbytes = S * S * bs.n_local * per_row
-    REGISTRY.inc("mpp_exchange_bytes_total", float(nbytes))
+    return _finish(spec, ps, bs, mesh_ids, S, out[2:], budget, remaps,
+                   nbytes=nbytes, rows_out=None, cap_out=cap_out)
+
+
+def _finish(spec: MPPJoinSpec, ps: _SideState, bs: _SideState, mesh_ids,
+            S: int, out, budget: int, remaps, nbytes: int, rows_out,
+            cap_out: int) -> List[Chunk]:
+    """Account for one finished exchange program on its `mpp.exchange`
+    span and turn what it returned into chunks.  `out` is the program's
+    output past the two exchange-overflow scalars; `rows_out` is None
+    where the rows come with a keep mask (the exchanged join) and the
+    count of leading rows where they come compacted (the directory)."""
+    from ..copr.device_health import DEVICE_HEALTH
     from ..trace import annotate
 
-    annotate(bytes=nbytes, device_ids=list(mesh_ids))
-
-    from ..copr.device_health import DEVICE_HEALTH
-
+    grouped = spec.aggs is not None and spec.group_by is not None
+    if grouped:
+        over_l, over_m, n_uniq, keys, states = out
+        if int(over_l) or int(np.max(over_m)):
+            raise MPPGroupedAggOverflow(
+                f"distinct groups over budget {budget} "
+                f"(per-shard over {int(over_l)}, merged over "
+                f"{int(np.max(over_m))})")
+    REGISTRY.inc("mpp_exchange_bytes_total", float(nbytes))
     DEVICE_HEALTH.record_success(mesh_ids)
     if grouped:
         REGISTRY.inc("mpp_grouped_agg_pushed_total")
-        annotate(groups=int(out[4][0]), group_budget=budget)
-        return _assemble_grouped(spec, ps, bs, out[4], out[5], out[6],
-                                 remaps=remaps)
-    if spec.aggs is not None:
-        return _assemble_partials(spec, out[2], S)
-    return _assemble_rows(spec, ps, bs, out[2], out[3])
+        annotate(groups=int(n_uniq[0]), group_budget=budget)
+        chunks = _assemble_grouped(spec, ps, bs, n_uniq, keys, states,
+                                   remaps=remaps)
+    elif spec.aggs is not None:
+        chunks = _assemble_partials(spec, out[0], S)
+    elif rows_out is None:
+        chunks = _assemble_rows(spec, ps, bs, out[0], out[1])
+    else:
+        chunks = _assemble_rows(spec, ps, bs, None,
+                                _directory_columns(spec, ps, bs, out,
+                                                   rows_out))
+    if rows_out is None:
+        rows_out = sum(c.num_rows for c in chunks)
+    # what the program moved between shards (static shapes), and the
+    # rows it handed out against the slots it was compiled for
+    annotate(bytes=nbytes, device_ids=list(mesh_ids), rows_out=rows_out,
+             cap_out=cap_out)
+    return chunks
 
 
 def run_mpp_join(storage, spec: MPPJoinSpec) -> Tuple[List[Chunk], str]:

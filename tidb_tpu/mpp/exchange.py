@@ -190,6 +190,38 @@ def expand_matches(sbk, bord, nb, probe_keys, probe_emit, probe_match_ok,
 
 
 # ---------------------------------------------------------------------------
+# the one-shard join: nothing to exchange, keys the statistics bound
+# ---------------------------------------------------------------------------
+
+def directory_join(dir_keys, dir_sel, keys, sel, key_ok, lo, hi,
+                   capacity: int):
+    """The local join of one shard with itself, for a key column whose
+    values the column statistics bound to [lo, hi] (`capacity` >= hi -
+    lo + 1, static): no partition, no exchange, no sort.  The directory
+    side's selected rows are written into a table addressed by key - lo;
+    every row of the other side reads its match there, one gather.
+
+    A directory holds ONE row a key: `unsound` counts the selected
+    directory rows that do not find themselves in it (a duplicate key,
+    or a key outside the statistics' bounds); the caller must then take
+    the sorted two-pass join.  Returns (j, unsound): the matched
+    directory row of each row of the other side, -1 where `sel & key_ok`
+    is false or no directory row has the key."""
+    n_dir = dir_keys.shape[0]
+    rows = jnp.arange(n_dir, dtype=jnp.int32)
+    inside = (dir_keys >= lo) & (dir_keys <= hi)
+    slot = jnp.where(dir_sel & inside, dir_keys - lo, capacity) \
+        .astype(jnp.int32)
+    table = jnp.full(capacity, -1, dtype=jnp.int32) \
+        .at[slot].set(rows, mode="drop")
+    back = table[jnp.minimum(slot, capacity - 1)]
+    unsound = (dir_sel & (back != rows)).sum().astype(jnp.int64)
+    ok = sel & key_ok & (keys >= lo) & (keys <= hi)
+    at = jnp.where(ok, keys - lo, 0).astype(jnp.int32)
+    return jnp.where(ok, table[at], -1), unsound
+
+
+# ---------------------------------------------------------------------------
 # kernelcheck registration: abstract-trace the exchange + partitioned join
 # ---------------------------------------------------------------------------
 
@@ -230,17 +262,39 @@ def _canonical_join_fn(S: int, cap: int, n_local: int, mode: str):
     return shard_fn
 
 
+def _canonical_directory_fn(capacity: int, cap_out: int):
+    """The canonical one-shard join (mpp/engine.py's two programs as
+    one): the directory over the build keys, every probe row's match in
+    it, the matched rows compacted into `cap_out` slots and the build
+    payload gathered there."""
+    from .. import ops
+
+    def shard_fn(pk, pm, bk, bm, pv):
+        j, unsound = directory_join(bk, bm, pk, pm, pm, jnp.int64(0),
+                                    jnp.int64(capacity - 1), capacity)
+        emit = j >= 0
+        rows = ops.first_marked(emit, cap_out, 0)
+        live = jnp.arange(cap_out, dtype=jnp.int32) \
+            < emit.sum().astype(jnp.int32)
+        payload = jnp.where(live, pv[jnp.maximum(j[rows], 0)], 0.0)
+        return jax.lax.psum(unsound, "dp"), jnp.int64(0), live, payload
+
+    return shard_fn
+
+
 def trace_exchange_kernel(mode: str = "shuffle"):
     """make_jaxpr stats for the canonical exchange join over a 1-device
     mesh (deterministic across environments regardless of how many
-    virtual devices the harness exposes); used by lint.kernelcheck."""
+    virtual devices the harness exposes); used by lint.kernelcheck.
+    `mode` "directory" is the one-shard join, which exchanges nothing."""
     from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     S, cap, n_local = 1, 64, 256
     mesh = Mesh(np.array(jax.devices()[:1]), ("dp",))
     fn = shard_map(
-        _canonical_join_fn(S, cap, n_local, mode), mesh=mesh,
+        _canonical_directory_fn(n_local, n_local) if mode == "directory"
+        else _canonical_join_fn(S, cap, n_local, mode), mesh=mesh,
         in_specs=(P("dp"),) * 5,
         out_specs=(P(), P(), P("dp"), P("dp")),
     )
@@ -397,11 +451,10 @@ def _canonical_grouped_fn(S: int, cap_out: int, cap_g: int):
     def shard_fn(gk, gv, meas, mm, gbudget):
         key_bits = [jnp.where(gv, gk, 0)]
         key_flags = [gv.astype(jnp.int64)]
-        order, sm, skeys, seg, pos, n_uniq = sort_group_segments(
+        order, sm, out_keys, seg, n_uniq = sort_group_segments(
             key_bits, key_flags, mm, cap_g)
         states = grouped_partial_states(
             aggs, lambda e: (meas, mm), order, sm, seg, cap_g)
-        out_keys = [k[pos] for k in skeys]
         over_l = jax.lax.psum(jnp.maximum(n_uniq - gbudget, 0), "dp")
         slot_ok = jnp.arange(cap_g, dtype=jnp.int64) \
             < jnp.minimum(n_uniq, cap_g)

@@ -60,6 +60,7 @@ from ..copr.parallel import (
     _bounds_args,
     _check_membership_epoch,
     _handle_mesh_failure,
+    _named_jit,
     _no_eligible_devices,
     _packed_jit,
     get_mesh,
@@ -76,6 +77,7 @@ from .engine import (
     MPPJoinSide,
     OUT_CHUNK_ROWS,
     _pow2ceil,
+    program_name,
     _shard_side,
     _SideState,
     _slack,
@@ -177,7 +179,7 @@ def _slot_resolver(spec: MPPJoinTreeSpec, states, n_slots: int,
 
 def _build_rung_fn(spec: MPPJoinTreeSpec, r: int, states, mesh, mode: str,
                    n_in: int, cap_p: int, cap_b: int, cap_out: int,
-                   conds_rw, elide_probe: bool = False):
+                   conds_rw, elide_probe: bool = False, name=None):
     """One rung's shard_map program.  Inputs: the intermediate arrays
     (rung 0 builds them inline from side 0's scan) + the build side's
     cached scan columns.  Outputs: the NEXT intermediate (still sharded,
@@ -354,7 +356,7 @@ def _build_rung_fn(spec: MPPJoinTreeSpec, r: int, states, mesh, mode: str,
     full_in = tuple(in_specs) + (P("dp"), P("dp"), P("dp"), P())
     fn = shard_map(shard_fn, mesh=mesh, in_specs=full_in,
                    out_specs=out_specs, check_vma=False)
-    return jax.jit(fn)
+    return _named_jit(fn, name)
 
 
 # ---------------------------------------------------------------------------
@@ -405,7 +407,7 @@ def _tree_key_remaps(spec: MPPJoinTreeSpec, states):
 
 
 def _build_final_fn(spec: MPPJoinTreeSpec, states, mesh, n_in: int,
-                    cap_g: int, aggs_rw, group_rw, remaps):
+                    cap_g: int, aggs_rw, group_rw, remaps, name=None):
     """The final partial-aggregation program over the finished
     intermediate (scalar psum or grouped sort-group + on-device merge —
     the PR 8 emitters over the slot layout)."""
@@ -448,12 +450,11 @@ def _build_final_fn(spec: MPPJoinTreeSpec, states, mesh, n_in: int,
                         else jnp.int64(0))
                 key_bits.append(jnp.where(v, k, zero))
                 key_flags.append(v.astype(jnp.int64))
-            order, sm, skeys, seg, pos, n_uniq = sort_group_segments(
+            order, sm, out_keys, seg, n_uniq = sort_group_segments(
                 key_bits, key_flags, mask, cap_g)
             states_ = grouped_partial_states(
                 aggs_rw, lambda e: compile_expr(e, env, n_in),
                 order, sm, seg, cap_g)
-            out_keys = [k[pos] for k in skeys]
             over_l = jax.lax.psum(jnp.maximum(n_uniq - gbudget, 0), "dp")
             slot_ok = jnp.arange(cap_g, dtype=jnp.int64) \
                 < jnp.minimum(n_uniq, cap_g)
@@ -537,7 +538,7 @@ def _build_final_fn(spec: MPPJoinTreeSpec, states, mesh, n_in: int,
             P() for r in (remaps or ()) if r is not None)
     fn = shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
                    out_specs=out_specs, check_vma=False)
-    return _packed_jit(fn, mesh)
+    return _packed_jit(fn, mesh, name, join=True)
 
 
 # ---------------------------------------------------------------------------
@@ -766,7 +767,8 @@ def _run_tree_once(storage, spec: MPPJoinTreeSpec, modes: List[str],
         if fn is None:
             fn = _build_rung_fn(spec, r, states, mesh, mode, n_in,
                                 cap_p, cap_b, cap_out, rung_conds[r],
-                                elide_probe=elide)
+                                elide_probe=elide,
+                                name=program_name("tree", fp))
             _COMPILED.put(fp, fn)
         FAILPOINTS.hit(TREE_FAILPOINT, rung=r, mode=mode,
                        kind=rung.kind, device_ids=mesh_ids)
@@ -844,7 +846,8 @@ def _run_tree_once(storage, spec: MPPJoinTreeSpec, modes: List[str],
     fn = _COMPILED.get(fp)
     if fn is None:
         fn = _build_final_fn(spec, states, mesh, n_in, cap_g, aggs_rw,
-                             group_rw, remaps)
+                             group_rw, remaps,
+                             name=program_name("tree", fp))
         _COMPILED.put(fp, fn)
     args = tuple(inter) + (mask,)
     if grouped:

@@ -32,6 +32,10 @@ from .segment import (  # noqa: E402
     masked_segment_max,
     masked_segment_argfirst,
     segment_min,
+    prefix_counts,
+    prefix_max,
+    prefix_sums,
+    first_marked,
 )
 from .topk import masked_top_k  # noqa: E402
 
@@ -43,5 +47,9 @@ __all__ = [
     "masked_segment_max",
     "masked_segment_argfirst",
     "segment_min",
+    "prefix_counts",
+    "prefix_max",
+    "prefix_sums",
+    "first_marked",
     "masked_top_k",
 ]

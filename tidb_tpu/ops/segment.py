@@ -11,6 +11,8 @@ primitives, PAPERS.md).
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -104,3 +106,55 @@ def segment_min(data, gidx, num_segments: int):
             for g in range(num_segments)
         ])
     return jax.ops.segment_min(data, gidx, num_segments=num_segments)
+
+
+def _two_level(x, scan, combine):
+    """An inclusive scan of a vector in two levels (along blocks of up
+    to 1,024, then over the blocks' last values): the chip's compiler
+    takes seconds over a one-level scan of millions of rows and a
+    fraction of one over this."""
+    n = x.shape[0]
+    b = math.gcd(n, 1024)
+    inner = scan(x.reshape(n // b, b), 1)
+    ends = scan(inner[:, -1], 0)
+    return combine(inner[:, 1:], inner[:, :1], ends[:-1]).reshape(n)
+
+
+def prefix_sums(x):
+    """Inclusive running sum of a vector, in its own dtype."""
+    def combine(rest, first, before):
+        before = jnp.concatenate([jnp.zeros(1, x.dtype), before])[:, None]
+        return jnp.concatenate([first + before, rest + before], axis=1)
+
+    return _two_level(x, jnp.cumsum, combine)
+
+
+def prefix_max(x):
+    """Inclusive running maximum of a vector."""
+    def combine(rest, first, before):
+        low = jnp.full(1, _extreme(x.dtype, want_max=False), x.dtype)
+        before = jnp.concatenate([low, before])[:, None]
+        return jnp.concatenate([jnp.maximum(first, before),
+                                jnp.maximum(rest, before)], axis=1)
+
+    return _two_level(x, lambda a, axis: jax.lax.cummax(a, axis=axis),
+                      combine)
+
+
+def prefix_counts(mask):
+    """Inclusive running count of a boolean vector, int32."""
+    return prefix_sums(mask.astype(jnp.int32))
+
+
+def first_marked(mask, size: int, fill: int, counts=None):
+    """Row numbers of the first `size` rows `mask` marks, in order, int32;
+    slots past their number hold `fill` (`jnp.nonzero(mask, size=size,
+    fill_value=fill)[0]`, by one prefix count and one scatter of the row
+    numbers instead of its prefix sum, bincount and second prefix sum).
+    `counts` is `prefix_counts(mask)` where the caller has it."""
+    n = mask.shape[0]
+    if counts is None:
+        counts = prefix_counts(mask)
+    return jnp.full(size, fill, dtype=jnp.int32) \
+        .at[jnp.where(mask, counts - 1, size)] \
+        .set(jnp.arange(n, dtype=jnp.int32), mode="drop")

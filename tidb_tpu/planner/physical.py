@@ -1455,8 +1455,11 @@ def _physical_agg(plan: LogicalAggregation,
 
 # device-join gates: the build side is broadcast to every shard, so it must
 # be decisively the small side; the key must be int-domain and plan-time
-# unique (lookup join semantics: <= 1 match per probe row)
-DEVICE_JOIN_BUILD_MAX = 2_000_000
+# unique (lookup join semantics: <= 1 match per probe row).  2^23 rows of
+# keys and payload are a few hundred MB a shard; under the old cap of
+# 2,000,000 TPC-H Q3 at SF10, whose build side is estimated at just over
+# that, fell to the join tree (88 s a statement; PERF.md, PR 36)
+DEVICE_JOIN_BUILD_MAX = 1 << 23
 _DJ_KEY_KINDS = (TypeKind.INT, TypeKind.UINT, TypeKind.DECIMAL,
                  TypeKind.DATE)
 _DJ_PAYLOAD_KINDS = _DJ_KEY_KINDS + (TypeKind.FLOAT, TypeKind.BOOL)

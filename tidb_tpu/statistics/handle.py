@@ -19,7 +19,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from ..types import TypeKind
-from .histogram import CMSketch, FMSketch, Histogram
+from .histogram import sorted_ints, CMSketch, FMSketch, Histogram
 from ..util_concurrency import make_rlock
 
 
@@ -143,13 +143,24 @@ class StatsHandle:
                 # shouldn't happen (dict-encoded), but guard
                 vals = np.array([hash(x) & 0x7FFFFFFF for x in vals],
                                 dtype=np.int64)
-            vals64 = vals.astype(np.float64, copy=False)
-            hist = Histogram.build(vals64, nulls, n_buckets)
             cms = CMSketch()
-            if len(vals):
-                cms.insert_batch(vals.astype(np.int64, copy=False)
-                                 if vals.dtype != np.float64
-                                 else vals.view(np.int64))
+            if vals.dtype.kind in "iub" and len(vals):
+                # one sort serves both: the histogram reads the sorted
+                # column, the sketch each distinct value with its count
+                sv = sorted_ints(vals)
+                hist = Histogram.build(sv, nulls, n_buckets, presorted=True)
+                first = np.flatnonzero(
+                    np.concatenate(([True], sv[1:] != sv[:-1])))
+                cms.insert_batch(
+                    sv[first].astype(np.int64),
+                    np.diff(np.append(first, len(sv))))
+            else:
+                hist = Histogram.build(vals.astype(np.float64, copy=False),
+                                       nulls, n_buckets)
+                if len(vals):
+                    cms.insert_batch(vals.astype(np.int64, copy=False)
+                                     if vals.dtype != np.float64
+                                     else vals.view(np.int64))
             stats.columns[ci] = ColumnStats(hist, cms, nulls, hist.ndv)
         for offs in (index_offsets or ()):
             offs = tuple(offs)
@@ -173,11 +184,24 @@ class StatsHandle:
                                  decode_strings=False)
         cols = [chunk.col(i).data for i in range(len(offs))]
         valids = [chunk.col(i).validity() for i in range(len(offs))]
-        seen = set()
-        for h in range(chunk.num_rows):
-            if h in dele or not all(v[h] for v in valids):
-                continue
-            seen.add(tuple(c[h] for c in cols))
+        keep = np.ones(chunk.num_rows, dtype=np.bool_)
+        for v in valids:
+            keep &= v
+        if dele:
+            keep[[h for h in dele if h < chunk.num_rows]] = False
+        cols = [c[keep] for c in cols]
+        if not inserted and all(c.dtype.kind in "iub" for c in cols):
+            # integer key tuples of the base rows alone: sort, count the
+            # places where a row differs from the one before
+            if not len(cols[0]):
+                return 1
+            order = np.lexsort(cols[::-1]) if len(cols) > 1 else None
+            new = np.zeros(len(cols[0]) - 1, dtype=np.bool_)
+            for c in cols:
+                c = np.sort(c) if order is None else c[order]
+                new |= c[1:] != c[:-1]
+            return int(new.sum()) + 1
+        seen = set(zip(*(c.tolist() for c in cols)))
         dict_cols = store.dict_encoded_cols()
         for row in inserted.values():
             key = []

@@ -13,6 +13,18 @@ from typing import List, Optional, Tuple
 import numpy as np
 
 
+def sorted_ints(values: np.ndarray) -> np.ndarray:
+    """An integer column sorted, in the narrowest integer type that holds
+    it (a 16-bit sort is a counting sort, a 32-bit one half the bytes)."""
+    if len(values):
+        lo, hi = int(values.min()), int(values.max())
+        for dt in (np.int16, np.int32):
+            if np.iinfo(dt).min <= lo and hi <= np.iinfo(dt).max:
+                values = values.astype(dt)
+                break
+    return np.sort(values)
+
+
 @dataclass
 class Bucket:
     upper: float  # inclusive upper bound
@@ -34,11 +46,12 @@ class Histogram:
 
     @staticmethod
     def build(values: np.ndarray, null_count: int = 0,
-              n_buckets: int = 64) -> "Histogram":
+              n_buckets: int = 64, presorted: bool = False) -> "Histogram":
         n = len(values)
         if n == 0:
             return Histogram([], null_count, 0, 0)
-        v = np.sort(values.astype(np.float64, copy=False))
+        v = (values.astype(np.float64, copy=False) if presorted
+             else np.sort(values.astype(np.float64, copy=False)))
         ndv = int((np.diff(v) != 0).sum()) + 1
         per = max(n // n_buckets, 1)
         buckets: List[Bucket] = []
@@ -114,19 +127,37 @@ class CMSketch:
         """[depth, n] bucket indices (splitmix-style avalanche)."""
         x = vals.astype(np.uint64)
         out = np.empty((self.depth, len(vals)), dtype=np.int64)
+        w = np.uint64(self.width)
+        pow2 = self.width & (self.width - 1) == 0
+        h = np.empty_like(x)
+        t = np.empty_like(x)
         for d in range(self.depth):
-            h = x + np.uint64(self._SEEDS[d])
-            h = (h ^ (h >> np.uint64(30))) * np.uint64(0xBF58476D1CE4E5B9)
-            h = (h ^ (h >> np.uint64(27))) * np.uint64(0x94D049BB133111EB)
-            h = h ^ (h >> np.uint64(31))
-            out[d] = (h % np.uint64(self.width)).astype(np.int64)
+            # in place: at tens of millions of rows the temporaries of
+            # the expression form cost more than the arithmetic
+            np.add(x, np.uint64(self._SEEDS[d]), out=h)
+            for shift, mul in ((30, 0xBF58476D1CE4E5B9),
+                               (27, 0x94D049BB133111EB)):
+                np.right_shift(h, np.uint64(shift), out=t)
+                np.bitwise_xor(h, t, out=h)
+                np.multiply(h, np.uint64(mul), out=h)
+            np.right_shift(h, np.uint64(31), out=t)
+            np.bitwise_xor(h, t, out=h)
+            # a 64-bit modulo is a division a row; a power of two masks
+            if pow2:
+                np.bitwise_and(h, w - np.uint64(1), out=t)
+            else:
+                np.remainder(h, w, out=t)
+            out[d] = t
         return out
 
-    def insert_batch(self, vals: np.ndarray):
+    def insert_batch(self, vals: np.ndarray, counts=None):
+        """Count every value of `vals` once, or `counts[i]` times."""
         idx = self._hash(vals)
         for d in range(self.depth):
-            np.add.at(self.table[d], idx[d], 1)
-        self.count += len(vals)
+            self.table[d] += np.bincount(
+                idx[d], weights=counts, minlength=self.width
+            ).astype(np.int64)
+        self.count += len(vals) if counts is None else int(counts.sum())
 
     def query(self, val: int) -> int:
         idx = self._hash(np.array([val], dtype=np.int64))
