@@ -391,11 +391,14 @@ def test_group_indices_multicol_vectorized():
                 np.array([True, True, False, True, True, True]))
     gb = Column(ty_string(),
                 np.array(["x", "y", "x", "x", "y", "x"], dtype=object))
-    gidx, keys, G = group_indices([ga, gb])
-    # first-appearance group ids, NULL is its own group, keys are python
-    # tuples with None for NULL — the old row-at-a-time dict contract
+    gidx, first, G = group_indices([ga, gb])
+    # first-appearance group ids, NULL is its own group — the old
+    # row-at-a-time dict contract; the keys are the columns taken at
+    # each group's first row
     assert G == 4
     assert gidx.tolist() == [0, 1, 2, 3, 1, 0]
+    assert first.tolist() == [0, 1, 2, 3]
+    keys = list(zip(ga.take(first).to_pylist(), gb.take(first).to_pylist()))
     assert keys == [(3, "x"), (1, "y"), (None, "x"), (2, "x")]
 
 

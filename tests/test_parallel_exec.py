@@ -70,18 +70,21 @@ def test_hash_join_probe_workers(sess):
     assert w >= 4
 
 
-def test_hashagg_final_workers_partition_merge(sess):
-    # 12k distinct groups -> partial rows >> 8192 threshold: the final
-    # merge partitions across tidb_hashagg_final_concurrency workers
+def test_hashagg_final_merge_is_one_pass_whatever_the_knob(sess):
+    # 12k distinct groups, partial rows past the 8,192 at which the final
+    # merge used to hash-partition across tidb_hashagg_final_concurrency
+    # workers: that pool was slower than the single vectorised merge at
+    # every row count measured (PERF.md section 6, PR 37), so the merge
+    # is one pass and the knob, still accepted, starts no worker
     sql = "select g, count(*), sum(a) from p group by g order by g limit 5"
     sess.execute("set tidb_use_tpu = 0")  # host HashAgg path
     sess.execute("set tidb_hashagg_final_concurrency = 1")
-    serial, _ = _workers_used(sess, sql)
+    serial, w1 = _workers_used(sess, sql)
     sess.execute("set tidb_hashagg_final_concurrency = 4")
-    par, w = _workers_used(sess, sql)
+    par, w4 = _workers_used(sess, sql)
     sess.execute("set tidb_use_tpu = 1")
     assert serial == par
-    assert w >= 4
+    assert w1 == w4
 
 
 def test_umbrella_executor_concurrency(sess):

@@ -102,6 +102,21 @@ def test_q3_as_served_is_the_reference(bench, loaded, engine, seed, index):
         _as_wire(bench["q3"].control(tables, q["params"][index])), want)
 
 
+def test_no_aggregate_of_q3_walks_rows_in_python(bench, loaded, engine):
+    """`agg_rowwise_groups_total` counts rows an aggregate walked one at a
+    time in Python; a Q3 statement (partial aggregate, the root's final
+    merge over its groups, TopN) leaves it where it was."""
+    from tidb_tpu.metrics import REGISTRY
+
+    domain, _ = loaded(SEEDS[0])
+    sess = domain.new_session()
+    sess.execute(f"set tidb_use_tpu = {0 if engine == 'host' else 1}")
+    before = REGISTRY.get("agg_rowwise_groups_total")
+    rows = sess.query(bench["traffic"].render(bench["query"], 0))
+    assert len(rows) == 10
+    assert REGISTRY.get("agg_rowwise_groups_total") == before
+
+
 def test_one_chip_joins_by_directory_and_names_its_programs(bench, loaded,
                                                            engine):
     """On a mesh of one the customer-orders join exchanges nothing: its
@@ -213,6 +228,15 @@ def test_sort_indices_breaks_decimal_ties_by_the_next_key(desc):
                            [3, 1, 9, 2, 4])
     got = _order(chunk, (0, True), (1, desc))
     assert got == ([2, 4, 0, 3, 1] if desc else [4, 2, 1, 3, 0])
+
+
+def test_sort_indices_orders_strings_of_digits_as_text():
+    from tidb_tpu.chunk import Chunk, Column
+    from tidb_tpu.types import ty_string
+
+    chunk = Chunk([Column.from_values(ty_string(), ["10", "9", "100", "2"])])
+    assert _order(chunk, (0, False)) == [0, 2, 3, 1]
+    assert _order(chunk, (0, True)) == [1, 3, 2, 0]
 
 
 def test_sort_indices_ranks_escalated_integer_sums_and_strings_as_before():

@@ -106,10 +106,17 @@ def chunk_from_pylists(ftypes: Sequence[FieldType], cols: Sequence[Sequence]) ->
 
 
 def concat_chunks(chunks: Sequence[Chunk]) -> Optional[Chunk]:
+    """All the chunks' rows as one chunk, each column copied once."""
     chunks = [c for c in chunks if c is not None and c.num_rows >= 0]
     if not chunks:
         return None
-    out = chunks[0]
-    for c in chunks[1:]:
-        out = out.append(c)
-    return out
+    if len(chunks) == 1:
+        return chunks[0]
+    cols = []
+    for parts in zip(*(c.columns for c in chunks)):
+        valid = None
+        if any(p.valid is not None for p in parts):
+            valid = np.concatenate([p.validity() for p in parts])
+        cols.append(Column(parts[0].ftype,
+                           np.concatenate([p.data for p in parts]), valid))
+    return Chunk(cols)

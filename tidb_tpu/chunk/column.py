@@ -155,9 +155,12 @@ class Column:
         return [self.get(i) for i in range(len(self))]
 
     def nbytes(self) -> int:
-        b = self.data.nbytes if self.data.dtype != object else sum(
-            len(str(x)) for x in self.data
-        )
+        if self.data.dtype != object:
+            b = self.data.nbytes
+        elif self.ftype.kind == TypeKind.DECIMAL:
+            b = 16 * len(self.data)  # exact ints of up to 38 digits
+        else:
+            b = sum(len(str(x)) for x in self.data)
         if self.valid is not None:
             b += self.valid.nbytes
         return int(b)

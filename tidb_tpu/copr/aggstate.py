@@ -15,7 +15,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from ..chunk import Chunk, Column
+from ..chunk import Chunk, Column, concat_chunks
 from ..errors import ExecutorError
 from ..expr.aggregation import AggDesc, avg_type, sum_type
 from ..expr.vec import Vec
@@ -30,90 +30,151 @@ def _sum_repr(v: Vec, st: FieldType) -> np.ndarray:
     return cast_vec(v, st).data
 
 
-def group_indices(cols: List[Column]) -> Tuple[np.ndarray, List[tuple], int]:
-    """Map rows to dense group ids.  Returns (gidx, key_tuples, G).
+#: mixed-radix group codes stay below this, so a product never wraps int64
+_CODE_SPAN_MAX = 1 << 62
+#: an integer key column whose values span less than this is coded by its
+#: offset from the minimum (no sort); a wider one by np.unique
+_OFFSET_SPAN_MAX = 1 << 31
 
-    Single fixed-width columns factorize through the native open-addressing
-    hash (tidb_tpu/native), assigning codes in first-appearance order — the
-    C-speed replacement for the reference's row-at-a-time agg hash maps."""
-    n = len(cols[0]) if cols else 0
-    if not cols:
-        return np.zeros(n, dtype=np.int64), [()], 1
-    if len(cols) == 1 and cols[0].data.dtype != object and n:
-        from ..native import KeyTable
 
-        c = cols[0]
-        data = c.data
-        if data.dtype == np.float64:
-            data = np.where(data == 0.0, 0.0, data).view(np.int64)
-        else:
-            data = data.astype(np.int64, copy=False)
-        valid = c.valid  # None = all valid
-        kt = KeyTable(min(n, 1 << 20))
-        gidx = kt.upsert(data, valid)
-        n_named = int(gidx.max()) + 1 if (gidx >= 0).any() else 0
-        has_null = bool((gidx < 0).any())
-        if has_null:
-            gidx = np.where(gidx < 0, n_named, gidx)  # NULL = its own group
-        G = n_named + (1 if has_null else 0)
-        # first-occurrence row per group -> key tuples
-        first = np.full(G, n, dtype=np.int64)
-        np.minimum.at(first, gidx, np.arange(n, dtype=np.int64))
-        keys = [(c.get(int(first[g])),) for g in range(G)]
-        return gidx, keys, G
-    # multi-column / object keys: per-column vectorized factorize +
-    # mixed-radix combine (re-factorized per step so codes stay < n and
-    # never overflow), then a first-appearance remap so group ids and
-    # key ordering match the old row-at-a-time dict exactly.  NULL is
-    # its own code per column (validity joins the key), and equal float
-    # keys collapse like the single-column bit-domain path.
+def _count_rowwise(n: int) -> None:
+    """Count rows an aggregate walked one at a time in Python (the paths
+    no array form covers); 0 where the array forms served."""
+    if n:
+        from ..metrics import REGISTRY
+
+        REGISTRY.inc("agg_rowwise_groups_total", float(n))
+
+
+def group_indices(cols: List[Column]) -> Tuple[np.ndarray, np.ndarray, int]:
+    """Map rows to dense group ids.  Returns (gidx, first, G): group ids
+    in first-appearance order and, per group, the row of its first
+    appearance (ascending), from which `group_key_column` makes the
+    group-key output columns.
+    NULL is its own group per column, -0.0 and 0.0 are one float key,
+    strings group by value.
+
+    Every row's key becomes one int64 (a single fixed-width column's own
+    bits; otherwise a code per column, combined mixed-radix), which the
+    native open-addressing hash (tidb_tpu/native) numbers in
+    first-appearance order — the C-speed replacement for the reference's
+    row-at-a-time agg hash maps; without the native library np.unique
+    does.  Nothing here runs once a row or once a group in Python."""
+    from ..native import available
+
+    n = len(cols[0])
     if n == 0:
-        return np.zeros(0, dtype=np.int64), [], 0
-    combined = np.zeros(n, dtype=np.int64)
+        return np.zeros(0, dtype=np.int64), np.zeros(0, dtype=np.int64), 0
+    native = available()
+    if native and len(cols) == 1 and cols[0].data.dtype != object:
+        keys, valid = cols[0].data, cols[0].valid
+        if keys.dtype.kind == "f":
+            keys = np.where(keys == 0.0, 0.0, keys).astype(
+                np.float64, copy=False).view(np.int64)
+    else:
+        keys, valid = _combined_codes(cols), None  # NULL is in the code
+    if native:
+        return _hashed_groups(keys.astype(np.int64, copy=False), valid)
+    _u, first, inv = np.unique(keys, return_index=True, return_inverse=True)
+    # np.unique numbers groups by key value; renumber by first appearance
+    order = np.argsort(first)
+    rank = np.empty(len(order), dtype=np.int64)
+    rank[order] = np.arange(len(order), dtype=np.int64)
+    return rank[inv.reshape(-1)], first[order].astype(np.int64), len(order)
+
+
+def group_key_column(c: Column, first: np.ndarray,
+                     ftype: Optional[FieldType] = None) -> Column:
+    """The group-key output column: `c` at each group's first row, with
+    the type's placeholder under a NULL (as `Column.from_values` leaves
+    it), so nothing downstream meets what the source held there."""
+    data = c.data[first]
+    valid = None if c.valid is None else c.valid[first]
+    if valid is not None and not valid.all():
+        data[~valid] = (Column._object_fill(c.ftype)
+                        if data.dtype == object else 0)
+    return Column(ftype or c.ftype, data, valid)
+
+
+def _combined_codes(cols: List[Column]) -> np.ndarray:
+    """One int64 a row that is equal exactly where every key column is."""
+    combined, span = None, 1  # codes in [0, span)
     for c in cols:
-        inv, card = _factorize_column(c)
-        combined = combined * card + inv
-        combined = np.unique(combined, return_inverse=True)[1] \
-            .astype(np.int64)
-    G = int(combined.max()) + 1
-    first = np.full(G, n, dtype=np.int64)
-    np.minimum.at(first, combined, np.arange(n, dtype=np.int64))
-    order = np.argsort(first, kind="stable")
-    rank = np.empty(G, dtype=np.int64)
-    rank[order] = np.arange(G, dtype=np.int64)
-    gidx = rank[combined]
-    # G key tuples gathered from each group's first row (G-scale, the
-    # same per-group materialization the single-column path does)
-    keys = [tuple(c.get(int(first[g])) for c in cols) for g in order]
-    return gidx, keys, G
+        codes, card = _factorize_column(c)
+        if combined is None:
+            combined, span = codes, card
+            continue
+        if span * card > _CODE_SPAN_MAX:
+            # re-factorize what is combined so far: its span falls to the
+            # number of distinct prefixes (at most n) and cannot wrap
+            uniq, combined = np.unique(combined, return_inverse=True)
+            combined, span = combined.reshape(-1), len(uniq)
+        combined = combined * card + codes
+        span *= card
+    return combined
+
+
+def _hashed_groups(keys: np.ndarray, valid: Optional[np.ndarray]
+                   ) -> Tuple[np.ndarray, np.ndarray, int]:
+    """group_indices of int64 keys by the native key table."""
+    from ..native import KeyTable
+
+    # codes in first-appearance order; NULL -1
+    codes = KeyTable(min(len(keys), 1 << 20)).upsert(keys, valid)
+    # a row opens a group exactly where the running maximum rises
+    top = np.maximum.accumulate(codes)
+    first = np.flatnonzero(np.diff(top, prepend=-1) > 0)
+    if valid is None or codes.min() >= 0:
+        return codes, first, len(first)
+    # NULL is a group of its own, numbered where it first appears
+    null = codes < 0
+    null_first = int(np.argmax(null))
+    g_null = int(np.searchsorted(first, null_first))
+    gidx = codes + (codes >= g_null)
+    gidx[null] = g_null
+    return gidx, np.insert(first, g_null, null_first), len(first) + 1
 
 
 def _factorize_column(c: Column) -> Tuple[np.ndarray, int]:
-    """(dense codes, cardinality) for one key column, NULL rows coded 0.
-    np.unique vectorizes str/numeric payloads; exotic object payloads
-    (mixed types that don't compare) fall back to a hash-map pass."""
+    """(codes, cardinality) for one key column: codes in [0, cardinality),
+    equal keys equal codes, NULL rows coded 0 apart from every value.
+    Integers of a narrow range are coded by offset, everything else by
+    np.unique (str and numeric payloads); exotic object payloads (mixed
+    types that don't compare) fall back to a hash-map pass, which counts
+    itself."""
     n = len(c)
     data, valid = c.data, c.valid
+    live = data if valid is None else data[valid]
+    base = 0 if valid is None else 1  # code 0 is NULL's where there is one
+    if data.dtype.kind in "ib" and len(live):
+        lo, hi = int(live.min()), int(live.max())
+        if hi - lo < _OFFSET_SPAN_MAX:
+            codes = data.astype(np.int64) + (base - lo)
+            if valid is not None:
+                codes[~valid] = 0
+            return codes, hi - lo + 1 + base
     try:
+        if data.dtype.kind == "f":
+            live = np.where(live == 0.0, 0.0, live)  # -0.0 is 0.0's key
+        uniq, iv = np.unique(live, return_inverse=True)
+        iv = iv.reshape(-1).astype(np.int64, copy=False)
         if valid is None:
-            _u, iv = np.unique(data, return_inverse=True)
-            return iv.astype(np.int64, copy=False), max(len(_u), 1)
+            return iv, max(len(uniq), 1)
         inv = np.zeros(n, dtype=np.int64)
-        _u, iv = np.unique(data[valid], return_inverse=True)
-        inv[valid] = iv.astype(np.int64, copy=False) + 1
-        return inv, len(_u) + 1
+        inv[valid] = iv + 1
+        return inv, len(uniq) + 1
     except TypeError:
-        codes: Dict[object, int] = {}
+        _count_rowwise(n)
+        seen: Dict[object, int] = {}
         inv = np.zeros(n, dtype=np.int64)
-        vv = valid
         for i, x in enumerate(data.tolist()):
-            if vv is not None and not vv[i]:
+            if valid is not None and not valid[i]:
                 continue  # NULL keeps code 0
-            g = codes.get(x)
+            g = seen.get(x)
             if g is None:
-                g = codes[x] = len(codes) + 1
+                g = seen[x] = len(seen) + 1
             inv[i] = g
-        return inv, len(codes) + 1
+        return inv, len(seen) + 1
 
 
 def partial_states(agg: AggDesc, arg_vecs: List[Vec], gidx: np.ndarray,
@@ -145,49 +206,36 @@ def partial_states(agg: AggDesc, arg_vecs: List[Vec], gidx: np.ndarray,
         return [sum_col, Column(pts[1], cnt)]
     if name in ("min", "max"):
         st = pts[0]
-        if st.kind == TypeKind.STRING:
-            out = np.empty(G, dtype=object)
-            out[:] = None
-            for i in range(len(gidx)):
-                if not valid[i]:
-                    continue
-                g = gidx[i]
-                x = v.data[i]
-                if out[g] is None or (x < out[g] if name == "min" else x > out[g]):
-                    out[g] = x
-            ovalid = np.array([x is not None for x in out], dtype=np.bool_)
-            data = np.empty(G, dtype=object)
-            for i in range(G):
-                data[i] = out[i] if out[i] is not None else ""
-            return [Column(st, data, ovalid)]
-        ident = (
-            np.iinfo(np.int64).max if name == "min" else np.iinfo(np.int64).min
-        ) if st.np_dtype != np.float64 else (np.inf if name == "min" else -np.inf)
-        acc = np.full(G, ident, dtype=st.np_dtype)
-        masked = np.where(valid, v.data, ident)
-        if name == "min":
-            np.minimum.at(acc, gidx, masked)
+        gi, live = gidx[valid], v.data[valid]
+        if st.np_dtype == object:  # strings, wide decimals: by value
+            acc = np.empty(G, dtype=object)
+            acc[:] = Column._object_fill(st)
         else:
-            np.maximum.at(acc, gidx, masked)
-        cnt = np.bincount(gidx, weights=valid.astype(np.float64), minlength=G)
-        ovalid = cnt > 0
-        acc = np.where(ovalid, acc, 0)
-        return [Column(st, acc.astype(st.np_dtype), ovalid)]
+            acc = np.zeros(G, dtype=st.np_dtype)
+            live = live.astype(acc.dtype, copy=False)
+        # any of its values starts a group, so no type needs an identity
+        acc[gi] = live
+        (np.minimum if name == "min" else np.maximum).at(acc, gi, live)
+        ovalid = np.zeros(G, dtype=np.bool_)
+        ovalid[gi] = True
+        return [Column(st, acc, ovalid)]
     if name == "first_row":
         st = pts[0]
-        seen = np.zeros(G, dtype=np.bool_)
-        if st.kind == TypeKind.STRING:
+        # each group's first row, NULL or not (a group without rows, the
+        # scalar aggregate over nothing, reads NULL)
+        n = len(gidx)
+        first = np.full(G, n, dtype=np.int64)
+        np.minimum.at(first, gidx, np.arange(n, dtype=np.int64))
+        groups = np.flatnonzero(first < n)
+        first = first[groups]
+        if st.np_dtype == object:
             data = np.empty(G, dtype=object)
-            data[:] = ""
+            data[:] = Column._object_fill(st)
         else:
             data = np.zeros(G, dtype=st.np_dtype)
         ovalid = np.zeros(G, dtype=np.bool_)
-        for i in range(len(gidx)):
-            g = gidx[i]
-            if not seen[g]:
-                seen[g] = True
-                data[g] = v.data[i]
-                ovalid[g] = valid[i]
+        data[groups] = v.data[first]
+        ovalid[groups] = valid[first]
         return [Column(st, data, ovalid)]
     if name in ("bit_and", "bit_or", "bit_xor"):
         ident = -1 if name == "bit_and" else 0
@@ -213,6 +261,7 @@ def partial_states(agg: AggDesc, arg_vecs: List[Vec], gidx: np.ndarray,
 
         sep = agg.ftype and ","  # MySQL default separator
         strs = _str_data(v)
+        _count_rowwise(len(gidx))
         parts: List[List[str]] = [[] for _ in range(G)]
         for i in range(len(gidx)):
             if valid[i]:
@@ -273,6 +322,7 @@ def merge_states(agg: AggDesc, state_cols: List[Column], gidx: np.ndarray,
         parts: List[List[str]] = [[] for _ in range(G)]
         sv = state_cols[0]
         valid = sv.validity()
+        _count_rowwise(len(gidx))
         for i in range(len(gidx)):
             if valid[i]:
                 parts[gidx[i]].append(str(sv.data[i]))
@@ -296,21 +346,17 @@ def merge_partials_to_final(n_keys: int, aggs: List[AggDesc],
     Returns None when there are no input rows AND n_keys > 0 (empty group-by
     result); for scalar agg (n_keys == 0) the caller handles the
     one-row-from-nothing case."""
-    rows = [c for c in chunks if c is not None and c.num_rows > 0]
-    if not rows:
+    whole = concat_chunks(
+        [c for c in chunks if c is not None and c.num_rows > 0])
+    if whole is None:
         return None
-    whole = rows[0]
-    for c in rows[1:]:
-        whole = whole.append(c)
     key_cols = [whole.col(i) for i in range(n_keys)]
     if key_cols:
-        gidx, keys, G = group_indices(key_cols)
-    else:
-        gidx, keys, G = np.zeros(whole.num_rows, dtype=np.int64), [()], 1
-    out_cols: List[Column] = []
-    for ci in range(n_keys):
-        vals = [k[ci] for k in keys]
-        out_cols.append(Column.from_values(key_cols[ci].ftype, vals))
+        gidx, first, G = group_indices(key_cols)
+    else:  # scalar aggregation: every row merges into the one group
+        gidx, first, G = np.zeros(whole.num_rows, dtype=np.int64), None, 1
+    out_cols: List[Column] = [group_key_column(c, first)
+                              for c in key_cols]
     off = n_keys
     for a in aggs:
         width = len(a.partial_types())

@@ -16,6 +16,7 @@ from ..chunk import Chunk, Column
 from ..errors import ExecutorError
 from ..expr.expression import eval_bool_mask
 from ..expr.vec import Vec
+from ..types import ty_int
 from . import aggstate
 from .ir import (
     DAG,
@@ -112,15 +113,14 @@ def grouped_partial_chunks(group_by, aggs, chunks) -> List[Chunk]:
 def _run_agg(agg_ir: AggregationIR, chunk: Chunk) -> Chunk:
     gcols = [g.eval(chunk).to_column() for g in agg_ir.group_by]
     if gcols:
-        gidx, keys, G = aggstate.group_indices(gcols)
+        gidx, first, G = aggstate.group_indices(gcols)
     else:
         # scalar aggregation: one group, one output row
-        gidx, keys, G = np.zeros(chunk.num_rows, dtype=np.int64), [()], 1
-    out_cols: List[Column] = []
+        gidx, first, G = np.zeros(chunk.num_rows, dtype=np.int64), None, 1
     # group-key output columns (one row per group)
-    for ci, g in enumerate(agg_ir.group_by):
-        vals = [k[ci] for k in keys]
-        out_cols.append(Column.from_values(g.ftype, vals))
+    out_cols: List[Column] = [
+        aggstate.group_key_column(c, first, g.ftype)
+        for g, c in zip(agg_ir.group_by, gcols)]
     for a in agg_ir.aggs:
         if a.distinct:
             cols = _distinct_states(a, chunk, gidx, G)
@@ -136,16 +136,12 @@ def _run_agg(agg_ir: AggregationIR, chunk: Chunk) -> Chunk:
 
 def _distinct_states(a, chunk: Chunk, gidx: np.ndarray, G: int):
     """COUNT/SUM/AVG(DISTINCT x): dedup (group, value) pairs first."""
-    arg_vecs = [x.eval(chunk) for x in a.args]
-    n = chunk.num_rows
-    seen = set()
-    keep = np.zeros(n, dtype=np.bool_)
-    cols = [v.to_column() for v in arg_vecs]
-    for i in range(n):
-        key = (int(gidx[i]),) + tuple(c.get(i) for c in cols)
-        if key not in seen:
-            seen.add(key)
-            keep[i] = True
+    cols = [x.eval(chunk).to_column() for x in a.args]
+    keep = np.zeros(chunk.num_rows, dtype=np.bool_)
+    if chunk.num_rows:
+        # the first row of every distinct (group, values...) combination
+        pair_key = [Column(ty_int(False), gidx)] + cols
+        keep[aggstate.group_indices(pair_key)[1]] = True
     sub_vecs = [Vec.from_column(c.filter(keep)) for c in cols]
     return aggstate.partial_states(a, sub_vecs, gidx[keep], G)
 
@@ -163,13 +159,15 @@ def _object_ranks(data: np.ndarray) -> np.ndarray:
     """int64 sort keys of an object column: exact Python ints (a wide
     decimal's scaled values, an escalated integer sum) order by value,
     strings as text."""
+    try:
+        out = data.astype(np.int64)
+        # a string of digits converts too, but does not equal its number
+        if bool((out == data).all()) and (
+                not len(out) or out.min() > np.iinfo(np.int64).min):
+            return out  # negating it for DESC cannot wrap
+    except (OverflowError, TypeError, ValueError):
+        pass  # past int64, or not numbers: rank the values themselves
     if all(isinstance(x, (int, np.integer)) for x in data):
-        try:
-            out = data.astype(np.int64)
-            if not len(out) or out.min() > np.iinfo(np.int64).min:
-                return out  # negating it for DESC cannot wrap
-        except OverflowError:
-            pass
         uniq = sorted(set(data))
     else:
         data = [str(x) for x in data]

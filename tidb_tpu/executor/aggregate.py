@@ -93,8 +93,9 @@ class HashAggExec(Executor):
                     scalar_parts = [concat_chunks(scalar_parts)]
                 continue
             self._buffered.append(c)
-            self._consumed += c.nbytes()
-            self.ctx.mem_tracker.consume(c.nbytes())
+            nbytes = c.nbytes()
+            self._consumed += nbytes
+            self.ctx.mem_tracker.consume(nbytes)
         if stream_scalar:
             whole = concat_chunks(scalar_parts)
             if whole is None or whole.num_rows == 0:
@@ -115,7 +116,8 @@ class HashAggExec(Executor):
         self._buffered = []
         self._spill_armed = False
         if self.partial_input:
-            final = self._merge_final(n_keys, chunks)
+            final = aggstate.merge_partials_to_final(
+                n_keys, self.aggs, chunks)
         else:
             if has_distinct:
                 whole = concat_chunks(chunks)
@@ -129,8 +131,9 @@ class HashAggExec(Executor):
             else:
                 # chunk-wise partials computed by a worker pool
                 # (aggregate.go:101-169 partial workers; numpy releases the
-                # GIL so the pool genuinely overlaps), then partitioned
-                # final merge
+                # GIL so the pool genuinely overlaps), then one vectorised
+                # final merge (a hash-partitioned, pooled merge was slower
+                # at every row count measured: PERF.md section 6, PR 37)
                 ir = AggregationIR(self.group_by, self.aggs, mode="partial")
                 live = [c for c in chunks if c.num_rows > 0]
                 par = self.ctx.hashagg_partial_concurrency
@@ -147,7 +150,8 @@ class HashAggExec(Executor):
                         )
                 else:
                     partials = [_run_agg(ir, c) for c in live]
-                final = self._merge_final(n_keys, partials)
+                final = aggstate.merge_partials_to_final(
+                    n_keys, self.aggs, partials)
         if final is None:
             if n_keys == 0:
                 return [aggstate.empty_final_row(self.aggs)]
@@ -202,44 +206,6 @@ class HashAggExec(Executor):
             if merged is not None:
                 out.extend(merged.split(self.ctx.chunk_size))
         self._spill_lists = None
-        return out
-
-    def _merge_final(self, n_keys: int, partials: List[Chunk]):
-        """Final merge; with many partial rows the merge itself partitions
-        by key hash across tidb_hashagg_final_concurrency workers
-        (aggregate.go final worker ring)."""
-        fin = self.ctx.hashagg_final_concurrency
-        live = [c for c in partials if c is not None and c.num_rows > 0]
-        total = sum(c.num_rows for c in live)
-        if fin <= 1 or n_keys == 0 or total < 8192:
-            return aggstate.merge_partials_to_final(n_keys, self.aggs, live)
-        parts = [[] for _ in range(fin)]
-        for c in live:
-            h = _partition_hash(c, n_keys)
-            if h is None:  # unhashable key column (host objects): serial
-                return aggstate.merge_partials_to_final(
-                    n_keys, self.aggs, live)
-            for p in range(fin):
-                sel = h % fin == p
-                if sel.any():
-                    parts[p].append(c.filter(sel))
-        from concurrent.futures import ThreadPoolExecutor
-
-        from ..metrics import REGISTRY
-
-        REGISTRY.inc("executor_parallel_workers_total", fin)
-        with ThreadPoolExecutor(max_workers=fin) as pool:
-            merged = list(pool.map(
-                lambda cs: aggstate.merge_partials_to_final(
-                    n_keys, self.aggs, cs),
-                parts,
-            ))
-        merged = [m for m in merged if m is not None]
-        if not merged:
-            return None
-        out = merged[0]
-        for m in merged[1:]:
-            out = out.append(m)
         return out
 
     def _next(self) -> Optional[Chunk]:
@@ -303,11 +269,9 @@ class StreamAggExec(Executor):
                 self._open_partial = part
                 continue
             # emit all fully-closed groups; hold back the last (still open)
-            last_key = part.row(part.num_rows - 1)[:n_keys]
-            closed_mask = np.array(
-                [part.row(i)[:n_keys] != last_key for i in range(part.num_rows)],
-                dtype=np.bool_,
-            )
+            gidx = aggstate.group_indices(
+                [part.col(i) for i in range(n_keys)])[0]
+            closed_mask = gidx != gidx[-1]
             closed = part.filter(closed_mask)
             self._open_partial = part.filter(~closed_mask)
             if closed.num_rows:
@@ -333,6 +297,9 @@ def _partition_hash(c: Chunk, n_keys: int):
                 np.float64).view(np.uint64)
         else:
             v = data.astype(np.int64, copy=False).view(np.uint64)
+        if col.valid is not None:
+            # what lies under a NULL is not part of the key
+            v = np.where(col.valid, v, np.uint64(0))
         v = v * np.uint64(0x9E3779B97F4A7C15)
         h = (h * np.uint64(31)) ^ (v >> np.uint64(7)) ^ v
         h = h ^ (~col.validity()).astype(np.uint64)

@@ -91,10 +91,6 @@ class ExecContext:
         return self._conc("tidb_hashagg_partial_concurrency", 4)
 
     @property
-    def hashagg_final_concurrency(self) -> int:
-        return self._conc("tidb_hashagg_final_concurrency", 4)
-
-    @property
     def projection_concurrency(self) -> int:
         return self._conc("tidb_projection_concurrency", 4)
 

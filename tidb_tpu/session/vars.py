@@ -40,6 +40,7 @@ SYSVAR_DEFAULTS = {
     "tidb_executor_concurrency": ("5", "int"),
     "tidb_hash_join_concurrency": ("-1", "int"),
     "tidb_hashagg_partial_concurrency": ("-1", "int"),
+    # accepted for TiDB's clients; the final merge is one vectorised pass
     "tidb_hashagg_final_concurrency": ("-1", "int"),
     "tidb_projection_concurrency": ("-1", "int"),
     "tidb_index_lookup_concurrency": ("4", "int"),
