@@ -38,9 +38,6 @@ REGIONS = 8
 Q3_ROWS = (16_777_216, 4_194_304)
 #: the MPP shuffle-join shape of bench.py: both sides too big to broadcast
 MPP_ROWS = (8_000_000, 2_000_000)
-#: what the hot tier assumes a chip holds (copr/parallel.py, layout/__init__.py)
-HOT_TIER_ASSUMED_BYTES = 8 << 30
-
 Q1 = (
     "select l_returnflag, l_linestatus, sum(l_quantity), sum(l_extendedprice),"
     " sum(l_extendedprice * (1 - l_discount)),"
@@ -474,10 +471,12 @@ def run_four_chip(smoke: Smoke, rows: int, mpp_rows: tuple, n_devices: int,
 def report_device():
     import jax
 
+    from tidb_tpu.layout import hot_cap_bytes
+
     stats = jax.devices()[0].memory_stats() or {}
     emit({"peak_bytes_in_use": stats.get("peak_bytes_in_use"),
           "bytes_limit": stats.get("bytes_limit"),
-          "hot_tier_assumed_bytes": HOT_TIER_ASSUMED_BYTES,
+          "hot_tier_bytes": hot_cap_bytes(),
           "compile_cache_dir": jax.config.jax_compilation_cache_dir})
 
 

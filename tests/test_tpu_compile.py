@@ -81,11 +81,13 @@ def q3_pair():
 
 
 def _fragment_args(sess, table, sql, mesh, n_tiles, build_pad=16,
-                   wire=None):
+                   wire=None, rows=None):
     """(core, abstract args) of the fused mesh program for `sql`'s cop DAG,
     built the way fusion.trace_fused_fragment builds it, but over `mesh`
     and with ShapeDtypeStructs carrying NamedShardings: columns in their
-    production wire dtypes, NULL-free columns without a validity array."""
+    production wire dtypes, NULL-free columns without a validity array.
+    `rows` stands in for the table's base rows where they decide the
+    program (a sum that can pass int64 over them)."""
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -115,6 +117,8 @@ def _fragment_args(sess, table, sql, mesh, n_tiles, build_pad=16,
     assert an is not None, f"no device-eligible cop DAG for {sql!r}"
     kind = "agg" if an.agg is not None else (
         "topn" if an.topn is not None else "filter")
+    if rows is not None:
+        an.agg_rows = rows
     hoisted = hoist_conds(an)
     col_order = an.needed_cols()
     datas, valids = [], []
@@ -248,6 +252,37 @@ def test_q1_compiles_for_four_chips_with_its_psum(meshes, lineitem):
                                 dict(CANONICAL_KERNEL_QUERIES)["q1-dense-agg"],
                                 meshes[4], SF10_TILES)
     assert "all-reduce" in _compile(core, args).as_text()
+
+
+SF100_TILES = 1024         # 600 M rows: 573 tiles, padded to a power of two
+
+
+def test_sf100_q1_compiles_for_four_chips_with_its_wide_sum(meshes, lineitem):
+    """The program of the benchmark's cell `sf100-q1-4chip`: 256 tiles a
+    shard, sum_charge leaving the device as three limb sums beside the
+    recombined slots, one all-reduce for all of them, no full-length
+    temporary, and a shard's share of the columns inside a chip."""
+    import jax
+
+    from tidb_tpu.copr import fusion
+
+    sess, table = lineitem
+    core, args = _fragment_args(sess, table, _benchmark_sql("q1w", 2),
+                                meshes[4], SF100_TILES, rows=600_000_000)
+    compiled = _compile(core, args)
+    text = compiled.as_text()
+    assert text.count(" all-reduce(") + text.count(" all-reduce-start(") == 1
+    assert not _full_length_copies(compiled)
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 4e9
+    # the same statement over SF10's rows keeps every slot on the device
+    core10, args10 = _fragment_args(sess, table, _benchmark_sql("q1w", 2),
+                                    meshes[1], SF10_TILES, rows=60_000_000)
+    out = jax.eval_shape(core, *args)
+    out10 = jax.eval_shape(core10, *args10)
+    assert [x[0].shape for x in out[1][:4]] == [(6,), (6,), (6,), (3, 6)]
+    assert [x[0].shape for x in out10[1][:4]] == [(6,)] * 4
+    assert fusion.AGG_LIMB * 600_000_000 < 1 << 61
 
 
 def test_mpp_shuffle_join_compiles_for_four_chips_with_its_all_to_all(meshes):

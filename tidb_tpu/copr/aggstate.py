@@ -402,10 +402,19 @@ def finalize(agg: AggDesc, states: List[Column]) -> Column:
         else:
             # decimal: state scale -> result scale with round-half-up
             st = sum_type(agg.args[0].ftype)
-            up = ft.scale - st.scale
-            num = s.data.astype(np.int64) * (10 ** max(up, 0))
-            sign = np.sign(num)
-            data = sign * ((np.abs(num) + safe // 2) // safe)
+            mul = 10 ** max(ft.scale - st.scale, 0)
+            if s.data.dtype == object and any(
+                    abs(int(x)) * mul >= 1 << 63 for x in s.data):
+                # a sum whose rescale passes int64 (TPC-H Q1's avg_price
+                # at SF100): the few such groups as Python integers
+                data = np.array(
+                    [(-1 if int(x) < 0 else 1)
+                     * ((abs(int(x)) * mul + int(n) // 2) // int(n))
+                     for x, n in zip(s.data, safe)], dtype=object)
+            else:
+                num = s.data.astype(np.int64) * mul
+                sign = np.sign(num)
+                data = sign * ((np.abs(num) + safe // 2) // safe)
         return Column(ft, data.astype(ft.np_dtype), (cnt > 0))
     if name in ("min", "max", "first_row"):
         s = states[0]

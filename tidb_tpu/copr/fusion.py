@@ -196,7 +196,8 @@ def agg_lanes(an) -> str:
     arithmetic from the wire arrays), `i64:4` where they do not (int64
     arithmetic, four 16-bit limbs).  Part of the program's fingerprint;
     'scatter' where the group space is past `ops.UNROLL_G` and the sums
-    stay `jax.ops.segment_sum`."""
+    stay `jax.ops.segment_sum`.  Where sums can pass int64 over the
+    table's rows (`wide_sums`), `;wide=<their aggregates>` follows."""
     from .ir import serialize_expr
     from .jax_eval import bounded_int, lane_limbs
 
@@ -215,7 +216,78 @@ def agg_lanes(an) -> str:
                 seen[key] = ("i64:4" if v is None else
                              f"i32:{len(lane_limbs(v, AGG_LIMB))}")
         out = ",".join(seen.values())
+        wide = wide_sums(an)
+        if wide:
+            out += ";wide=" + ",".join(str(i) for i in sorted(wide))
     an.agg_lanes = out
+    return out
+
+
+#: sums that left the device as limb sums and were put together on the
+#: host as Python integers, one a slot a dispatch
+WIDE_SUM_SLOTS = "agg_wide_sum_slots_total"
+
+
+def _state_rescale(expr, state_ft) -> int:
+    """What a sum's argument is multiplied by to stand in its state's
+    scale."""
+    from ..types import TypeKind
+
+    ft = expr.ftype
+    return 10 ** (state_ft.scale
+                  - (ft.scale if ft.kind == TypeKind.DECIMAL else 0))
+
+
+def _passes_int64(an, expr, mul: int) -> bool:
+    """Whether the sum of `expr * mul` over the table's base rows can
+    pass int64 while no row's value does.  Reckoned from the statistics'
+    own minima and maxima, not from the powers of two the lanes are cut
+    by: TPC-H Q1's sum_charge at SF10 fits by the first and not by the
+    second."""
+    from .jax_eval import int_bounds
+
+    own = int_bounds(expr, an.agg_stats)
+    top = 0 if own is None else max(abs(own[0]), abs(own[1])) * mul
+    return top < 1 << 63 <= top * an.agg_rows
+
+
+#: the limbs of a sum the statistics do not bound: an int64 in 16-bit
+#: pieces, the last one signed
+I64_SHIFTS = (0, 16, 32, 48)
+
+
+def wide_sums(an) -> dict:
+    """{aggregate: (limb shifts, constant, rescale)} for the sums of a
+    small-G dense aggregate that can pass int64: under a mesh such a sum
+    leaves the device as its limb sums (each far inside int64: a limb is
+    within +-AGG_LIMB) and `recombine_wide` puts it together on the host
+    as Python integers, which the DECIMAL(38, s) state holds as they
+    are.  Empty for every other aggregate."""
+    from .jax_eval import bounded_int, lane_limbs
+
+    if an.agg_wide is None:
+        an.agg_wide = {}
+        if an.agg_mode == "dense" and an.num_groups <= ops.UNROLL_G:
+            for i, a in enumerate(an.agg.aggs):
+                if not _int_state(a):
+                    continue
+                mul = _state_rescale(a.args[0], a.partial_types()[0])
+                if not _passes_int64(an, a.args[0], mul):
+                    continue
+                v = bounded_int(a.args[0], _agg_wire(an))
+                an.agg_wide[i] = (I64_SHIFTS, 0, mul) if v is None else (
+                    [s for _x, s in lane_limbs(v, AGG_LIMB)], v.const, mul)
+    return an.agg_wide
+
+
+def recombine_wide(limbs: np.ndarray, counts: np.ndarray, plan) -> np.ndarray:
+    """A wide sum's limb sums [limbs, G] and its counts [G] as the exact
+    sums, an object array of Python integers."""
+    shifts, const, mul = plan
+    out = np.empty(limbs.shape[1], dtype=object)
+    for g in range(limbs.shape[1]):
+        out[g] = mul * (sum(int(x) << s for x, s in zip(limbs[:, g], shifts))
+                        + const * int(counts[g]))
     return out
 
 
@@ -252,7 +324,9 @@ class _BlockSums:
     `jax.lax.reduce` over blocks of at most AGG_BLOCK rows with int32
     accumulators, so the columns are read once.  Second level: the block
     partials as one stacked array, widened to int64, summed, the limbs
-    recombined by their weights, one psum.
+    recombined by their weights, one psum.  Under a mesh a sum that can
+    pass int64 (`wide_sums`) is not recombined: its limb sums ride the
+    same psum as they are and its slot reads [limbs, G].
 
     An operand of a variadic reduce is merged with no other by anyone,
     so equal requests share a slot here: `sum(x)` and `avg(x)`, and every
@@ -265,6 +339,7 @@ class _BlockSums:
         self.counts = {}                    # validity key -> slot
         self.sums = {}                      # (argument, scale) -> slot
         self.slots = []                     # (limbs, const, mul, vkey)
+        self.wide = set()                   # slots that stay limb sums
         self.out = None
         self.count(frozenset())
 
@@ -299,13 +374,10 @@ class _BlockSums:
 
     def sum(self, expr, state_ft):
         """Slots of (sum of `expr` in the state's scale, its count)."""
-        from ..types import TypeKind
         from .ir import serialize_expr
         from .jax_eval import bounded_int, lane_limbs
 
-        ft = expr.ftype
-        mul = 10 ** (state_ft.scale
-                     - (ft.scale if ft.kind == TypeKind.DECIMAL else 0))
+        mul = _state_rescale(expr, state_ft)
         key = (str(serialize_expr(expr)), mul)
         if key not in self.sums:
             v = bounded_int(expr, self.wire)
@@ -317,10 +389,13 @@ class _BlockSums:
                 d = d.astype(jnp.int64)
                 vkey = self.valid_of(expr, valid)
                 limbs = [(((d >> s) & 0xFFFF if s < 48 else d >> s)
-                          .astype(jnp.int32), s) for s in (0, 16, 32, 48)]
+                          .astype(jnp.int32), s) for s in I64_SHIFTS]
                 const = 0
             cnt = self.count(vkey)   # may take the next slot itself
             self.sums[key] = (len(self.slots), cnt)
+            if self.ctx.axis is not None \
+                    and _passes_int64(self.ctx.an, expr, mul):
+                self.wide.add(len(self.slots))
             self.slots.append((limbs, const, mul, vkey))
         return self.sums[key]
 
@@ -352,18 +427,31 @@ class _BlockSums:
             .reshape(-1, G, parts[0].size).astype(jnp.int64).sum(axis=2)
         weights = [[0] * sums.shape[0] for _ in self.slots]
         first, at = [], 0
-        for row, (limbs, const, mul, vkey) in zip(weights, self.slots):
+        for i, (row, (limbs, const, mul, vkey)) in enumerate(
+                zip(weights, self.slots)):
             first.append(at)
-            for _x, s in limbs:
-                row[at] = mul << s
-                at += 1
+            at += len(limbs)
+            if i in self.wide:
+                continue
+            for k, (_x, s) in enumerate(limbs):
+                row[first[i] + k] = mul << s
             row[first[self.counts[vkey]]] += mul * const
         weights = np.array(
             [[(w + (1 << 63)) % (1 << 64) - (1 << 63) for w in row]
              for row in weights], dtype=np.int64)
         weights = np.broadcast_to(weights[:, :, None], weights.shape + (G,))
-        out = ctx.psum((weights * sums[None]).sum(axis=1))
-        self.out = [o.reshape(G) for o in jnp.split(out, len(self.slots))]
+        out = (weights * sums[None]).sum(axis=1)
+        n = len(self.slots)
+        if not self.wide:
+            self.out = [o.reshape(G) for o in jnp.split(ctx.psum(out), n)]
+            return
+        kept = [sums[first[i]: first[i] + len(self.slots[i][0])]
+                for i in sorted(self.wide)]
+        out = ctx.psum(jnp.concatenate([out, *kept]))
+        self.out = [o.reshape(G) for o in jnp.split(out[:n], n)]
+        for i, limbs in zip(sorted(self.wide), kept):
+            self.out[i] = out[n: n + len(limbs)]
+            n += len(limbs)
 
     def __getitem__(self, slot):
         return self.out[slot]

@@ -435,6 +435,12 @@ class _Analyzed:
         self.agg_bounds: dict = {}
         #: what the dense emitter makes of them (fusion.agg_lanes), lazily
         self.agg_lanes: Optional[str] = None
+        #: the same columns' own minima and maxima, and the table's base
+        #: rows: what decides whether a sum can pass int64
+        #: (fusion.wide_sums, lazily in `agg_wide`)
+        self.agg_stats: dict = {}
+        self.agg_rows = 0
+        self.agg_wide: Optional[dict] = None
         if self.agg is not None:
             width = len(self.scan.columns)
             for a in self.agg.aggs:
@@ -489,6 +495,7 @@ class _Analyzed:
                 self.group_cols = []
                 self.group_card = []
                 self.agg_bounds = {}
+                self.agg_stats = {}
         if self.topn is not None:
             if len(self.topn.order_by) != 1:
                 # exact compound ordering: pack every key's stats-bounded
@@ -583,6 +590,8 @@ class _Analyzed:
                 min(-(1 << (-lo - 1).bit_length()), 0) if lo < 0 else 0,
                 (1 << hi.bit_length()) - 1 if hi > 0 else 0,
                 bool(has_null))
+            self.agg_stats[i] = (lo, hi)
+        self.agg_rows = table.base_rows
 
     def needed_cols(self) -> List[int]:
         """Scan-output col indices the device actually needs (payload
@@ -1056,7 +1065,11 @@ def _merge_device_agg(accum, gcount: np.ndarray, results, table, an: _Analyzed,
         elif tag == "sumcount":
             s, c = r
             if slot[1] is None:
-                slot[1], slot[2] = s.copy(), c.copy()
+                # integer sums add up across tiles as Python integers: a
+                # tile's partial fits int64 where the table's sum may not
+                slot[1] = s.astype(object) if s.dtype.kind == "i" \
+                    else s.copy()
+                slot[2] = c.copy()
             else:
                 slot[1] += s
                 slot[2] += c

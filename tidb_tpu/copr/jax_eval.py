@@ -992,6 +992,46 @@ def bounded_int(e: Expression, wire: dict):
     return _norm(a.terms + b.terms, a.const + b.const, a.vcols | b.vcols)
 
 
+def int_bounds(e: Expression, stats: dict):
+    """(lo, hi) of `e` by interval arithmetic over `stats`, scan column
+    -> (lo, hi), in `e`'s own scale; None outside `bounded_int`'s grammar
+    (columns, integer literals, `+`, `-`, `*`, unary minus, upward
+    rescales).  No width is asked of the columns: this is the bound a
+    sum's state is sized by, not a lane."""
+    if e.ftype.kind not in _BOUNDED_KINDS:
+        return None
+    if isinstance(e, ColumnExpr):
+        return stats.get(e.index)
+    if isinstance(e, Constant):
+        if (getattr(e, "param_slot", None) is not None
+                or isinstance(e.value, bool)
+                or not isinstance(e.value, int)):
+            return None
+        return int(e.value), int(e.value)
+    if not isinstance(e, ScalarFunc) or e.name not in (
+            "unaryminus", "+", "-", "*"):
+        return None
+    args = [int_bounds(x, stats) for x in e.args]
+    if any(a is None for a in args):
+        return None
+    if e.name == "unaryminus":
+        return -args[0][1], -args[0][0]
+    out_scale = e.ftype.scale if e.ftype.kind == TypeKind.DECIMAL else 0
+    scales = [x.ftype.scale if x.ftype.kind == TypeKind.DECIMAL else 0
+              for x in e.args]
+    (alo, ahi), (blo, bhi) = args
+    if e.name == "*":
+        up = out_scale - sum(scales)
+        c = (alo * blo, alo * bhi, ahi * blo, ahi * bhi)
+        return None if up < 0 else (min(c) * 10 ** up, max(c) * 10 ** up)
+    if min(out_scale - sc for sc in scales) < 0:
+        return None
+    ma, mb = (10 ** (out_scale - sc) for sc in scales)
+    if e.name == "-":
+        return alo * ma - bhi * mb, ahi * ma - blo * mb
+    return alo * ma + blo * mb, ahi * ma + bhi * mb
+
+
 def lane_limbs(v: Lanes, limit: int):
     """The terms of `v` as limbs within +-limit (limit >= 0xFFFF), each
     (x, shift): what a block sum of 2^31 // (limit + 1) rows holds in an

@@ -369,7 +369,8 @@ class _MeshCache:
     device / substitute a constant mask, so both the link transfer and the
     steady-state HBM traffic shrink to the narrow width.
 
-    This is the HOT tier: capacity comes from TIDB_TPU_HBM_BYTES, and
+    This is the HOT tier: capacity is `layout.hot_cap_bytes()` (a share
+    of the devices' own memory, or TIDB_TPU_HBM_BYTES), and
     eviction is VALUE-WEIGHTED (layout autotuner): the lowest-priority
     column is the victim, and packable victims DEMOTE to the compressed
     cold tier (tidb_tpu/layout/coldtier) instead of dropping — a table
@@ -377,14 +378,14 @@ class _MeshCache:
     host reloads."""
 
     def __init__(self, capacity_bytes: Optional[int] = None):
-        import os as _os2
-
         from .cache import ByteCapCache
 
-        if capacity_bytes is None:
-            capacity_bytes = int(_os2.environ.get(
-                "TIDB_TPU_HBM_BYTES", str(8 << 30)))
-        self._c = ByteCapCache(capacity_bytes, name="mesh")
+        # without a capacity of its own the cache follows
+        # `hot_cap_bytes()`, read at each load: the devices cannot be
+        # asked while this module is imported
+        self._follow = capacity_bytes is None
+        self._c = ByteCapCache(8 << 30 if self._follow else capacity_bytes,
+                               name="mesh")
         self._c.set_policy(priority_fn=_hot_priority,
                            demote_fn=_hot_demote)
 
@@ -393,6 +394,10 @@ class _MeshCache:
         return self._c.items_view
 
     def get_column(self, mesh: Mesh, table, store_ci: int):
+        if self._follow:
+            from ..layout import hot_cap_bytes
+
+            self._c.capacity = hot_cap_bytes()
         S = len(mesh.devices.ravel())
         # device ids in the key so a rebuilt same-size mesh never serves
         # arrays placed on a dead device set (matches _ONES_CACHE);
@@ -428,8 +433,9 @@ class _MeshCache:
                     if vflat is not None:
                         vflat[off:off + n] = True if v is None else v
                     off += n
-                sp.set(bytes=flat.nbytes
-                       + (vflat.nbytes if vflat is not None else 0))
+                nbytes = flat.nbytes + (
+                    vflat.nbytes if vflat is not None else 0)
+                sp.set(bytes=nbytes, shard_bytes=nbytes // S)
                 sh = NamedSharding(mesh, P("dp"))
                 data = jax.device_put(flat.reshape(n_pad, tile), sh)
                 valid = None
@@ -957,13 +963,16 @@ def _readback_sharding(mesh: Mesh):
     return NamedSharding(mesh, P()) if jax.process_count() > 1 else None
 
 
-def _launch(jitted, program: Optional[str], args):
+def _launch(jitted, program: Optional[str], args, devices: int = 0):
     """Enqueue one compiled program.  `copr.device.execute` ends when
     the call returns, which is at the enqueue; `program` is the name the
-    device trace knows the program by (its XLA module is `jit_<name>`)."""
+    device trace knows the program by (its XLA module is `jit_<name>`),
+    `devices` the shards of the mesh it runs on (a mesh program's)."""
     from ..trace import span
 
     attrs = {"program": program} if program else {}
+    if devices:
+        attrs["devices"] = devices
     with span("copr.device.execute", hbm_bytes=_hbm_bytes(), **attrs):
         return jitted(*args)
 
@@ -1090,7 +1099,8 @@ def _packed_jit(fn, mesh: Mesh, name: Optional[str] = None, merge=None,
     def call(*args):
         from ..trace import span
 
-        buf = _read_back(_launch(jitted, name, args), join)
+        buf = _read_back(_launch(jitted, name, args, mesh.devices.size),
+                         join)
         with span("copr.unpack", rows=int(buf.size), bytes=buf.nbytes):
             leaves, off = [], 0
             for shape, dt in meta["specs"]:
@@ -1250,14 +1260,27 @@ def _build_mesh_fn(an: _Analyzed, kind: str, col_order: List[int],
         return _wrap_sort_agg(an, core, mesh, S, n_local, name)
 
     if kind == "agg":
+        from . import fusion
+        from ..trace import annotate
+
         agg_ir = an.agg
         G = an.num_groups
         tags = je._agg_tags(agg_ir)
+        wide = fusion.wide_sums(an)
 
         def merge_shards(out):
             gcount, results = out
+            # inside `copr.unpack`: how many sums came as limb sums and
+            # are put together here as Python integers
+            annotate(wide_sums=len(wide))
+            if wide:
+                REGISTRY.inc(fusion.WIDE_SUM_SLOTS, float(len(wide)))
             merged = []
             for tag, r in zip(tags, results):
+                if len(merged) in wide:
+                    limbs, cnt = r
+                    r = (fusion.recombine_wide(
+                        limbs, cnt, wide[len(merged)]), cnt)
                 if tag == "minmax":
                     part, cnt = r  # part: [S*G] per-shard partials
                     part = part.reshape(S, G)
@@ -1302,7 +1325,7 @@ def _build_mesh_fn(an: _Analyzed, kind: str, col_order: List[int],
         from ..trace import span
 
         n_rows = S * n_local
-        bits = _read_back(_launch(jitted, name, _call_args(*operands)))
+        bits = _read_back(_launch(jitted, name, _call_args(*operands), S))
         with span("copr.unpack", rows=n_rows, bytes=bits.nbytes):
             return np.unpackbits(bits, count=n_rows).astype(np.bool_)
     return wrapped
@@ -1431,7 +1454,7 @@ def _wrap_lookup_agg(an: _Analyzed, core, mesh: Mesh, S: int, name: str):
 
     def wrapped(*operands):
         rows, states = _read_back_tree(
-            _launch(jitted, name, _call_args(*operands)))
+            _launch(jitted, name, _call_args(*operands), S))
         return {"mode": "lookup", "S": S, "rows": rows, "states": states}
 
     return wrapped
@@ -2240,7 +2263,7 @@ def _dispatch_once(kind, fn, datas, valids, del_mask, bounds, lvals, pargs,
     _check_membership_epoch()
     FAILPOINTS.hit("copr/chunk_dispatch", kind=kind, chunk=0, total=1,
                    start=start, end=end)
-    with span("copr.chunk", kind=kind, chunk=0,
+    with span("copr.chunk", kind=kind, chunk=0, devices=len(mesh_ids),
               rows=sum(hi - lo for lo, hi in bounds)):
         # resource-group admission per dispatch: a depleted group
         # waits here, and the dispatch's device time is its charge

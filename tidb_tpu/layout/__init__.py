@@ -60,11 +60,35 @@ def layout_epoch() -> int:
     return LAYOUT.epoch
 
 
+#: the share of the devices' memory the hot tier may fill.  Half: beside
+#: the resident columns a chip holds the all-true row masks (as long as
+#: the columns, a byte a row), the cold tier, join payloads, and the
+#: programs' own temporaries, which for a flat-view program are several
+#: full-length arrays; on one v5e chip it comes to 8.45 GB, where the
+#: constant it replaces said 8 GiB for any number of chips
+HOT_SHARE = 0.5
+_DEVICE_CAP = None
+
+
 def hot_cap_bytes() -> int:
     """Hot-tier (mesh column cache) byte cap — the pressure signal the
-    autotuner's residency decisions key off.  One authority for the
-    default shared with `parallel.MESH_CACHE`."""
-    return int(os.environ.get("TIDB_TPU_HBM_BYTES", str(8 << 30)))
+    autotuner's residency decisions key off, and `parallel.MESH_CACHE`'s
+    capacity.  `TIDB_TPU_HBM_BYTES` where set; else HOT_SHARE of what the
+    process's devices say they hold (`bytes_limit`, summed: a table is
+    sharded over all of them), 8 GiB where they say nothing (the CPU
+    backend)."""
+    global _DEVICE_CAP
+    env = os.environ.get("TIDB_TPU_HBM_BYTES")
+    if env:
+        return int(env)
+    if _DEVICE_CAP is None:
+        import jax
+
+        limits = [(d.memory_stats() or {}).get("bytes_limit")
+                  for d in jax.local_devices()]
+        _DEVICE_CAP = (int(HOT_SHARE * sum(limits))
+                       if limits and all(limits) else 8 << 30)
+    return _DEVICE_CAP
 
 
 def set_hot_cap_bytes(n: int):
