@@ -165,6 +165,23 @@ def test_wide_sums_per_stmt_reads_the_unpack_spans(bench_path):
     assert _read("wide_sums_per_stmt", _run(spans=[parent, []])) is None
 
 
+def test_pad_tile_pct_is_the_fanouts_tiles_against_the_padded(bench_path):
+    sf100 = [_span("distsql.fanout", tiles=573, tiles_padded=576)]
+    parent = [_span("distsql.fanout", tiles=573, tiles_padded=1024)]
+    assert _read("pad_tile_pct", _run(spans=[sf100, sf100])) \
+        == pytest.approx(100 * 3 / 576)
+    assert _read("pad_tile_pct", _run(spans=[parent, parent])) \
+        == pytest.approx(100 * 451 / 1024)
+    # a statement of several dispatches: both summed, then the share
+    two = [_span("distsql.fanout", tiles=6, tiles_padded=8),
+           _span("distsql.fanout", tiles=2, tiles_padded=2)]
+    assert _read("pad_tile_pct", _run(spans=[two, two])) \
+        == pytest.approx(20.0)
+    # the parent's span has no such attributes: nothing, and no error
+    bare = [_span("distsql.fanout", device_ids=[0, 1, 2, 3])]
+    assert _read("pad_tile_pct", _run(spans=[bare, []])) is None
+
+
 # ---- the new cell, rehearsed ------------------------------------------------
 
 def test_sf100_q1_4chip_rehearsed_is_correct_and_its_control_is_caught(
@@ -193,6 +210,8 @@ def test_sf100_q1_4chip_rehearsed_is_correct_and_its_control_is_caught(
     assert control["caught"] is True
     got = result["metrics"]
     assert got["mesh_devices"]["value"] == 4
+    # 118 tiles of 1,024 rows over four shards: 30 a shard, laid out as 32
+    assert got["pad_tile_pct"]["value"] == pytest.approx(100 * 10 / 128)
     assert got["wide_sums_per_stmt"]["value"] == 0   # SF 0.02: none can pass
     assert got["device_rung_pct"]["value"] == 100.0
     assert got["passes_per_stmt"]["value"] == 1

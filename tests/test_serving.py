@@ -89,6 +89,76 @@ def test_shape_bucket_units():
     assert topn_budget(5) == 5  # disabled: exact
 
 
+# (tiles of rows, shards) -> tiles a shard: the benchmark's tables, of which
+# only SF100's lineitem over four chips leaves its power of two (256)
+@pytest.mark.parametrize("tiles, shards, per_shard", [
+    (573, 4, 144),  # lineitem SF100
+    (58, 1, 64),    # lineitem SF10
+    (6, 1, 8),      # lineitem SF1
+    (2, 1, 2),      # orders SF1
+    (1, 1, 1),      # customer SF1
+])
+def test_tile_bucket_of_the_benchmarks_tables(tiles, shards, per_shard):
+    from tidb_tpu.copr import jax_engine as je
+    from tidb_tpu.copr.parallel import _layout
+
+    rows = tiles * je.TILE - je.TILE // 3
+    assert _layout(rows, shards) == (tiles, per_shard * shards, per_shard)
+
+
+@pytest.mark.parametrize("shards", [1, 3, 4, 8])
+def test_tile_bucket_steps_in_eighths_and_whole_groups_of_8(shards):
+    from tidb_tpu.copr import jax_engine as je
+    from tidb_tpu.copr.parallel import _layout
+    from tidb_tpu.serving import tile_bucket
+
+    last = 0
+    for n in range(1, 4097):
+        tiles, n_pad, tl = _layout(n * je.TILE, shards)
+        need = -(-n // shards)
+        assert tiles == n and n_pad == tl * shards and tl == tile_bucket(need)
+        assert need <= tl <= need + max(need // 8, 7), (n, tl)
+        assert tl % 8 == 0 or tl in (1, 2, 4), (n, tl)
+        assert tl >= last, (n, tl, last)  # a table that grows never shrinks
+        last = tl
+    # eight shapes an octave
+    assert len({tile_bucket(n) for n in range(129, 257)}) == 8
+
+
+@pytest.mark.parametrize("shards", [1, 2, 4, 8])
+def test_tile_bucket_keeps_the_small_shapes_of_the_power_of_two_rule(shards):
+    """Up to 8 tiles a shard over a power-of-two mesh, n_pad is what
+    padding the whole table to a power of two gave: the suite's meshes of
+    one and eight devices over small tables keep every program shape."""
+    from tidb_tpu.copr import jax_engine as je
+    from tidb_tpu.copr.parallel import _layout
+    from tidb_tpu.serving import shape_bucket
+
+    for n in range(1, 8 * shards + 1):
+        _, n_pad, tl = _layout(n * je.TILE, shards)
+        assert tl <= 8
+        assert n_pad == -(-shape_bucket(n) // shards) * shards, n
+
+
+@pytest.mark.parametrize("how", ["tuner-exact", "buckets-off"])
+def test_unbucketed_shard_is_still_whole_groups_of_8(how, monkeypatch):
+    """The tuner's `exact` decision and `tidb_tpu_shape_buckets = 0` pad
+    nothing but the last group of 8: the blocked row view stays."""
+    from tidb_tpu.copr import jax_engine as je
+    from tidb_tpu.copr import parallel as par
+
+    if how == "tuner-exact":
+        monkeypatch.setattr(par, "_tile_bucket", lambda table: "exact")
+    else:
+        serving.configure(shape_buckets=False)
+    for shards in (1, 3, 4):
+        for n in range(1, 600):
+            tiles, n_pad, tl = par._layout(n * je.TILE, shards)
+            need = -(-n // shards)
+            assert (tiles, n_pad) == (n, tl * shards)
+            assert tl == (need if need <= 8 else -(-need // 8) * 8), (n, tl)
+
+
 def test_param_hoist_shares_one_mesh_program(sess):
     from tidb_tpu.copr import parallel as pl
 

@@ -254,27 +254,30 @@ def test_q1_compiles_for_four_chips_with_its_psum(meshes, lineitem):
     assert "all-reduce" in _compile(core, args).as_text()
 
 
-SF100_TILES = 1024         # 600 M rows: 573 tiles, padded to a power of two
-
-
 def test_sf100_q1_compiles_for_four_chips_with_its_wide_sum(meshes, lineitem):
-    """The program of the benchmark's cell `sf100-q1-4chip`: 256 tiles a
-    shard, sum_charge leaving the device as three limb sums beside the
-    recombined slots, one all-reduce for all of them, no full-length
-    temporary, and a shard's share of the columns inside a chip."""
+    """The program of the benchmark's cell `sf100-q1-4chip`: 144 tiles a
+    shard (600 M rows are 573 tiles, a shard's 144 a class of its own in
+    steps of an eighth), sum_charge leaving the device as three limb sums
+    beside the recombined slots, one all-reduce for all of them, no
+    full-length temporary, and a shard's share of the columns inside a
+    chip."""
     import jax
 
     from tidb_tpu.copr import fusion
+    from tidb_tpu.copr import parallel as par
 
     sess, table = lineitem
+    tiles, sf100_tiles, per_shard = par._layout(600_000_000, 4)
+    assert (tiles, sf100_tiles, per_shard) == (573, 576, 144)
     core, args = _fragment_args(sess, table, _benchmark_sql("q1w", 2),
-                                meshes[4], SF100_TILES, rows=600_000_000)
+                                meshes[4], sf100_tiles, rows=600_000_000)
     compiled = _compile(core, args)
     text = compiled.as_text()
     assert text.count(" all-reduce(") + text.count(" all-reduce-start(") == 1
     assert not _full_length_copies(compiled)
     mem = compiled.memory_analysis()
-    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 4e9
+    # 144 tiles of 2^20 rows, 12 bytes of columns and one of mask a row
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 2.2e9
     # the same statement over SF10's rows keeps every slot on the device
     core10, args10 = _fragment_args(sess, table, _benchmark_sql("q1w", 2),
                                     meshes[1], SF10_TILES, rows=60_000_000)

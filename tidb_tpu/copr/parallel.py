@@ -40,6 +40,7 @@ from ..util_concurrency import make_lock
 
 from jax import shard_map
 
+from .. import serving as _serving
 from ..chunk import Chunk, Column
 from ..coord import CoordEpochMismatch
 from ..metrics import REGISTRY
@@ -229,32 +230,41 @@ def _check_membership_epoch():
 
 def _layout(base_rows: int, n_shards: int, table=None
             ) -> Tuple[int, int, int]:
-    """(n_tiles, n_tiles_padded, tiles_per_shard) for a table.
+    """(tiles that hold a row, n_tiles_padded, tiles_per_shard) for a
+    table.
 
-    With shape buckets on (tidb_tpu_shape_buckets, the default) the tile
-    count pads UP to the next power of two before sharding: tables whose
-    row counts fall in the same bucket class — and the SAME table as it
-    grows within a class — share one compiled shard_map program shape.
-    Padded tiles are zeros and always masked (the row mask clips to
-    [start, end) which never exceeds base_rows), so results are
-    identical; the cost is bounded extra masked compute.
+    With shape buckets on (tidb_tpu_shape_buckets, the default) the tiles
+    of ONE SHARD pad up to a class that steps in eighths of its power of
+    two (serving.tile_bucket): tables whose row counts fall in the same
+    class — and the SAME table as it grows within a class — share one
+    compiled shard_map program shape, and every shard holds its
+    `tiles_per_shard` of the row order, so the rows spread over all
+    shards.  Padded tiles are zeros and always masked (the row mask clips
+    to [start, end) which never exceeds base_rows), so results are
+    identical; the cost is masked compute, at most 12.5% of the scan (7
+    tiles a shard under 64), and a table that grows through a step
+    compiles its program classes once more.
 
     The layout autotuner can flip a table's tiling to EXACT (`table`
     given + the tuner's tile-bucket decision): under HBM pressure the
-    pow2 padding is pure wasted capacity, so capacity-squeezed tables
-    trade program reuse for resident bytes."""
-    from ..serving import shape_bucket, shape_buckets_enabled
-
-    tile = je.TILE
-    n_tiles = max((base_rows + tile - 1) // tile, 1)
-    if shape_buckets_enabled() and _tile_bucket(table) == "pow2":
-        n_tiles = shape_bucket(n_tiles)
-    n_pad = ((n_tiles + n_shards - 1) // n_shards) * n_shards
-    return n_tiles, n_pad, n_pad // n_shards
+    padding is pure wasted capacity, so capacity-squeezed tables trade
+    program reuse for resident bytes.  Exact or with buckets off, a
+    shard of more than 8 tiles is still whole groups of 8 (at most 7
+    tiles more): the dense aggregate's blocked row view (_RowView) needs
+    them, and without it the program writes its columns out in full."""
+    n_tiles = max(-(-base_rows // je.TILE), 1)
+    Tl = -(-n_tiles // n_shards)
+    if _serving.shape_buckets_enabled() and _tile_bucket(table) == "pow2":
+        Tl = _serving.tile_bucket(Tl)
+    elif Tl > 8:
+        Tl = -(-Tl // 8) * 8
+    return n_tiles, Tl * n_shards, Tl
 
 
 def _tile_bucket(table) -> str:
-    """The autotuner's table-level tiling decision ('pow2' default)."""
+    """The autotuner's table-level tiling decision: 'pow2' (the default;
+    the name is from when the class was a power of two) buckets the
+    shard's tiles, 'exact' does not."""
     if table is None:
         return "pow2"
     from ..layout import LAYOUT, layout_enabled
@@ -688,8 +698,9 @@ class _RowView:
     The rows are viewed as [Tl/8, blocks, 8, rows of a block], which is
     the order the tiles already lie in, so the view is free and the dense
     emitter's block sums (fusion.AGG_BLOCK) reduce the last axis as it
-    stands.  Shards that are not whole groups of 8 tiles (tables under 8
-    tiles a shard) stay flat."""
+    stands.  Shards that are not whole groups of 8 tiles stay flat: only
+    tables under 8 tiles a shard, since _layout keeps every larger shard
+    in whole groups."""
 
     def __init__(self, n_local: int, an: Optional[_Analyzed] = None,
                  kind: str = "", col_layout=None):
@@ -2113,7 +2124,7 @@ def _run_mesh_once(storage, req: CopRequest, tid: int,
         import os as _os
 
         fp += "|aggout=" + _os.environ.get("TIDB_TPU_AGG_OUT", "")
-    annotate(device_ids=list(mesh_ids))
+    annotate(device_ids=list(mesh_ids), tiles=n_tiles, tiles_padded=n_pad)
     from .fusion import compile_attrs, note_agg_dispatch
 
     cattrs = compile_attrs(an, kind)

@@ -12,8 +12,8 @@ match the kernels.  This subsystem applies both to the TPU coprocessor:
   plane, agg-key vs probe-key usage from the fragment analysis — and
   CHOOSES a per-column device layout: dictionary vs direct encoding,
   packed code width, device-cache residency priority, and the table's
-  tile-size bucket (pow2-padded shape classes vs exact tiling when HBM
-  is scarce).
+  tile-size bucket (padded shape classes, an eighth of a power of two
+  apart, vs exact tiling when HBM is scarce).
 
 - **Cold tier** (`coldtier.py`): tables larger than the hot-tier byte
   cap stay queryable — cold columns live ON DEVICE as compressed blocks

@@ -17,9 +17,9 @@ Decisions (`ColumnPlan`) per column: **encoding** (dictionary codes vs
 direct values on device), **packed code width** (1/2/4/8 bits; 0 = not
 packable), **residency tier** (hot wire arrays vs compressed cold
 blocks), **priority** (value-weighted eviction order), and per table a
-**tile-size bucket** (pow2-padded shape classes — program reuse as the
-table grows — vs exact tiling, which stops paying pow2 HBM padding
-exactly when capacity is the scarce resource).
+**tile-size bucket** (padded shape classes, an eighth of a power of two
+apart — program reuse as the table grows — vs exact tiling, which stops
+paying HBM for padding exactly when capacity is the scarce resource).
 
 Layout CLASS changes (encoding/width/tier/tiling) may refingerprint
 compiled programs, so they are RATE-LIMITED (`TIDB_TPU_LAYOUT_RETUNE_S`
@@ -274,9 +274,11 @@ class LayoutEngine:
 
     def tile_bucket(self, table) -> str:
         """Table-level tiling decision consulted by `parallel._layout`:
-        pow2-padded shape buckets by default (program reuse as tables
-        grow); EXACT tiling under capacity pressure — pow2 padding
-        wastes HBM exactly when HBM is what ran out."""
+        'pow2', the bucketed shard of `serving.tile_bucket` (steps of an
+        eighth of a power of two: program reuse as tables grow), by
+        default; EXACT tiling (whole groups of 8 tiles a shard) under
+        capacity pressure — padding wastes HBM exactly when HBM is what
+        ran out."""
         plan = self.plan_for(table, 0) if table.n_cols else None
         return plan.tile_bucket if plan is not None else "pow2"
 
@@ -398,11 +400,11 @@ class LayoutEngine:
 
 
 def _pad_ratio(table) -> float:
-    """Device arrays are [n_pad, TILE]-shaped (shard-padded, possibly
-    pow2-bucketed), so the RESIDENT footprint exceeds raw wire bytes —
-    the pressure signal must budget what actually occupies HBM.  Uses
-    the default pow2 layout (not the table's own tile-bucket decision)
-    to stay recursion-free."""
+    """Device arrays are [n_pad, TILE]-shaped (shard-padded, bucketed
+    in eighths of a power of two by default), so the RESIDENT footprint
+    exceeds raw wire bytes — the pressure signal must budget what
+    actually occupies HBM.  Uses the default bucketed layout (not the
+    table's own tile-bucket decision) to stay recursion-free."""
     try:
         import jax
 

@@ -12,8 +12,9 @@ runtimes, Flare amortizes compilation across whole stages — here across
 
 - **Shape buckets** (`buckets.py` + hooks in the copr engines): compiled
   programs are keyed on the query's SHAPE CLASS, not its literal shape
-  or literal constants.  Row counts pad to next-power-of-two tile
-  classes (masked rows), TopN budgets and probe key-sets pad to pow2,
+  or literal constants.  Row counts pad to tile classes an eighth of a
+  power of two apart (masked rows), TopN budgets and probe key-sets pad
+  to pow2,
   and predicate constants are HOISTED out of the program into runtime
   parameter vectors (`params.py`), so `l_shipdate <= '1998-09-02'` and
   `l_shipdate <= '1998-07-01'` run the SAME cached XLA program.
@@ -38,7 +39,7 @@ from __future__ import annotations
 import threading
 from typing import Dict
 
-from .buckets import shape_bucket, topn_budget  # noqa: F401
+from .buckets import shape_bucket, tile_bucket, topn_budget  # noqa: F401
 from .params import hoist_conds  # noqa: F401
 from ..util_concurrency import make_lock
 
