@@ -147,6 +147,10 @@ def test_peer_stall_fails_over_within_deadline(fleet3, monkeypatch):
     sessions, planes, dps, tid = fleet3
     sA = sessions[0]
     want = _oracle(sA, Q6)
+    # compile Q6's programs outside the timed stretch: under xdist this
+    # test may be the first of its worker to send it, and a cold compile
+    # is no part of the deadline arithmetic below
+    assert sA.execute(Q6)[0].rows == want
     monkeypatch.setenv("TIDB_TPU_DATAPLANE_FRAG_TIMEOUT_S", "0.3")
     release = threading.Event()
 

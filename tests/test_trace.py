@@ -833,3 +833,280 @@ def test_recorder_off_served_statement_leaves_no_stamp(env):
             await srv.stop()
 
     assert len(run(body())["rows"]) == 3
+
+
+# ---------------------------------------------------------------------------
+# the host's floor of a statement (ISSUE 41): spans inside `distsql.fanout`
+# and the executor's frame, across the two threads, in the collector's
+# pauses; compile seconds under `compile_ms`
+# ---------------------------------------------------------------------------
+
+FANOUT_CHILDREN = ["distsql.spawn", "distsql.route", "mesh.analyze",
+                   "mesh.columns", "mesh.program", "mesh.delta",
+                   "copr.chunk", "mesh.result"]
+ROOT_CHILDREN = ["parse", "plan", "executor.build", "executor.open",
+                 "executor.next", "executor.close"]
+
+
+def _kids(sp):
+    """A span's children without the collector's pauses, which may fall
+    anywhere."""
+    return [c for c in sp.children if c.name != "py.gc"]
+
+
+@pytest.fixture
+def span_threads(monkeypatch):
+    """{id(span): name of the thread that made it}, for every span made
+    through the recorder while the fixture lives."""
+    import threading
+
+    from tidb_tpu.trace import recorder
+
+    made = {}
+    for meth in ("child", "add_span"):
+        real = getattr(recorder.QueryTrace, meth)
+
+        def noting(self, *a, _real=real, **kw):
+            sp = _real(self, *a, **kw)
+            made[id(sp)] = threading.current_thread().name
+            return sp
+
+        monkeypatch.setattr(recorder.QueryTrace, meth, noting)
+    return made
+
+
+@pytest.mark.parametrize("kind", ["agg", "topn"])
+def test_mesh_statement_floor_is_tiled_by_named_spans(env, kind,
+                                                      span_threads):
+    import threading
+
+    d, s = env
+    s.query(MESH[kind])
+    s.query(MESH[kind])  # every column resident, the program cached
+    tr = s.last_trace
+    me = threading.current_thread().name
+    root = _kids(tr.root)
+    assert [c.name for c in root] == ROOT_CHILDREN
+    _assert_in_order(root)
+    assert all(span_threads[id(c)] == me for c in root)
+    by = {c.name: c for c in root}
+    # nothing between `plan` and `executor.open` but the build
+    assert _end(by["plan"]) <= by["executor.build"].start_ns
+    assert _end(by["executor.build"]) <= by["executor.open"].start_ns
+    # the producer's tree hangs under `executor.open`, on its own thread
+    fan = _kids(by["executor.open"])
+    assert [c.name for c in fan] == ["distsql.fanout"]
+    fan = fan[0]
+    kids = _kids(fan)
+    assert [c.name for c in kids] == FANOUT_CHILDREN
+    assert all(span_threads[id(c)] == "tidb-tpu-select" for c in kids)
+    spawn, rest = kids[0], kids[1:]
+    assert _end(spawn) == fan.start_ns  # ends where the fan-out starts
+    assert by["executor.open"].start_ns <= spawn.start_ns
+    _assert_in_order(rest)
+    assert fan.start_ns <= rest[0].start_ns and _end(rest[-1]) <= _end(fan)
+    at = {c.name: c for c in kids}
+    assert at["distsql.route"].attrs["declined"] == "dataplane,microbatch"
+    assert at["mesh.analyze"].attrs["kind"] == kind
+    cols = at["mesh.columns"].attrs
+    assert cols["cols"] == cols["resident"] >= 1
+    assert not _spans_by_name(tr, "copr.transfer")
+    hit = _kids(at["mesh.program"])
+    assert [c.name for c in hit] == ["copr.compile"]
+    assert hit[0].attrs["cache"] == "hit"
+    assert at["mesh.delta"].attrs == {"deleted": 0, "inserted": 0}
+    assert at["mesh.result"].attrs["chunks"] == 1
+    assert "chunk" not in at["copr.chunk"].attrs
+    # the hand-off back: on the statement's thread, under its drain
+    wakes = _spans_by_name(tr, "distsql.wake")
+    assert wakes and all(w in by["executor.next"].children for w in wakes)
+    assert all(span_threads[id(w)] == me for w in wakes)
+    assert all(w.attrs["items"] >= 1 for w in wakes)
+    assert all(by["executor.next"].start_ns <= w.start_ns
+               and _end(w) <= _end(by["executor.next"]) for w in wakes)
+
+
+def test_first_fetch_of_a_column_is_not_resident_and_is_counted():
+    from tidb_tpu.copr import parallel as pl
+
+    d, s = _mk_session()
+    before = REGISTRY.get(pl.COLUMN_LOAD_SECONDS)
+    s.query("select sum(l_orderkey) from li where l_qty < 11")
+    cols = _spans_by_name(s.last_trace, "mesh.columns")
+    assert len(cols) == 1 and cols[0].attrs == {"cols": 2, "resident": 0}
+    # the transfers nest in it, whichever thread of the pool ran them
+    assert len([c for c in _kids(cols[0])
+                if c.name == "copr.transfer"]) == 2
+    moved = REGISTRY.get(pl.COLUMN_LOAD_SECONDS) - before
+    assert 0 < moved <= cols[0].dur_ns / 1e9 + 1e-3
+    s.query("select sum(l_orderkey) from li where l_qty < 12")
+    cols = _spans_by_name(s.last_trace, "mesh.columns")
+    assert cols[0].attrs == {"cols": 2, "resident": 2}
+    assert REGISTRY.get(pl.COLUMN_LOAD_SECONDS) - before == moved
+
+
+def _gc_counters():
+    snap = REGISTRY.snapshot()
+    return (snap["py_gc_pause_seconds_total"],
+            snap["py_gc_collections_total"])
+
+
+def test_gc_pause_is_a_span_under_the_current_one_and_two_counters(
+        env, monkeypatch):
+    import gc
+    import threading
+
+    from tidb_tpu.copr import parallel as pl
+
+    real = pl._call_args
+
+    def collecting(*a):
+        gc.collect()  # inside `copr.chunk`, before `copr.args` opens
+        return real(*a)
+
+    monkeypatch.setattr(pl, "_call_args", collecting)
+    d, s = env
+    secs, n = _gc_counters()
+    s.query(MESH["agg"])
+    tr = s.last_trace
+    full = [g for g in _spans_by_name(tr, "py.gc") if g.attrs["gen"] == 2]
+    assert full and full[0].attrs["collected"] >= 0
+    chunk = _spans_by_name(tr, "copr.chunk")[0]
+    assert full[0] in chunk.children
+    assert chunk.start_ns <= full[0].start_ns and _end(full[0]) <= _end(chunk)
+    secs1, n1 = _gc_counters()
+    assert n1 >= n + 1
+    assert secs1 >= secs + full[0].dur_ns / 1e9 - 1e-9 > secs
+    # on a thread with no trace: the counters only
+    ring = list(trace_mod.TRACE_RING)
+    spans_before = len(_span_names(tr))
+    t = threading.Thread(target=gc.collect)
+    t.start()
+    t.join()
+    secs2, n2 = _gc_counters()
+    assert n2 >= n1 + 1 and secs2 > secs1
+    assert list(trace_mod.TRACE_RING) == ring
+    assert len(_span_names(tr)) == spans_before
+
+
+def test_gc_callback_is_installed_once(env):
+    import gc
+
+    from tidb_tpu.trace import recorder
+
+    trace_mod.install_gc_spans()
+    Domain().maintenance.stop()
+    assert gc.callbacks.count(recorder._on_gc) == 1
+
+
+def test_compile_seconds_are_compile_ms_and_not_device_ms(env):
+    d, s = env
+    # a shape no other test of the module sends: a fresh program
+    sql = "select max(l_qty), min(l_orderkey), count(*) from li" \
+          " where l_qty between 3 and 23"
+    c0 = REGISTRY.snapshot()
+    s.query(sql)
+    tr = s.last_trace
+    miss = [c for c in _spans_by_name(tr, "copr.compile")
+            if c.attrs["cache"] == "miss"]
+    assert len(miss) == 1
+    exe = _spans_by_name(tr, "copr.device.execute")
+    assert len(exe) == 1 and exe[0] in miss[0].children
+    ns = exe[0].attrs["compile_ns"]
+    assert 0 < ns <= exe[0].dur_ns
+    tot = tr.phase_totals()
+    assert tot["compile_ms"] >= ns / 1e6
+    assert tot["device_ms"] == pytest.approx((exe[0].dur_ns - ns) / 1e6)
+    assert tot["compile_misses"] == 1
+    c1 = REGISTRY.snapshot()
+    assert c1["xla_compiles_total"] >= c0["xla_compiles_total"] + 1
+    assert c1["xla_compile_seconds_total"] \
+        >= c0["xla_compile_seconds_total"] + ns / 1e9 - 1e-6
+    # the second dispatch of the program compiles nothing
+    s.query(sql)
+    tr = s.last_trace
+    exe = _spans_by_name(tr, "copr.device.execute")
+    assert len(exe) == 1 and "compile_ns" not in exe[0].attrs
+    tot = tr.phase_totals()
+    assert tot["compile_ms"] == 0.0 and tot["compile_hits"] == 1
+    assert tot["device_ms"] == pytest.approx(exe[0].dur_ns / 1e6)
+    assert REGISTRY.snapshot()["xla_compiles_total"] \
+        == c1["xla_compiles_total"]
+
+
+def test_nested_compile_stages_count_as_wall_time():
+    """JAX reports every stage whole; one that ended inside another is
+    taken out of it, so the counter is wall time."""
+    import threading
+
+    from tidb_tpu.trace import recorder
+
+    trace_ev, _lower, backend = recorder._COMPILE_EVENTS
+    got = {}
+
+    def body():
+        tr, token = trace_mod.start_trace("compile stages")
+        try:
+            with trace_mod.span("holder") as sp:
+                c0 = REGISTRY.snapshot()
+                recorder.note_compile(trace_ev, 0.010)   # an inner jit
+                recorder.note_compile(backend, 0.020)    # an eager op
+                recorder.note_compile(trace_ev, 0.100)   # encloses both
+                recorder.note_compile("/jax/other", 5.0)
+                c1 = REGISTRY.snapshot()
+                got.update(ns=sp.attrs["compile_ns"], secs=(
+                    c1["xla_compile_seconds_total"]
+                    - c0["xla_compile_seconds_total"]), n=(
+                    c1["xla_compiles_total"] - c0["xla_compiles_total"]))
+        finally:
+            trace_mod.finish_trace(tr, token)
+
+    t = threading.Thread(target=body)  # its own stack of stages
+    t.start()
+    t.join()
+    assert got["secs"] == pytest.approx(0.100)
+    assert got["ns"] == pytest.approx(0.100e9, rel=1e-6)
+    assert got["n"] == 1
+
+
+def test_slow_log_off_no_span_is_made_at_any_new_site(env, monkeypatch):
+    import gc
+
+    from tidb_tpu.copr import parallel as pl
+    from tidb_tpu.trace import recorder
+
+    made = []
+
+    class Counting(recorder.Span):
+        __slots__ = ()
+
+        def __init__(self, name, trace):
+            made.append(name)
+            super().__init__(name, trace)
+
+    real = pl._call_args
+
+    def collecting(*a):
+        gc.collect()
+        return real(*a)
+
+    d, s = env
+    want = s.query(MESH["agg"])
+    monkeypatch.setattr(recorder, "Span", Counting)
+    monkeypatch.setattr(pl, "_call_args", collecting)
+    s.execute("set tidb_enable_slow_log = 0")
+    try:
+        made.clear()
+        n = REGISTRY.snapshot()["py_gc_collections_total"]
+        got = s.query(MESH["agg"])
+        from test_lifecycle import _wait_no_select_threads
+
+        assert _wait_no_select_threads() == []
+        assert made == []
+        # the collector is still counted
+        assert REGISTRY.snapshot()["py_gc_collections_total"] >= n + 1
+    finally:
+        s.execute("set tidb_enable_slow_log = 1")
+    assert sorted(got) == sorted(want)
+    s.query(MESH["agg"])
+    assert "mesh.analyze" in made and "distsql.wake" in made

@@ -30,6 +30,17 @@ class _InFlight:
         self.failed = False
 
 
+_MISSES = threading.local()
+
+
+def thread_misses() -> int:
+    """`get_or_load` calls of the calling thread that did not find
+    their key resident, over every ByteCapCache: the difference around
+    a fetch says whether it was served from the device's memory
+    (`mesh.columns` counts `resident` by it)."""
+    return getattr(_MISSES, "n", 0)
+
+
 class ByteCapCache:
     """key -> tuple of device arrays (anything with .nbytes)."""
 
@@ -89,6 +100,8 @@ class ByteCapCache:
                 hit = self._cache.get(key)
                 if hit is not None:
                     return hit
+                # not resident: this thread loads or waits for a load
+                _MISSES.n = thread_misses() + 1
                 rec = self._inflight.get(key)
                 if rec is None:
                     rec = self._inflight[key] = _InFlight()

@@ -861,7 +861,7 @@ def run_base_jax(table, dag: DAG, start: int, end: int,
     kind = "agg" if an.agg is not None else (
         "topn" if an.topn is not None else "filter"
     )
-    from ..trace import span
+    from ..trace import NOOP, span
 
     col_order = an.needed_cols()
     # hoist predicate constants into runtime parameter slots (serving):
@@ -949,15 +949,18 @@ def run_base_jax(table, dag: DAG, start: int, end: int,
                 del_mask = jax.device_put(dm, dev)
 
         # first post-miss dispatch IS the XLA compile (jit compiles
-        # lazily): label it so compile time lands in the compile phase
-        dspan = ("copr.compile" if compiled_now else "copr.device.execute")
-        dattr = {"cache": "miss", **cattrs} if compiled_now else {}
+        # lazily): label it, as the mesh path does; the seconds JAX
+        # reports compiling land on the execute span inside the label
+        # (`compile_ns`), which is how they reach the compile phase
+        label = (span("copr.compile", cache="miss", kind=kind, **cattrs)
+                 if compiled_now else NOOP)
         # per-trace HBM attribution (ISSUE 13): resident tile-cache
         # bytes at dispatch time ride the execute span
-        dattr["hbm_bytes"] = DEVICE_CACHE._c._bytes
+        dattr = {"hbm_bytes": DEVICE_CACHE._c._bytes}
         compiled_now = False
         if kind == "filter":
-            with span(dspan, kind=kind, tile=tile_idx, **dattr):
+            with label, span("copr.device.execute", kind=kind,
+                             tile=tile_idx, **dattr):
                 with chunk_admission():
                     m, outs = fn(datas, valids, lo, hi, del_mask,
                                  *pextra)
@@ -987,7 +990,8 @@ def run_base_jax(table, dag: DAG, start: int, end: int,
                 if remaining_limit <= 0:
                     break
         elif kind == "agg":
-            with span(dspan, kind=kind, tile=tile_idx, **dattr):
+            with label, span("copr.device.execute", kind=kind,
+                             tile=tile_idx, **dattr):
                 with chunk_admission():
                     gcount, results = fn(datas, valids, lo, hi, del_mask,
                                          *pextra)
@@ -1000,7 +1004,8 @@ def run_base_jax(table, dag: DAG, start: int, end: int,
             agg_accum = _merge_device_agg(agg_accum, gh, rh, table, an,
                                           base0)
         else:  # topn
-            with span(dspan, kind=kind, tile=tile_idx, **dattr):
+            with label, span("copr.device.execute", kind=kind,
+                             tile=tile_idx, **dattr):
                 with chunk_admission():
                     idx, cnt = fn(datas, valids, lo, hi, del_mask,
                                   *pextra)

@@ -27,6 +27,7 @@ Tests run this on 8 virtual CPU devices (tests/conftest.py); the driver's
 from __future__ import annotations
 
 import threading
+import time
 import zlib
 from typing import Dict, List, Optional, Tuple
 
@@ -53,6 +54,7 @@ from .device_health import (
     attribute_devices,
     classify_failure,
 )
+from .cache import thread_misses
 from .ir import DAG
 from .jax_eval import JaxUnsupported, compile_expr
 from . import jax_engine as je
@@ -445,7 +447,7 @@ class _MeshCache:
                     off += n
                 nbytes = flat.nbytes + (
                     vflat.nbytes if vflat is not None else 0)
-                sp.set(bytes=nbytes, shard_bytes=nbytes // S)
+                sp.set(bytes=nbytes)
                 sh = NamedSharding(mesh, P("dp"))
                 data = jax.device_put(flat.reshape(n_pad, tile), sh)
                 valid = None
@@ -519,26 +521,56 @@ except AttributeError:  # pragma: no cover - very old CPython
     _atexit.register(_note_shutdown)
 
 
-def load_columns(mesh: Mesh, table, store_cis):
-    """Load several columns into the mesh cache concurrently; returns the
-    (data, valid) pairs in order.
+#: wall seconds of the `mesh.columns` intervals in which a column was
+#: not resident (a statement's wait for transfers; not `prefetch_table`)
+COLUMN_LOAD_SECONDS = "mesh_column_load_seconds_total"
+REGISTRY.inc(COLUMN_LOAD_SECONDS, 0.0)  # there from the start, at 0
+
+
+def _fetched(fetch, mesh: Mesh, table, store_ci: int):
+    """(the column, whether every cache it came through had it)."""
+    n = thread_misses()
+    return fetch(mesh, table, store_ci), thread_misses() == n
+
+
+def _load_many(fetch, mesh: Mesh, table, store_cis) -> list:
+    """`fetch` of several columns, concurrently on the transfer pool so
+    that the host tile build of one overlaps the transfer of another;
+    the results in order.  `mesh.columns` covers the whole lookup, the
+    futures included (`copr.transfer` nests in it on a miss); `resident`
+    says how many of `cols` the device already held.
 
     Multi-process meshes load SEQUENTIALLY: every process must issue
     device_puts against the shared mesh in the same deterministic order,
     or the collective fabric sees mismatched ops (observed as gloo
     'received data size doesn't match expected size' aborts)."""
-    cis = list(store_cis)
-    if len(cis) <= 1 or jax.process_count() > 1:
-        return [MESH_CACHE.get_column(mesh, table, ci) for ci in cis]
-    # pool workers re-attach to the submitter's span so transfer spans
-    # land in the query's trace (contextvars don't cross threads)
-    from ..trace import current_span, run_attached
+    from ..trace import current_span, run_attached, span
 
-    parent = current_span()
-    futs = [_xfer_pool().submit(run_attached, parent,
-                                MESH_CACHE.get_column, mesh, table, ci)
-            for ci in cis]
-    return [f.result() for f in futs]
+    cis = list(store_cis)
+    t0 = time.perf_counter()
+    with span("mesh.columns", cols=len(cis)) as sp:
+        if len(cis) <= 1 or jax.process_count() > 1:
+            got = [_fetched(fetch, mesh, table, ci) for ci in cis]
+        else:
+            # pool workers re-attach to the submitter's span so transfer
+            # spans land in the query's trace (contextvars don't cross
+            # threads)
+            parent = current_span()
+            futs = [_xfer_pool().submit(run_attached, parent, _fetched,
+                                        fetch, mesh, table, ci)
+                    for ci in cis]
+            got = [f.result() for f in futs]
+        resident = sum(1 for _col, hit in got if hit)
+        sp.set(resident=resident)
+    if resident < len(cis):
+        REGISTRY.inc(COLUMN_LOAD_SECONDS, time.perf_counter() - t0)
+    return [col for col, _hit in got]
+
+
+def load_columns(mesh: Mesh, table, store_cis):
+    """Load several columns into the mesh cache (`_load_many`); returns
+    the (data, valid) pairs in order."""
+    return _load_many(MESH_CACHE.get_column, mesh, table, store_cis)
 
 
 def get_layout_column(mesh: Mesh, table, store_ci: int):
@@ -590,18 +622,8 @@ def get_layout_column(mesh: Mesh, table, store_ci: int):
 
 def load_layout_columns(mesh: Mesh, table, store_cis):
     """Layout-aware variant of `load_columns`: per-column hot/cold
-    entries, concurrent transfers on the xfer pool (same multi-process
-    determinism rule)."""
-    cis = list(store_cis)
-    if len(cis) <= 1 or jax.process_count() > 1:
-        return [get_layout_column(mesh, table, ci) for ci in cis]
-    from ..trace import current_span, run_attached
-
-    parent = current_span()
-    futs = [_xfer_pool().submit(run_attached, parent,
-                                get_layout_column, mesh, table, ci)
-            for ci in cis]
-    return [f.result() for f in futs]
+    entries."""
+    return _load_many(get_layout_column, mesh, table, store_cis)
 
 
 def prefetch_table(storage, table_id: int, min_rows: int = 1 << 20):
@@ -1812,8 +1834,10 @@ def try_run_mesh(storage, req: CopRequest, table_id=None):
     generator for filters (streamed gathers — iterate exactly once; a
     device error before the first chunk retries on the rebuilt mesh,
     after rows were emitted it surfaces to the consumer)."""
-    dag = DAG.from_dict(req.dag)
-    tid = table_id if table_id is not None else dag.scan.table_id
+    # the scan's table id as the request's dictionary has it: the one
+    # parse of the DAG is `_run_mesh_once`'s, under `mesh.analyze`
+    tid = (table_id if table_id is not None
+           else req.dag["executors"][0]["table_id"])
     range_tids = sorted({kr.table_id for kr in req.ranges})
     if range_tids and (len(range_tids) > 1 or range_tids[0] != tid):
         # partitioned table: ranges address partition stores, not the
@@ -1944,91 +1968,95 @@ def _run_mesh_once(storage, req: CopRequest, tid: int,
     the blown-budget agg moves to the host tail.  `forced_label` names
     the split reason for such forced cuts (the region analyzes cleanly,
     so plan_regions cannot classify them itself)."""
-    dag = DAG.from_dict(req.dag)
-    table = storage.table(tid)
-    if table.base_rows == 0 or table.base_ts > req.ts:
-        req.mesh_reject_reason = "empty table or stale snapshot"
-        return None
-    if len(req.ranges) > MESH_RANGE_SLOTS:
-        req.mesh_reject_reason = f"{len(req.ranges)} disjoint ranges"
-        return None  # many disjoint ranges: per-region fan-out handles it
-    from .fusion import fusion_enabled, plan_regions, run_tail
+    from ..trace import annotate, span
 
-    if not fusion_enabled():
-        req.mesh_reject_reason = "whole-fragment fusion disabled"
-        return None
-    # fusion-region planning (copr/fusion.py): the longest device-
-    # compilable executor prefix becomes the fused mesh program; an
-    # unfusable suffix runs as a host tail over the region's output
-    # instead of rejecting the whole fragment off the mesh path
-    try:
-        plan = plan_regions(dag, table, max_cut=max_cut)
-    except JaxUnsupported as e:
-        req.mesh_reject_reason = str(e)
-        return None
-    if plan.tail and len(plan.dag.executors) == 1:
-        req.mesh_reject_reason = (
-            plan.split_reason or "fragment not device-eligible")
-        return None
-    if plan.tail and forced_label and plan.split_reason is None:
-        # the forced cut saw no JaxUnsupported (the head analyzes
-        # cleanly), so classify_split_reason defaulted — the caller
-        # knows the true cause (e.g. a blown agg budget)
-        plan.reason_label = forced_label
-    an, tail = plan.an, plan.tail
-    kind = "agg" if an.agg is not None else (
-        "topn" if an.topn is not None else "filter"
-    )
-    # hoist predicate constants into runtime parameter slots (serving/
-    # params.py): the fingerprint serializes slots, so parameter-different
-    # queries — a changed date literal, a different point-lookup key —
-    # reuse the SAME compiled shard_map program instead of recompiling
-    from ..serving import hoist_conds
+    with span("mesh.analyze") as asp:
+        dag = DAG.from_dict(req.dag)
+        table = storage.table(tid)
+        if table.base_rows == 0 or table.base_ts > req.ts:
+            req.mesh_reject_reason = "empty table or stale snapshot"
+            return None
+        if len(req.ranges) > MESH_RANGE_SLOTS:
+            req.mesh_reject_reason = f"{len(req.ranges)} disjoint ranges"
+            return None  # many disjoint ranges: per-region fan-out handles it
+        from .fusion import fusion_enabled, plan_regions, run_tail
 
-    hoisted = hoist_conds(an)
+        if not fusion_enabled():
+            req.mesh_reject_reason = "whole-fragment fusion disabled"
+            return None
+        # fusion-region planning (copr/fusion.py): the longest device-
+        # compilable executor prefix becomes the fused mesh program; an
+        # unfusable suffix runs as a host tail over the region's output
+        # instead of rejecting the whole fragment off the mesh path
+        try:
+            plan = plan_regions(dag, table, max_cut=max_cut)
+        except JaxUnsupported as e:
+            req.mesh_reject_reason = str(e)
+            return None
+        if plan.tail and len(plan.dag.executors) == 1:
+            req.mesh_reject_reason = (
+                plan.split_reason or "fragment not device-eligible")
+            return None
+        if plan.tail and forced_label and plan.split_reason is None:
+            # the forced cut saw no JaxUnsupported (the head analyzes
+            # cleanly), so classify_split_reason defaulted — the caller
+            # knows the true cause (e.g. a blown agg budget)
+            plan.reason_label = forced_label
+        an, tail = plan.an, plan.tail
+        kind = "agg" if an.agg is not None else (
+            "topn" if an.topn is not None else "filter"
+        )
+        # hoist predicate constants into runtime parameter slots (serving/
+        # params.py): the fingerprint serializes slots, so parameter-different
+        # queries — a changed date literal, a different point-lookup key —
+        # reuse the SAME compiled shard_map program instead of recompiling
+        from ..serving import hoist_conds
 
-    mesh = get_mesh()
-    S = len(mesh.devices.ravel())
-    n_tiles, n_pad, Tl = _layout(table.base_rows, S, table=table)
-    col_order = an.needed_cols()
-    _observe_fragment(table, an)
+        hoisted = hoist_conds(an)
 
-    # runtime join-filter payloads: sorted build keys, padded to a pow2
-    # bucket so compiled programs are reused across key-set sizes
-    pargs: list = []
-    counts: List[int] = []
-    kpads: List[int] = []
-    for p in an.probes:
-        arr = (req.aux or {}).get(f"probe_keys_{p.filter_id}")
-        if arr is None:
-            from ..errors import ExecutorError
+        mesh = get_mesh()
+        S = len(mesh.devices.ravel())
+        n_tiles, n_pad, Tl = _layout(table.base_rows, S, table=table)
+        col_order = an.needed_cols()
+        _observe_fragment(table, an)
 
-            raise ExecutorError(f"missing runtime probe keys {p.filter_id}")
-        if p.key.ftype.kind == TypeKind.FLOAT:
-            # aux carries canonical int64 BIT patterns (ir.key_bits_int64);
-            # the device compares float keys by VALUE (no 64-bit bitcast on
-            # this backend), so translate bits -> values here and re-sort
-            # (bit order != value order for negatives)
-            vals = np.sort(arr.view(np.float64))
-            k = len(vals)
-            kpad = 16
-            while kpad < k:
-                kpad <<= 1
-            padded = np.full(kpad, np.inf, dtype=np.float64)
-            padded[:k] = vals
-        else:
-            k = len(arr)
-            kpad = 16
-            while kpad < k:
-                kpad <<= 1
-            padded = np.full(kpad, np.iinfo(np.int64).max, dtype=np.int64)
-            padded[:k] = arr
-        pargs.append(jnp.asarray(padded))
-        counts.append(k)
-        kpads.append(kpad)
+        # runtime join-filter payloads: sorted build keys, padded to a pow2
+        # bucket so compiled programs are reused across key-set sizes
+        pargs: list = []
+        counts: List[int] = []
+        kpads: List[int] = []
+        for p in an.probes:
+            arr = (req.aux or {}).get(f"probe_keys_{p.filter_id}")
+            if arr is None:
+                from ..errors import ExecutorError
+
+                raise ExecutorError(
+                    f"missing runtime probe keys {p.filter_id}")
+            if p.key.ftype.kind == TypeKind.FLOAT:
+                # aux carries canonical int64 BIT patterns (ir.key_bits_int64);
+                # the device compares float keys by VALUE (no 64-bit bitcast on
+                # this backend), so translate bits -> values here and re-sort
+                # (bit order != value order for negatives)
+                vals = np.sort(arr.view(np.float64))
+                k = len(vals)
+                kpad = 16
+                while kpad < k:
+                    kpad <<= 1
+                padded = np.full(kpad, np.inf, dtype=np.float64)
+                padded[:k] = vals
+            else:
+                k = len(arr)
+                kpad = 16
+                while kpad < k:
+                    kpad <<= 1
+                padded = np.full(kpad, np.iinfo(np.int64).max, dtype=np.int64)
+                padded[:k] = arr
+            pargs.append(jnp.asarray(padded))
+            counts.append(k)
+            kpads.append(kpad)
+        asp.set(kind=kind)
 
     from ..expr.expression import ColumnExpr
-    from ..trace import annotate, span
 
     if an.lookups:
         annotate(join=len(an.lookups))  # on `distsql.fanout`
@@ -2081,103 +2109,107 @@ def _run_mesh_once(storage, req: CopRequest, tid: int,
     # Loads run on the transfer pool so host tile builds overlap
     # host-to-device transfers.
     datas, valids, col_layout, lvals, wire_sig = [], [], [], [], []
-    for tier, entry in load_layout_columns(
-            mesh, table, [an.scan.columns[ci] for ci in col_order]):
-        if tier == "cold":
-            datas.append(entry.packed)
-            valids.append(None)
-            col_layout.append((entry.bits, entry.cap, entry.kind))
-            # the decode operand (bias scalar / dictionary vector) is
-            # already device-resident and replicated — a cold hit ships
-            # NOTHING over the link
-            lvals.append(entry.operand)
-            wire_sig.append(
-                (f"cold{entry.bits}c{entry.cap}{entry.kind[0]}", True))
+    entries = load_layout_columns(
+        mesh, table, [an.scan.columns[ci] for ci in col_order])
+    with span("mesh.program"):
+        for tier, entry in entries:
+            if tier == "cold":
+                datas.append(entry.packed)
+                valids.append(None)
+                col_layout.append((entry.bits, entry.cap, entry.kind))
+                # the decode operand (bias scalar / dictionary vector) is
+                # already device-resident and replicated — a cold hit ships
+                # NOTHING over the link
+                lvals.append(entry.operand)
+                wire_sig.append(
+                    (f"cold{entry.bits}c{entry.cap}{entry.kind[0]}", True))
+            else:
+                d, v = entry
+                datas.append(d)
+                valids.append(v)
+                col_layout.append(None)
+                wire_sig.append((str(d.dtype), v is None))
+        # computed-key remap operands ride the lvals tail AFTER the cold
+        # dictionary operands (one ordering contract with _build_sort_agg_core
+        # and trace_fused_fragment); mapping CONTENTS are runtime data
+        for r in (getattr(an, "key_remaps", None) or ()):
+            if r is not None:
+                lvals.append(jnp.asarray(r.mapping))
+        lvals = tuple(lvals)
+        if not any(col_layout):
+            col_layout = None
+
+        # device ids in the key: a rebuilt mesh (even same-size, after a
+        # breaker trip + probe-restore cycle) must never reuse a program whose
+        # closure captured the dead mesh object
+        mesh_ids = tuple(d.id for d in mesh.devices.ravel())
+        fp = (_fingerprint(an, kind)
+              + f"|mesh S={S} Tl={Tl} devs={mesh_ids} cols={col_order} "
+              + f"kpads={kpads} wire={wire_sig}"
+              + (f"|hp={len(hoisted[0])},{len(hoisted[1])}"
+                 if hoisted is not None else ""))
+        if kind == "agg" and an.agg_mode == "sort":
+            # the static OUT budget shapes the compiled program: a re-tuned
+            # TIDB_TPU_AGG_OUT must not reuse a program with the old budget
+            import os as _os
+
+            fp += "|aggout=" + _os.environ.get("TIDB_TPU_AGG_OUT", "")
+        from .fusion import compile_attrs, note_agg_dispatch
+
+        cattrs = compile_attrs(an, kind)
+        fn = _COMPILED.get(fp)
+        if fn is None:
+            fn = _build_mesh_fn(an, kind, col_order, mesh, Tl,
+                                _program_name(kind, fp),
+                                hoisted=(None if hoisted is None else
+                                         (len(hoisted[0]), len(hoisted[1]))),
+                                col_layout=col_layout)
+            _COMPILED.put(fp, fn)
+            # label this query's FIRST dispatch as the compile: jit compiles
+            # lazily, so the program-cache miss pays XLA compilation there
+            fn = _compile_labeled(fn, kind, cattrs)
         else:
-            d, v = entry
-            datas.append(d)
-            valids.append(v)
-            col_layout.append(None)
-            wire_sig.append((str(d.dtype), v is None))
-    # computed-key remap operands ride the lvals tail AFTER the cold
-    # dictionary operands (one ordering contract with _build_sort_agg_core
-    # and trace_fused_fragment); mapping CONTENTS are runtime data
-    for r in (getattr(an, "key_remaps", None) or ()):
-        if r is not None:
-            lvals.append(jnp.asarray(r.mapping))
-    lvals = tuple(lvals)
-    if not any(col_layout):
-        col_layout = None
+            with span("copr.compile", cache="hit", kind=kind, **cattrs):
+                pass
+        pargs = tuple(pargs)
+        # the statement's int64 scalars ride the operand vector behind each
+        # dispatch's range slots (the shard program reads them back through
+        # _split_operands); float64 parameters, where there are any, are the
+        # last parg, a host array as hoist_conds made it
+        scalars = np.array(counts, dtype=np.int64)
+        if hoisted is not None:
+            scalars = np.concatenate([scalars, hoisted[0]])
+            if len(hoisted[1]):
+                pargs = pargs + (hoisted[1],)
 
-    # device ids in the key: a rebuilt mesh (even same-size, after a
-    # breaker trip + probe-restore cycle) must never reuse a program whose
-    # closure captured the dead mesh object
-    mesh_ids = tuple(d.id for d in mesh.devices.ravel())
-    fp = (_fingerprint(an, kind)
-          + f"|mesh S={S} Tl={Tl} devs={mesh_ids} cols={col_order} "
-          + f"kpads={kpads} wire={wire_sig}"
-          + (f"|hp={len(hoisted[0])},{len(hoisted[1])}"
-             if hoisted is not None else ""))
-    if kind == "agg" and an.agg_mode == "sort":
-        # the static OUT budget shapes the compiled program: a re-tuned
-        # TIDB_TPU_AGG_OUT must not reuse a program with the old budget
-        import os as _os
-
-        fp += "|aggout=" + _os.environ.get("TIDB_TPU_AGG_OUT", "")
+    # on `distsql.fanout`
     annotate(device_ids=list(mesh_ids), tiles=n_tiles, tiles_padded=n_pad)
-    from .fusion import compile_attrs, note_agg_dispatch
 
-    cattrs = compile_attrs(an, kind)
-    fn = _COMPILED.get(fp)
-    if fn is None:
-        fn = _build_mesh_fn(an, kind, col_order, mesh, Tl,
-                            _program_name(kind, fp),
-                            hoisted=(None if hoisted is None
-                                     else (len(hoisted[0]), len(hoisted[1]))),
-                            col_layout=col_layout)
-        _COMPILED.put(fp, fn)
-        # label this query's FIRST dispatch as the compile: jit compiles
-        # lazily, so the program-cache miss pays XLA compilation there
-        fn = _compile_labeled(fn, kind, cattrs)
-    else:
-        with span("copr.compile", cache="hit", kind=kind, **cattrs):
-            pass
-    pargs = tuple(pargs)
-    # the statement's int64 scalars ride the operand vector behind each
-    # dispatch's range slots (the shard program reads them back through
-    # _split_operands); float64 parameters, where there are any, are the
-    # last parg, a host array as hoist_conds made it
-    scalars = np.array(counts, dtype=np.int64)
-    if hoisted is not None:
-        scalars = np.concatenate([scalars, hoisted[0]])
-        if len(hoisted[1]):
-            pargs = pargs + (hoisted[1],)
+    with span("mesh.delta") as dsp:
+        # one delta pass for the whole table
+        deleted, inserted = table.delta_overlay(req.ts, 0, 1 << 62)
+        if deleted:
+            dm = np.ones((n_pad, je.TILE), dtype=np.bool_)
+            flat = dm.reshape(-1)
+            flat[np.fromiter(sorted(deleted), dtype=np.int64,
+                             count=len(deleted))] = False
+            del_mask = jax.device_put(dm, NamedSharding(mesh, P("dp")))
+        else:
+            del_mask = _all_true(mesh, n_pad)
 
-    # one delta pass for the whole table
-    deleted, inserted = table.delta_overlay(req.ts, 0, 1 << 62)
-    if deleted:
-        dm = np.ones((n_pad, je.TILE), dtype=np.bool_)
-        flat = dm.reshape(-1)
-        flat[np.fromiter(sorted(deleted), dtype=np.int64,
-                         count=len(deleted))] = False
-        del_mask = jax.device_put(dm, NamedSharding(mesh, P("dp")))
-    else:
-        del_mask = _all_true(mesh, n_pad)
+        REGISTRY.inc("mesh_scans_total")
+        if cattrs:
+            note_agg_dispatch(an)
 
-    from ..metrics import REGISTRY
-
-    REGISTRY.inc("mesh_scans_total")
-    if cattrs:
-        note_agg_dispatch(an)
-
-    # every requested range runs in ONE fused dispatch: clip the bounds
-    # host-side and hand them to the program's range slots — no per-range
-    # dispatch loop, no host glue between ranges
-    bounds = []
-    for kr in req.ranges:
-        lo, hi = max(kr.start, 0), min(kr.end, table.base_rows)
-        if lo < hi:
-            bounds.append((lo, hi))
+        # every requested range runs in ONE fused dispatch: clip the bounds
+        # host-side and hand them to the program's range slots — no per-range
+        # dispatch loop, no host glue between ranges
+        bounds = []
+        for kr in req.ranges:
+            lo, hi = max(kr.start, 0), min(kr.end, table.base_rows)
+            if lo < hi:
+                bounds.append((lo, hi))
+        dsp.set(deleted=len(deleted), inserted=len(inserted))
 
     if kind == "filter":
         # large filter outputs STREAM: the generator gathers selected rows
@@ -2189,45 +2221,64 @@ def _run_mesh_once(storage, req: CopRequest, tid: int,
                               lvals=lvals,
                               split_label=plan.reason_label)
 
-    chunks: List[Chunk] = []
-    topn_parts: List[Chunk] = []
+    out = None
     if bounds:
         out = _dispatch_once(kind, fn, datas, valids, del_mask, bounds,
                              lvals, pargs, scalars, mesh_ids)
-        if kind == "agg" and an.agg_mode == "sort" \
-                and out["mode"] == "lookup":
-            chunks.extend(_lookup_agg_chunks(out, an, req.aux))
-        elif kind == "agg" and an.agg_mode == "sort":
-            try:
-                chunks.extend(_sort_agg_chunks(out, table, an))
-            except MeshAggOverflow as e:
-                # data-dependent, by-design: too many distinct groups per
-                # shard.  Re-enter the fused mesh with the AGG PEELED to
-                # the host tail (scan+selection stays device-resident and
-                # streamed) instead of dropping the whole fragment to the
-                # per-tile fan-out rung; fragments with no device-worthy
-                # head still take the old host-hash-agg demotion.
-                peeled = _peel_agg_rerun(storage, req, tid, dag, str(e))
-                if peeled is not None:
-                    return peeled
-                req.mesh_reject_reason = str(e)
-                return None
-        elif kind == "agg":
-            # wrapped() already unpacked to numpy and merged shard
-            # partials
-            chunks.append(je._device_agg_to_chunk(
-                _mesh_agg_accum(*out, table, an), table, an))
-        elif kind == "topn":
-            gidx, cnts, k = out
-            picks = []
-            for s in range(S):
-                c = int(cnts[s])
-                if c:
-                    picks.append(gidx[s * k: s * k + c])
-            if picks:
-                topn_parts.append(
-                    table.gather_chunk(list(an.scan.columns),
-                                       np.concatenate(picks)))
+    try:
+        with span("mesh.result") as rsp:
+            chunks = _mesh_result(req, dag, table, an, kind, out, inserted,
+                                  S)
+            # every shard program over every range completed: reset error
+            # streaks and close any half-open breaker that just survived
+            # its probe
+            DEVICE_HEALTH.record_success(mesh_ids)
+            rsp.set(chunks=len(chunks))
+            return chunks
+    except MeshAggOverflow as e:
+        # data-dependent, by-design: too many distinct groups per
+        # shard.  Re-enter the fused mesh with the AGG PEELED to
+        # the host tail (scan+selection stays device-resident and
+        # streamed) instead of dropping the whole fragment to the
+        # per-tile fan-out rung; fragments with no device-worthy
+        # head still take the old host-hash-agg demotion.
+        peeled = _peel_agg_rerun(storage, req, tid, dag, str(e))
+        if peeled is not None:
+            return peeled
+        req.mesh_reject_reason = str(e)
+        return None
+
+
+def _mesh_result(req, dag, table, an: _Analyzed, kind: str, out, inserted,
+                 S: int) -> List[Chunk]:
+    """What `mesh.result` covers: from the dispatch's unpacked result
+    (`out`; None where no range held a row) to the chunks the fan-out
+    hands on: accumulate, the delta rows' chunk, the TopN merge and the
+    host tail.  A sort aggregate past its budget raises MeshAggOverflow."""
+    chunks: List[Chunk] = []
+    topn_parts: List[Chunk] = []
+    if out is None:
+        pass
+    elif kind == "agg" and an.agg_mode == "sort" \
+            and out["mode"] == "lookup":
+        chunks.extend(_lookup_agg_chunks(out, an, req.aux))
+    elif kind == "agg" and an.agg_mode == "sort":
+        chunks.extend(_sort_agg_chunks(out, table, an))
+    elif kind == "agg":
+        # wrapped() already unpacked to numpy and merged shard partials
+        chunks.append(je._device_agg_to_chunk(
+            _mesh_agg_accum(*out, table, an), table, an))
+    elif kind == "topn":
+        gidx, cnts, k = out
+        picks = []
+        for s in range(S):
+            c = int(cnts[s])
+            if c:
+                picks.append(gidx[s * k: s * k + c])
+        if picks:
+            topn_parts.append(
+                table.gather_chunk(list(an.scan.columns),
+                                   np.concatenate(picks)))
 
     # delta rows (committed inserts/updates) go through the CPU engine
     res = _delta_chunk(req, dag, an, inserted)
@@ -2247,9 +2298,6 @@ def _run_mesh_once(storage, req: CopRequest, tid: int,
 
     from .engine import _merge_tail
 
-    # every shard program over every range completed: reset error streaks
-    # and close any half-open breaker that just survived its probe
-    DEVICE_HEALTH.record_success(mesh_ids)
     return [c for c in _merge_tail(dag, chunks) if c.num_rows > 0]
 
 
@@ -2274,7 +2322,7 @@ def _dispatch_once(kind, fn, datas, valids, del_mask, bounds, lvals, pargs,
     _check_membership_epoch()
     FAILPOINTS.hit("copr/chunk_dispatch", kind=kind, chunk=0, total=1,
                    start=start, end=end)
-    with span("copr.chunk", kind=kind, chunk=0, devices=len(mesh_ids),
+    with span("copr.chunk", kind=kind, devices=len(mesh_ids),
               rows=sum(hi - lo for lo, hi in bounds)):
         # resource-group admission per dispatch: a depleted group
         # waits here, and the dispatch's device time is its charge

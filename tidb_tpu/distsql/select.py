@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import queue
 import threading
+import time
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Iterator, List, Optional
@@ -128,6 +129,10 @@ class SelectResult:
         # named so leak checks (tests/chaos harness) can find stragglers
         self._thread = threading.Thread(
             target=self._run, daemon=True, name="tidb-tpu-select")
+        # `distsql.spawn`: from here to the producer's first line, the
+        # hand-off of the GIL to a thread made for this statement
+        self._spawn_ns = (time.perf_counter_ns()
+                          if self._parent_span is not None else 0)
         self._thread.start()
 
     # ---- producer side -------------------------------------------------
@@ -140,7 +145,9 @@ class SelectResult:
             # to the consumer via _finish_error (the producer catches it)
             self._scope.check()
             try:
-                self._chunks.put(item, timeout=0.05)
+                # stamped for `distsql.wake`, which ends on the consumer
+                self._chunks.put((item, time.perf_counter_ns()),
+                                 timeout=0.05)
                 return
             except queue.Full:
                 continue
@@ -236,11 +243,14 @@ class SelectResult:
         with attach_scope(self._scope), attach(self._parent_span):
             with span("distsql.fanout", engine=self.req.engine) as sp:
                 self._fanout_span = None if sp is NOOP else sp
+                if sp is not NOOP:
+                    sp._trace.add_span(
+                        "distsql.spawn", sp.start_ns - self._spawn_ns,
+                        start_ns=self._spawn_ns, parent=sp)
                 try:
                     self._produce()
                 finally:
                     sp.set(scan_engine=self.scan_engine,
-                           tasks=self.total_tasks,
                            fallback_tasks=self.fallback_tasks)
 
     @staticmethod
@@ -265,32 +275,36 @@ class SelectResult:
     def _produce(self):
         try:
             if self.req.engine == "tpu":
-                # sharded data plane (tidb_tpu/dataplane): tables
+                # the rungs asked before the mesh, under `distsql.route`
+                # (both decline in a deployment of one process with the
+                # batcher off, and then the span is all they cost).
+                # Sharded data plane (tidb_tpu/dataplane): tables
                 # partitioned across the fleet scatter over partition
                 # owners and gather in handle order; None when the
                 # table is unsharded, the shard snapshot is stale, or
                 # any fragment fails (the local paths below hold the
-                # full base table, so the fallback is always correct)
-                from ..dataplane import try_run_dataplane
-
-                dpc = try_run_dataplane(self.storage, self.req)
-                if dpc is not None:
-                    self.scan_engine = "dataplane"
-                    for c in dpc:
-                        self._put(c)
-                    self._put(_DONE)
-                    return
-                # micro-batch rung (tidb_tpu/serving): identical-shape
+                # full base table, so the fallback is always correct).
+                # Micro-batch rung (tidb_tpu/serving): identical-shape
                 # point/agg statements arriving within the batching
                 # window coalesce into one vmapped device dispatch; None
                 # when ineligible/disabled or on a benign batch failure
                 # (the solo rungs below re-run with identical results)
+                from ..dataplane import try_run_dataplane
                 from ..serving import try_run_microbatch
+                from ..trace import span
 
-                mb = try_run_microbatch(self.storage, self.req)
-                if mb is not None:
-                    self.scan_engine = "microbatch"
-                    for c in mb:
+                declined = []
+                with span("distsql.route") as rsp:
+                    for rung, ask in (("dataplane", try_run_dataplane),
+                                      ("microbatch", try_run_microbatch)):
+                        out = ask(self.storage, self.req)
+                        if out is not None:
+                            break
+                        declined.append(rung)
+                    rsp.set(declined=",".join(declined))
+                if out is not None:
+                    self.scan_engine = rung
+                    for c in out:
                         self._put(c)
                     self._put(_DONE)
                     return
@@ -404,7 +418,7 @@ class SelectResult:
         except queue.Empty:
             pass
         try:
-            self._chunks.put_nowait(_DONE)
+            self._chunks.put_nowait((_DONE, time.perf_counter_ns()))
         except queue.Full:  # pragma: no cover - queue just drained
             pass
 
@@ -412,7 +426,20 @@ class SelectResult:
     def next_chunk(self) -> Optional[Chunk]:
         if self._closed:
             return None
-        item = self._chunks.get()
+        from ..trace import current_span
+
+        cur = current_span()
+        asked = time.perf_counter_ns() if cur is not None else 0
+        item, put_ns = self._chunks.get()
+        if cur is not None:
+            # `distsql.wake`: from the producer's put (or from asking,
+            # where the item was waiting) to holding it here, the
+            # hand-off of the GIL back; `items` is what was ready
+            start = max(put_ns, asked)
+            cur._trace.add_span(
+                "distsql.wake", time.perf_counter_ns() - start,
+                start_ns=start, parent=cur,
+                items=1 + self._chunks.qsize())
         if item is _DONE:
             if self._err is not None:
                 err, self._err = self._err, None

@@ -124,6 +124,14 @@ class Registry:
         with self._mu:
             self._counters[name] = value
 
+    def publish(self, name: str, value: float):
+        """Overwrite one counter WITHOUT the lock: for the one caller
+        that may run while its own thread holds it (the collector's
+        callback, trace/recorder.py::_on_gc).  Sound for a key that
+        exists and has that single writer: a dict store is atomic under
+        the GIL and every reader copies the dict in one C call."""
+        self._counters[name] = value
+
     def get(self, name: str, default: float = 0.0) -> float:
         """Point read of one counter/gauge (cheaper than snapshot())."""
         with self._mu:

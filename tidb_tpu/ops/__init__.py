@@ -9,6 +9,9 @@ Importing this package configures jax for the framework:
 import os
 
 import jax
+import jax.monitoring
+
+from ..metrics import REGISTRY
 
 jax.config.update("jax_enable_x64", True)
 
@@ -23,6 +26,22 @@ if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
             os.path.abspath(__file__)))), ".jax_cache"),
     )
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+
+
+def _on_duration(event: str, secs: float, **_kw):
+    # the recorder is looked up at the event (a compile), not here: this
+    # package is imported by modules the trace package itself imports
+    from ..trace.recorder import note_compile
+
+    note_compile(event, secs)
+
+
+# the program's one jax.monitoring listener: what XLA compiles cost, as
+# counters (there from the start, at 0) and on the span that compiled
+# (trace/recorder.py::note_compile)
+for _name in ("xla_compile_seconds_total", "xla_compiles_total"):
+    REGISTRY.inc(_name, 0.0)
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
 
 from .segment import (  # noqa: E402
     UNROLL_G,

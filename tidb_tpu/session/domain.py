@@ -95,9 +95,12 @@ class Domain:
         # continuous profiler (ISSUE 13): every finished trace folds
         # into the rotating flame windows; chains onto the trace export
         # hook (never replacing a coord plane's forwarder), idempotent
-        from ..trace import install_profiler
+        from ..trace import install_gc_spans, install_profiler
 
         install_profiler()
+        # the collector's pauses as `py.gc` spans and counters, likewise
+        # once a process
+        install_gc_spans()
         if data_dir:
             self._recover(data_dir)
         self._bootstrap()

@@ -711,8 +711,11 @@ class Session:
             from . import bindinfo
 
             bindinfo.maybe_capture(self, sql, stmt, phys)
-        ctx = self._exec_ctx(current_read=for_update)
-        exe = phys.build(ctx)
+        from ..trace import span
+
+        with span("executor.build"):
+            ctx = self._exec_ctx(current_read=for_update)
+            exe = phys.build(ctx)
         chunks = collect_all(exe)
         headers = phys.schema.headers() if len(phys.schema) else []
         rows: List[tuple] = []

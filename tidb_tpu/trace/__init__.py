@@ -35,6 +35,7 @@ from .recorder import (  # noqa: F401
     current_span,
     current_trace,
     finish_trace,
+    install_gc_spans,
     run_attached,
     span,
     start_trace,

@@ -21,11 +21,18 @@ before the root's start as negative numbers.  The three spans after the
 root are appended once `finish_trace` has run, so the slow log, the
 phase histograms and the export to a coordinator never see them; a
 holder of the finished QueryTrace (the ring, `Session.last_trace`, a
-chained export hook that keeps the object) does.
+chained export hook that keeps the object) does.  With `parent=` a
+pre-timed span hangs under another span than the root: the fan-out's two
+thread hand-offs (`distsql.spawn`, `distsql.wake`).  Two things happen
+under no `span(...)` of the program's own and are recorded at the
+bottom of this module: XLA compiles (`note_compile`, from JAX's
+monitoring events: `compile_ns` on the span that compiled) and the
+collector's pauses (`_on_gc`: a `py.gc` span under the current one).
 """
 
 from __future__ import annotations
 
+import gc
 import itertools
 import threading
 import time
@@ -35,6 +42,7 @@ from collections import deque
 from contextvars import ContextVar
 from dataclasses import dataclass
 from typing import Dict, List, Optional
+from ..metrics import REGISTRY
 from ..util_concurrency import make_lock
 
 #: per-process statement-trace sequence: multi-controller SPMD runs the
@@ -142,12 +150,14 @@ class QueryTrace:
         return s
 
     def add_span(self, name: str, dur_ns: int = 0,
-                 start_ns: Optional[int] = None, **attrs) -> Span:
-        """Append a pre-timed span under the root after the fact, at
-        `start_ns` on `perf_counter_ns` (the moment the work BEGAN; left
-        out, the moment of the append) — the wire layer records result
-        write time onto the already-finished trace (the statement ended
-        before the rows hit the socket)."""
+                 start_ns: Optional[int] = None,
+                 parent: Optional[Span] = None, **attrs) -> Span:
+        """Append a pre-timed span after the fact, under `parent` (left
+        out, the root), at `start_ns` on `perf_counter_ns` (the moment
+        the work BEGAN; left out, the moment of the append) — the wire
+        layer records result write time onto the already-finished trace
+        (the statement ended before the rows hit the socket), the
+        fan-out its two thread hand-offs under the span that waited."""
         s = Span(name, self)
         if start_ns is not None:
             s.start_ns = start_ns
@@ -155,7 +165,7 @@ class QueryTrace:
         if attrs:
             s.set(**attrs)
         with self._mu:
-            self.root.children.append(s)
+            (parent or self.root).children.append(s)
         return s
 
     # ---- rendering ------------------------------------------------------
@@ -219,28 +229,18 @@ class QueryTrace:
             "engines": set(), "devices": set(),
         }
 
-        def nested_phase_ms(s: Span) -> float:
-            """Descendant time already attributed to other copr phases."""
-            out = 0.0
-            for c in s.children:
-                if c.name in ("copr.device.execute", "copr.readback",
-                              "copr.transfer"):
-                    out += (c.dur_ns or 0) / 1e6
-                out += nested_phase_ms(c)
-            return out
-
         def walk(s: Span):
             ms = (s.dur_ns or 0) / 1e6
             a = s.attrs or {}
             n = s.name
-            if n == "copr.compile":
-                # a cache miss labels the whole first dispatch; the
-                # execute/readback spans nested inside it are attributed
-                # to their own phases, so compile keeps only its SELF
-                # time (no double counting across phase columns)
-                tot["compile_ms"] += max(ms - nested_phase_ms(s), 0.0)
-            elif n in PHASES:
-                tot[PHASES[n]] += ms
+            # what JAX reported compiling under this span (`compile_ns`,
+            # note_compile): a jitted call traces, lowers and compiles
+            # inside its own `copr.device.execute`, so that span gives
+            # the compile its seconds and keeps the rest as device time
+            comp = a.get("compile_ns", 0) / 1e6
+            tot["compile_ms"] += comp
+            if n in PHASES:
+                tot[PHASES[n]] += max(ms - comp, 0.0)
             if n == "copr.compile":
                 if a.get("cache") == "hit":
                     tot["compile_hits"] += 1
@@ -292,7 +292,6 @@ class QueryTrace:
 PHASES = {
     "parse": "parse_ms",
     "plan": "plan_ms",
-    "copr.compile": "compile_ms",
     "copr.transfer": "transfer_ms",
     # one fused XLA launch per mesh dispatch (whole-fragment fusion)
     "copr.device.execute": "device_ms",
@@ -509,8 +508,6 @@ def finish_trace(tr: QueryTrace, token):
         except Exception:
             pass
     TRACE_RING.append(tr)
-    from ..metrics import REGISTRY
-
     totals = tr.phase_totals()
     # real log2-bucket histograms (ISSUE 13): p50/p95/p99 per phase on
     # /metrics and /status instead of the old _count/_sum/_max triple
@@ -525,3 +522,86 @@ def finish_trace(tr: QueryTrace, token):
         REGISTRY.inc("trace_readback_bytes_total",
                      float(totals["readback_bytes"]))
     return totals
+
+
+# ---------------------------------------------------------------------------
+# what runs under no `span(...)` of the program's own: XLA compiles (JAX
+# reports them) and the collector's pauses (CPython reports them)
+# ---------------------------------------------------------------------------
+
+#: the three stages of one jit compile, in the order they end
+_COMPILE_EVENTS = (
+    "/jax/core/compile/jaxpr_trace_duration",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration",
+    "/jax/core/compile/backend_compile_duration",
+)
+_COMPILING = threading.local()
+
+
+def note_compile(event: str, secs: float):
+    """The program's one `jax.monitoring` duration listener (registered
+    where `tidb_tpu/ops` configures JAX).  It runs on the thread that
+    compiles, when a stage ends: the seconds go to
+    `xla_compile_seconds_total` (a compile is counted at its last
+    stage) and, as `compile_ns`, onto the span current there — the
+    `copr.device.execute` a jitted call compiles inside — from which
+    `phase_totals` takes `compile_ms`.  Stages nest (a jit traced inside
+    an outer trace, an eager op compiled under one), and JAX reports each
+    whole: a stage is counted less the stages that ended inside it, so
+    the total is wall time."""
+    if event not in _COMPILE_EVENTS:
+        return
+    done = getattr(_COMPILING, "done", None)
+    if done is None:
+        done = _COMPILING.done = []
+    start = time.perf_counter() - secs
+    inner = 0.0
+    while done and done[-1][0] >= start:
+        inner += done.pop()[1]
+    done.append((start, secs))
+    del done[:-64]  # stages that no later one encloses
+    own = max(secs - inner, 0.0)
+    REGISTRY.inc("xla_compile_seconds_total", own)
+    if event == _COMPILE_EVENTS[-1]:
+        REGISTRY.inc("xla_compiles_total")
+    cur = _CUR.get()
+    if cur is not None:
+        cur.add("compile_ns", int(own * 1e9))
+
+
+_GC_COUNTERS = ("py_gc_pause_seconds_total", "py_gc_collections_total")
+_gc_state = [0, 0, 0]  # start of the running collection; ns, count so far
+
+
+def _on_gc(phase: str, info: dict):
+    """`gc.callbacks` entry: a collection, from `start` to `stop`, as a
+    pre-timed `py.gc` span under the span current on the thread it ran
+    on, and in the two counters whether a trace is open there or not.
+    CPython runs a collection between any two bytecodes of whichever
+    thread tripped it, so that thread may hold ANY lock: nothing here
+    takes one (collections do not nest, so this entry is its own only
+    writer; a list append and a dict store are atomic under the GIL)."""
+    if phase == "start":
+        _gc_state[0] = time.perf_counter_ns()
+        return
+    t0 = _gc_state[0]
+    dur = time.perf_counter_ns() - t0
+    _gc_state[1] += dur
+    _gc_state[2] += 1
+    REGISTRY.publish(_GC_COUNTERS[0], _gc_state[1] / 1e9)
+    REGISTRY.publish(_GC_COUNTERS[1], float(_gc_state[2]))
+    cur = _CUR.get()
+    if cur is not None:
+        s = Span("py.gc", cur._trace)
+        s.start_ns, s.dur_ns = t0, dur
+        s.attrs = {"gen": info.get("generation"),
+                   "collected": info.get("collected")}
+        cur.children.append(s)
+
+
+def install_gc_spans():
+    """Put `_on_gc` among `gc.callbacks`, once a process."""
+    if _on_gc not in gc.callbacks:
+        for name in _GC_COUNTERS:
+            REGISTRY.inc(name, 0.0)  # the keys exist before `publish`
+        gc.callbacks.append(_on_gc)
