@@ -7,21 +7,25 @@ variable and no `tidb_tpu_*` sysvar is set here.
 from __future__ import annotations
 
 import asyncio
+import importlib
 import threading
 import time
 
 import numpy as np
 
-from . import datagen
 from .wire import WireClient
 
 
 def load(config: dict, seed: int, sf: float, log) -> tuple:
-    """(domain, tables, seconds by phase).  `tables` holds the benchmark's
-    own narrow copy of every column loaded, for the reference."""
+    """(domain, tables, seconds by phase).  The rows come from the
+    generator the configuration names, `generators/<generator>.py`, block
+    by block as it yields them.  `tables` holds the benchmark's own narrow
+    copy of every column loaded, for the reference."""
     from tidb_tpu.session import Domain
 
     t0 = time.perf_counter()
+    gen_name = config["generator"]
+    gen = importlib.import_module(f"generators.{gen_name}")
     domain = Domain()
     sess = domain.new_session()
     want = config["tables"]
@@ -33,24 +37,26 @@ def load(config: dict, seed: int, sf: float, log) -> tuple:
         info = domain.catalog.info_schema().table("test", name)
         stores[name] = (info, domain.storage.table(info.id))
 
-    def put(name: str, block: dict):
-        info, store = stores[name]
+    for name, block in gen.blocks(set(want), sf, seed):
+        if name not in want:
+            continue
         cols = want[name]["columns"]
-        dicts = {i: datagen.DICTIONARIES[c] for i, c in enumerate(cols)
-                 if c in datagen.DICTIONARIES}
+        for c in cols:
+            if c not in block:
+                raise ValueError(f"generator {gen_name!r} makes no column "
+                                 f"{c!r} of table {name!r}")
+        dicts = {i: gen.DICTIONARIES[c] for i, c in enumerate(cols)
+                 if c in gen.DICTIONARIES}
+        _info, store = stores[name]
         store.bulk_load_arrays([block[c] for c in cols],
                                ts=domain.storage.current_ts(),
                                dictionaries=dicts or None)
         for c in cols:
             kept[name][c].append(block[c])
-
-    if "customer" in want:
-        put("customer", datagen.customer(sf, seed))
-    for orders, lineitem in datagen.order_blocks(sf, seed):
-        if "orders" in want:
-            put("orders", orders)
-        if "lineitem" in want:
-            put("lineitem", lineitem)
+    for name, cols in kept.items():
+        if not next(iter(cols.values())):
+            raise ValueError(f"generator {gen_name!r} makes no table "
+                             f"{name!r}")
     t_gen = time.perf_counter()
     for name, (info, store) in stores.items():
         domain.storage.regions.split_even(info.id, config["regions"],

@@ -7,7 +7,7 @@ exact"."""
 import numpy as np
 
 from . import _common as c
-from harness.datagen import DICTIONARIES
+from generators.tpch import DICTIONARIES
 
 
 def _groups(li, p, dtype):

@@ -11,7 +11,7 @@ in float32 all the way, as `q1.py` has it; it breaks the configuration's
 import numpy as np
 
 from . import _common as c
-from harness.datagen import DICTIONARIES
+from generators.tpch import DICTIONARIES
 
 HALF = 31
 #: rows of a block.  A quarter of `_common.BLOCK`: its arrays of 2^22

@@ -6,7 +6,7 @@ Revenue of one order is under 2^53, so the exact integer sums fit.
 import numpy as np
 
 from . import _common as c
-from harness.datagen import DICTIONARIES
+from generators.tpch import DICTIONARIES
 
 
 def _top(tables, p, dtype):

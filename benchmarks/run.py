@@ -12,9 +12,11 @@ received in the window with the plain reference's.  Earlier lines of
 standard output are JSON notes; the last is the result.
 
 Everything of one cell is data found by name: `BENCHMARK.json` gives the
-cell its configuration and traffic mix, `configs/<name>.json`,
-`traffic/<name>.json`, `queries/<name>.json` with the reference beside it
-in `queries/<name>.py`, and `metrics/<name>.py` for each per-layer metric.
+cell its configuration and traffic mix, `configs/<name>.json`, the
+generator of its tables that the configuration names in
+`generators/<generator>.py`, `traffic/<name>.json`, `queries/<name>.json`
+with the reference beside it in `queries/<name>.py`, and
+`metrics/<name>.py` for each per-layer metric.
 
 Options of the harness's own, which no cell uses: `--sf` runs the cell at
 another scale factor, `--rehearse-cpu 1` lets it run without a TPU (both

@@ -2,7 +2,7 @@
 
 import numpy as np
 
-from harness import datagen as g
+from generators import tpch as g
 
 SF = 0.02
 

@@ -30,7 +30,7 @@ def lineitem():
 
 @pytest.fixture(scope="module")
 def q3_tables():
-    return _load("tpch-sf10-q3")
+    return _load("tpch-sf1-q3")
 
 
 def _query(name):
@@ -55,9 +55,9 @@ def test_reference_equals_the_oracle_engine_on_every_tuple(name, lineitem):
 
 
 def test_q3_reference_equals_the_programs_groups_sorted_as_numbers(q3_tables):
-    """The second witness for the fault below: the program's own groups,
-    without its ORDER BY and LIMIT, sorted here as numbers, are the
-    reference's ten."""
+    """The program's own groups, without its ORDER BY and LIMIT, sorted
+    here as numbers, are the reference's ten: the witness that showed a
+    decimal sum ranked as text."""
     from decimal import Decimal
 
     sess, tables = q3_tables
@@ -70,10 +70,6 @@ def test_q3_reference_equals_the_programs_groups_sorted_as_numbers(q3_tables):
         assert compare.same_rows(_as_wire(top), ref.reference(tables, p)), p
 
 
-@pytest.mark.xfail(reason="program fault, PERF.md Open questions 1: ORDER BY "
-                   "on a decimal SUM sorts the values as text "
-                   "(copr/cpu_engine.py sort_indices, object columns)",
-                   strict=False)
 def test_q3_as_served_equals_the_reference(q3_tables):
     sess, tables = q3_tables
     q, ref = _query("q3")

@@ -80,10 +80,8 @@ ONE = {"server_envelope_ms": 0.5 + 1.5 + 1.0 + 2.0,
        "device_wait_ms": 1.0 + 40.0,
        "dispatch_wait_ms": 0.25,
        "chunk_unpack_ms": 0.75 + 1.5,
-       "host_gather_ms": 1.0 + 2.0 + 0.5,
        "root_exec_ms": 2.0 + 5.5}
-PER_PASS = ("device_wait_ms", "dispatch_wait_ms", "chunk_unpack_ms",
-            "host_gather_ms")
+PER_PASS = ("device_wait_ms", "dispatch_wait_ms", "chunk_unpack_ms")
 
 
 @pytest.mark.parametrize("name", sorted(ONE))
