@@ -236,4 +236,8 @@ def test_prepared_hit_pct_entry_is_as_the_issue_wrote_it(bench_path):
     assert m == {"name": "prepared_hit_pct", "unit": "%", "better": "higher",
                  "source": "program_span", "layer": "mesh engine",
                  "moves": "stmt_p50_ms"}
-    assert SPEC["per_layer"][-1] is m  # appended, nothing before it moved
+    # appended after the set-up readers, nothing before it moved; the
+    # metrics of later cells come after it
+    names = [x["name"] for x in SPEC["per_layer"]]
+    assert names.index("prepared_hit_pct") \
+        == names.index("setup_transfer_s") + 1

@@ -68,12 +68,16 @@ def test_mesh_used_and_sharded(sess):
     """The query must go through the mesh program, and the cached tile
     arrays must actually be laid out across every device (not replicated,
     not single-device)."""
+    from tidb_tpu.copr.parallel import MESH_CACHE
+
+    # the cache is the process's: an earlier test's entries (another
+    # domain's table of the same id, laid out while a breaker was open)
+    # must not stand in for this query's
+    MESH_CACHE.clear()
     before = _mesh_count()
     sess.execute("set tidb_use_tpu = 1")
     sess.query("select g, count(*) from t group by g")
     assert _mesh_count() > before, "query did not take the mesh path"
-
-    from tidb_tpu.copr.parallel import MESH_CACHE
 
     assert MESH_CACHE._cache, "mesh cache empty"
     data, _valid = next(iter(MESH_CACHE._cache.values()))

@@ -81,13 +81,14 @@ def q3_pair():
 
 
 def _fragment_args(sess, table, sql, mesh, n_tiles, build_pad=16,
-                   wire=None, rows=None):
+                   wire=None, rows=None, star_caps=None):
     """(core, abstract args) of the fused mesh program for `sql`'s cop DAG,
     built the way fusion.trace_fused_fragment builds it, but over `mesh`
     and with ShapeDtypeStructs carrying NamedShardings: columns in their
     production wire dtypes, NULL-free columns without a validity array.
     `rows` stands in for the table's base rows where they decide the
-    program (a sum that can pass int64 over them)."""
+    program (a sum that can pass int64 over them); `star_caps` a star
+    aggregate's (code table sizes, groups)."""
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -132,7 +133,10 @@ def _fragment_args(sess, table, sql, mesh, n_tiles, build_pad=16,
         datas.append(sds((n_tiles, PROD_TILE), dt, sharded))
         valids.append(None)
     pargs = []
-    for lk in an.lookups:
+    if an.agg is not None and an.agg_mode == "star":
+        # one code table a lookup
+        pargs = [sds((cap,), np.int32) for cap in star_caps[0]]
+    for lk in () if pargs else an.lookups:
         # where the groups are the build rows the keys alone are sent,
         # in 32 bits beside a probe key cached that narrow
         fd = par._fd_lookup(an)
@@ -152,7 +156,8 @@ def _fragment_args(sess, table, sql, mesh, n_tiles, build_pad=16,
     ints = par._bounds_args([], scalars)
     core = par._build_mesh_core(
         an, kind, col_order, mesh, tiles_per_shard=n_tiles // S,
-        hoisted=hoisted and (len(hoisted[0]), len(hoisted[1])))
+        hoisted=hoisted and (len(hoisted[0]), len(hoisted[1])),
+        star_caps=star_caps)
     args = (tuple(datas), tuple(valids),
             sds((n_tiles, PROD_TILE), np.bool_, sharded),
             sds(ints.shape, ints.dtype), ()) + tuple(pargs)
@@ -487,3 +492,53 @@ def test_one_chip_q3_join_programs_at_sf1_shapes(meshes, q3_bench):
     assert took_a < 20 and bytes_a < 20e9
     assert took_b < 15 and bytes_b < 9e9
     assert took_c < 170 and bytes_c < 15e9
+
+
+# ---------------------------------------------------------------------------
+# a star join of SSB at SF1 (configuration `ssb-sf1-star`)
+# ---------------------------------------------------------------------------
+
+#: SF1's wire dtypes of the fact columns SSB's Q2.1 reads
+SSB_WIRE = {"lo_orderdate": np.int32, "lo_custkey": np.int16,
+            "lo_partkey": np.int32, "lo_suppkey": np.int16,
+            "lo_revenue": np.int32}
+#: Q2.1's code tables at SF1 (the date keys' range of 61,130, 200,000
+#: parts, 2,000 suppliers, each padded to a power of two) and its 280
+#: groups, padded
+SSB_Q21_CAPS = ((1 << 16, 1 << 18, 1 << 11), 512)
+
+
+def test_star_join_program_compiles_for_one_v5e_chip(meshes):
+    """The fact scan of SSB's Q2.1 with its three lookups chained in one
+    program, at SF1's shapes: one gather a dimension and one sort of the
+    group ids for the aggregate."""
+    import json
+    import os
+    import sys
+
+    import jax
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    bench = os.path.join(root, "benchmarks")
+    sys.path.insert(0, bench)
+    try:
+        from harness import serve
+
+        config = json.load(open(os.path.join(bench, "configs",
+                                             "ssb-sf1-star.json")))
+        sql = json.load(open(os.path.join(bench, "queries",
+                                          "ssb21.json")))["sql"]
+        domain, _, _ = serve.load(config, 11, 0.001, lambda _n: None)
+    finally:
+        sys.path.remove(bench)
+    table = domain.storage.table(
+        domain.catalog.info_schema().table("test", "lineorder").id)
+    core, args = _fragment_args(domain.new_session(), table, sql,
+                                meshes[1], 8, wire=SSB_WIRE,
+                                star_caps=SSB_Q21_CAPS)
+    compiled, took, nbytes = _timed_compile(jax.jit(core), args)
+    assert compiled.as_text().count(" sort(") == 1
+    # an 8-core x86 host reads 43.8 s, 106.9 GB (the compiler counts a
+    # gather's whole operand a row)
+    print(f"star {took:.1f}s {nbytes / 1e9:.3f}GB")
+    assert took < 150

@@ -954,6 +954,48 @@ def lookup_group_sums(bkeys, keys, live, values):
     return rows, out
 
 
+def star_group_sums(gid, live, values, G: int, nulls: bool):
+    """Counts and exact sums of the live rows by group, for group ids in
+    [0, G) (a star's: each row's codes from its dimensions, combined
+    mixed-radix).  One sort of the ids with the summed values riding it,
+    then running sums read at the groups' boundaries, which a binary
+    search of the G + 1 ids in the sorted ids finds: nothing of the rows'
+    length is gathered or scattered.
+
+    `values` is one (data or None for count(*), valid) pair an
+    aggregate; sums must be integers (a difference of running sums is
+    exact modulo 2^64).  With `nulls` False no argument can be NULL and
+    every count is the group's rows; else the arguments' validity rides
+    the sort as bits of one int32.  Returns (rows a group, [(sum or
+    None, count) an aggregate]), each [G]."""
+    key = jnp.where(live, gid, G).astype(jnp.int32)
+    summed = [a for a, (d, _v) in enumerate(values) if d is not None]
+    operands = [key] + [jnp.where(live & values[a][1], values[a][0],
+                                  jnp.zeros((), values[a][0].dtype))
+                        for a in summed]
+    if nulls:
+        tag = jnp.zeros(key.shape, dtype=jnp.int32)
+        for a, (_d, v) in enumerate(values):
+            tag = tag | ((live & v).astype(jnp.int32) << a)
+        operands.append(tag)
+    skey, *sorted_ = jax.lax.sort(operands, num_keys=1)
+    pos = jnp.searchsorted(skey, jnp.arange(G + 1, dtype=jnp.int32))
+
+    def by_group(x):
+        run = jnp.concatenate([jnp.zeros(1, x.dtype), jnp.cumsum(x)])
+        return run[pos[1:]] - run[pos[:-1]]
+
+    rows = (pos[1:] - pos[:-1]).astype(jnp.int64)
+    out = []
+    for a in range(len(values)):
+        cnt = by_group((sorted_[-1] >> a) & 1).astype(jnp.int64) \
+            if nulls else rows
+        total = (by_group(sorted_[summed.index(a)]) if a in summed
+                 else None)
+        out.append((total, cnt))
+    return rows, out
+
+
 def grouped_partial_states(aggs, arg_fn, order, sm, seg, cap,
                            sgofs=None, n_global=0):
     """Segment-reduce per-group partial states for every aggregate over

@@ -441,6 +441,10 @@ class _Analyzed:
         self.agg_stats: dict = {}
         self.agg_rows = 0
         self.agg_wide: Optional[dict] = None
+        #: 'star' mode: the (lookup, payload slot) of each group key, and
+        #: whether an aggregate's argument can be NULL (`_star_groups`)
+        self.star_groups: Optional[List[Tuple[int, int]]] = None
+        self.star_nulls = False
         if self.agg is not None:
             width = len(self.scan.columns)
             for a in self.agg.aggs:
@@ -458,8 +462,12 @@ class _Analyzed:
                         # which has no payload columns
                         raise JaxUnsupported("first_row over join payload")
             try:
-                self._analyze_dense_keys(table)
-                self._bound_agg_args(table)
+                if self._star_groups(table):
+                    self.agg_mode = "star"
+                    self.num_groups = 0
+                else:
+                    self._analyze_dense_keys(table)
+                    self._bound_agg_args(table)
             except JaxUnsupported:
                 # high-NDV / float / NULLable / non-column keys: the mesh
                 # engine groups by sorting — keys only need to be
@@ -534,6 +542,47 @@ class _Analyzed:
             pack.append((e.index, int(lo), int(hi), int(slots),
                          bool(desc), bool(has_null)))
         self.topn_pack = pack
+
+    def _star_groups(self, table) -> bool:
+        """Whether the aggregate is a star's (`fusion.star_group_sums`):
+        two or more lookups, every group key a payload column (the host
+        makes each build side's group keys codes, so a dimension string
+        groups as its code), every aggregate a count or an integer sum of
+        the scanned columns that cannot pass int64 over the table's rows.
+        Sets `star_groups` and `star_nulls` where it is."""
+        from .fusion import _int_state, _passes_int64, _state_rescale
+
+        if len(self.lookups) < 2 or self.probes:
+            return False
+        width = len(self.scan.columns)
+        slots, off = {}, width
+        for j, lk in enumerate(self.lookups):
+            for p in range(len(lk.payload_ftypes)):
+                slots[off] = (j, p)
+                off += 1
+        groups = []
+        for g in self.agg.group_by:
+            if not isinstance(g, ColumnExpr) or g.index not in slots:
+                return False
+            groups.append(slots[g.index])
+        refs: set = set()
+        for a in self.agg.aggs:
+            if a.name not in ("count", "sum", "avg") or (
+                    a.name != "count" and not _int_state(a)):
+                return False
+            for x in a.args:
+                x.collect_columns(refs)
+        if any(i >= width for i in refs):
+            return False  # an argument from a build side's payload
+        self._bound_agg_args(table)
+        if any(_passes_int64(self, a.args[0], _state_rescale(
+                a.args[0], a.partial_types()[0]))
+               for a in self.agg.aggs if a.name != "count"):
+            return False
+        self.star_groups = groups
+        self.star_nulls = any(self.agg_bounds.get(i, (0, 0, True))[2]
+                              for i in refs)
+        return True
 
     def _analyze_dense_keys(self, table):
         g = 1
@@ -661,6 +710,8 @@ def _fingerprint(an: _Analyzed, kind: str) -> str:
                 for a in an.agg.aggs
             ],
         }
+        if an.agg_mode == "star":
+            payload["agg"]["star"] = [an.star_groups, an.star_nulls]
         if an.agg_mode == "dense":
             from .fusion import agg_lanes
 

@@ -44,7 +44,7 @@ from jax import shard_map
 from .. import serving as _serving
 from ..chunk import Chunk, Column
 from ..coord import CoordEpochMismatch
-from ..metrics import REGISTRY
+from ..metrics import REGISTRY, STAR_LOOKUPS
 from ..store.fault import FAILPOINTS
 from ..store.kv import CopRequest
 from ..types import TypeKind
@@ -1024,11 +1024,14 @@ def _apply_probes(an: _Analyzed, cols, m, pargs, counts, n_local: int):
 
 def _probe_specs(an: _Analyzed, hoisted=None):
     specs = [P()] * len(an.probes)
-    for lk in an.lookups:
-        # the keys; the payload too, unless the groups ARE the build
-        # rows and the host adds it to them (`_fd_lookup`)
-        specs += [P()] + ([] if _fd_lookup(an)
-                          else [P(), P()] * len(lk.payload_ftypes))
+    if an.agg is not None and an.agg_mode == "star":
+        specs += [P()] * len(an.lookups)  # one code table a lookup
+    else:
+        for lk in an.lookups:
+            # the keys; the payload too, unless the groups ARE the build
+            # rows and the host adds it to them (`_fd_lookup`)
+            specs += [P()] + ([] if _fd_lookup(an)
+                              else [P(), P()] * len(lk.payload_ftypes))
     if hoisted is not None and hoisted[1]:
         specs += [P()]  # the replicated pf parameter vector
     return tuple(specs)
@@ -1210,7 +1213,7 @@ def _mesh_in_specs(an: _Analyzed, hoisted, n_lvals: int = 0):
 
 def _build_mesh_core(an: _Analyzed, kind: str, col_order: List[int],
                      mesh: Mesh, tiles_per_shard: int,
-                     hoisted=None, col_layout=None):
+                     hoisted=None, col_layout=None, star_caps=None):
     """The raw shard_map'd whole-fragment program (pre-jit).
 
     One body per mesh: each shard flattens its local tiles to a
@@ -1243,6 +1246,10 @@ def _build_mesh_core(an: _Analyzed, kind: str, col_order: List[int],
                  else _build_sort_agg_core)
         return build(an, col_order, mesh, tiles_per_shard,
                      hoisted=hoisted, col_layout=col_layout)
+    if kind == "agg" and an.agg_mode == "star":
+        return _build_star_agg_core(an, col_order, mesh, tiles_per_shard,
+                                    star_caps, hoisted=hoisted,
+                                    col_layout=col_layout)
 
     view = _RowView(n_local, an, kind, col_layout)
 
@@ -1314,7 +1321,7 @@ def _build_mesh_core(an: _Analyzed, kind: str, col_order: List[int],
 
 def _build_mesh_fn(an: _Analyzed, kind: str, col_order: List[int],
                    mesh: Mesh, tiles_per_shard: int, name: str,
-                   hoisted=None, col_layout=None):
+                   hoisted=None, col_layout=None, star_caps=None):
     """One jitted shard_map program over the whole fragment.
 
     Inputs: datas [n_pad, TILE] x cols (cold columns: [n_pad,
@@ -1325,17 +1332,27 @@ def _build_mesh_fn(an: _Analyzed, kind: str, col_order: List[int],
     (probe key sets, lookup payloads, and a non-empty hoisted float64
     parameter vector).  Every range of a
     steady-state fragment runs in this ONE dispatch; intermediates never
-    leave HBM.  `name` is the jitted callable's (`_program_name`).
+    leave HBM.  `name` is the jitted callable's (`_program_name`);
+    `star_caps` a star aggregate's (table sizes, groups) (`_star_args`).
     """
     S = len(mesh.devices.ravel())
     n_local = tiles_per_shard * je.TILE
     core = _build_mesh_core(an, kind, col_order, mesh, tiles_per_shard,
-                            hoisted=hoisted, col_layout=col_layout)
+                            hoisted=hoisted, col_layout=col_layout,
+                            star_caps=star_caps)
 
     if kind == "agg" and an.agg_mode == "sort":
         if _fd_lookup(an):
             return _wrap_lookup_agg(an, core, mesh, S, name)
         return _wrap_sort_agg(an, core, mesh, S, n_local, name)
+    if kind == "agg" and an.agg_mode == "star":
+        packed = _packed_jit(core, mesh, name, join=True)
+
+        def star(*operands):
+            rows, states = packed(*_call_args(*operands))
+            return {"mode": "star", "rows": rows, "states": states}
+
+        return star
 
     if kind == "agg":
         from . import fusion
@@ -1579,6 +1596,164 @@ def _lookup_agg_chunks(out: dict, an: _Analyzed, aux: dict) -> List[Chunk]:
                 cols.append(Column(pts[1], c.astype(np.int64)))
         chunks.append(Chunk(cols))
     return chunks
+
+
+#: a star aggregate's group space, and a lookup's code table, at most:
+#: past them the fragment is not the mesh's (the per-region rung runs it)
+STAR_MAX_GROUPS = 1 << 16
+STAR_MAX_TABLE = 1 << 24
+
+
+def _build_star_agg_core(an: _Analyzed, col_order: List[int], mesh: Mesh,
+                         tiles_per_shard: int, star_caps, hoisted=None,
+                         col_layout=None):
+    """The shard_map'd core of a star's aggregate (`an.agg_mode` 'star'):
+    scan, selection, then each lookup as one gather from its dimension's
+    code table (`_star_args`: a direct table over the build keys' range,
+    a build row's group code times its stride, -1 where no build row
+    is), the codes summed into a mixed-radix group id, and
+    `fusion.star_group_sums` over the G groups; the shards' states add up
+    by psum.  A probe row with a miss in any table is dropped (the inner
+    joins)."""
+    from ..expr.expression import ColumnExpr
+    from . import fusion
+
+    S = len(mesh.devices.ravel())
+    n_local = tiles_per_shard * je.TILE
+    n_lvals = sum(1 for c in (col_layout or ()) if c is not None)
+    view = _RowView(n_local)
+    caps, G = star_caps
+
+    def shard_fn(datas, valids, del_mask, ints, lvals, *pargs):
+        bases, pargs, params = _split_operands(an, ints, pargs, hoisted)
+        cols = _cols_env(an, col_order, datas, valids, view, params,
+                         col_layout=col_layout, lvals=lvals)
+        gofs, m = _mesh_masks(del_mask, ints, view)
+        ctx = fusion.RegionContext(an=an, cols=cols, n=n_local, mask=m,
+                                   axis="dp", gofs=gofs,
+                                   n_global=S * n_local)
+        fusion.selection_mask(ctx)
+        live = ctx.mask
+        gid = jnp.zeros(n_local, dtype=jnp.int32)
+        for j, lk in enumerate(an.lookups):
+            d, v = compile_expr(lk.key, cols, n_local)
+            # the offset into the table in 32 bits where the key's column
+            # came that narrow: `_star_args` keeps a base within 2^30 of
+            # 0, so a key of the int32 range that wraps lands far past
+            # the table, never inside it
+            wire = (cols["__wire__"].get(lk.key.index)
+                    if isinstance(lk.key, ColumnExpr) else None)
+            if wire is not None and wire.dtype.itemsize <= 4:
+                at = wire.astype(jnp.int32) - bases[j].astype(jnp.int32)
+            else:
+                at = d.astype(jnp.int64) - bases[j]
+            inside = v & (at >= 0) & (at < caps[j])
+            code = jnp.where(inside, pargs[j][jnp.where(inside, at, 0)], -1)
+            live = live & (code >= 0)
+            gid = gid + code
+        values = []
+        for a in an.agg.aggs:
+            if not a.args:
+                values.append((None, jnp.ones(n_local, dtype=jnp.bool_)))
+                continue
+            d, v = compile_expr(a.args[0], cols, n_local)
+            values.append((None if a.name == "count" else _to_state_dtype(
+                d, a.args[0].ftype, a.partial_types()[0]), v))
+        rows, states = fusion.star_group_sums(gid, live, values, G,
+                                              an.star_nulls)
+        return (jax.lax.psum(rows, "dp"),
+                tuple(jax.lax.psum(c, "dp") if t is None
+                      else (jax.lax.psum(t, "dp"), jax.lax.psum(c, "dp"))
+                      for t, c in states))
+
+    out_specs = (P(), tuple(P() if a.name == "count" else (P(), P())
+                            for a in an.agg.aggs))
+    return _shard_map_norep(shard_fn, mesh,
+                            _mesh_in_specs(an, hoisted, n_lvals), out_specs)
+
+
+def _star_args(an: _Analyzed, aux: dict):
+    """What a star's program takes for this statement, from the build
+    sides the host holds: for each lookup its code table (a build row's
+    group code, the index of its group keys' values among the distinct
+    ones of its side, times the stride of its side in the mixed-radix
+    group id; -1 where the range holds no build key) and its keys' base,
+    the int32 width a table and the group count are padded to (so the
+    program compiles once a class), and what `_star_agg_chunks` decodes
+    the group ids with.  None, with the reason, where the group space or
+    a key range is past what the program takes."""
+    from ..trace import span
+    from .aggstate import group_indices
+
+    stride, tables, bases, decode = 1, [], [], []
+    for j, lk in enumerate(an.lookups):
+        fid = lk.filter_id
+        keys = aux[f"probe_keys_{fid}"]
+        payload = aux[f"payload_{fid}"]
+        pvalids = aux.get(f"payload_valid_{fid}") or [None] * len(payload)
+        with span("star.build", dim=aux.get(f"dim_{fid}", fid),
+                  phase="codes", rows=len(keys)) as sp:
+            slots = [p for jj, p in an.star_groups if jj == j]
+            if slots and len(keys):
+                gcols = [Column(lk.payload_ftypes[p], payload[p],
+                                pvalids[p]) for p in slots]
+                gidx, first, card = group_indices(gcols)
+                kept = {p: (c.data[first], c.validity()[first])
+                        for p, c in zip(slots, gcols)}
+            else:
+                gidx, card, kept = np.zeros(len(keys), np.int64), 1, {}
+            lo = int(keys[0]) if len(keys) else 0
+            span_ = int(keys[-1]) - lo + 1 if len(keys) else 1
+            if stride * card > STAR_MAX_GROUPS or span_ > STAR_MAX_TABLE \
+                    or not -(1 << 30) <= lo < 1 << 30:
+                return None, (f"star lookup {fid}: {card} groups, a key "
+                              f"range of {span_} from {lo}")
+            cap = 16
+            while cap < span_:
+                cap <<= 1
+            table = np.full(cap, -1, dtype=np.int32)
+            table[keys - lo] = gidx * stride
+            sp.set(bytes=table.nbytes, groups=int(card))
+        tables.append(table)
+        bases.append(lo)
+        decode.append((stride, card, kept))
+        stride *= card
+    G = 16
+    while G < stride:
+        G <<= 1
+    return {"tables": tables, "bases": bases, "decode": decode,
+            "dims": [aux.get(f"dim_{lk.filter_id}", lk.filter_id)
+                     for lk in an.lookups],
+            "caps": (tuple(len(t) for t in tables), G)}, None
+
+
+def _star_agg_chunks(out: dict, an: _Analyzed, decode) -> List[Chunk]:
+    """The groups a star's program found (`rows` > 0; a scalar aggregate's
+    one group whatever its rows) -> one partial chunk [keys...,
+    states...], each key decoded from its group id: the code of its side
+    (the id over the side's stride, modulo its count) indexes the
+    distinct values `_star_args` kept."""
+    rows = out["rows"]
+    sel = (np.flatnonzero(rows) if an.agg.group_by
+           else np.zeros(1, dtype=np.int64))
+    if not len(sel):
+        return []
+    cols: List[Column] = []
+    for g, (j, p) in zip(an.agg.group_by, an.star_groups):
+        stride, card, kept = decode[j]
+        data, valid = kept[p]
+        code = (sel // stride) % card
+        cols.append(Column(g.ftype, data[code], valid[code]))
+    for a, st in zip(an.agg.aggs, out["states"]):
+        pts = a.partial_types()
+        if a.name == "count":
+            cols.append(Column(pts[0], st[sel].astype(np.int64)))
+            continue
+        total, c = st[0][sel], st[1][sel]
+        cols.append(Column(pts[0], total.astype(pts[0].np_dtype), c > 0))
+        if a.name == "avg":
+            cols.append(Column(pts[1], c.astype(np.int64)))
+    return [Chunk(cols)]
 
 
 def _build_sort_agg_core(an: _Analyzed, col_order: List[int], mesh: Mesh,
@@ -2292,6 +2467,7 @@ def _run_mesh_once(storage, req: CopRequest, tid: int,
 
     if an.lookups:
         annotate(join=len(an.lookups))  # on `distsql.fanout`
+    star = an.agg is not None and an.agg_mode == "star"
     for lk in an.lookups:
         arr = (req.aux or {}).get(f"probe_keys_{lk.filter_id}")
         payload = (req.aux or {}).get(f"payload_{lk.filter_id}")
@@ -2302,6 +2478,13 @@ def _run_mesh_once(storage, req: CopRequest, tid: int,
             raise ExecutorError(f"missing join lookup aux {lk.filter_id}")
         if lk.key.ftype.kind == TypeKind.FLOAT:
             req.mesh_reject_reason = "float lookup key"
+            return None
+        if star:
+            continue  # the code tables below carry every lookup
+        if any(ft.kind == TypeKind.STRING for ft in lk.payload_ftypes):
+            # a dimension string rides as a code only in a star's
+            # aggregate; the per-region path carries the strings
+            req.mesh_reject_reason = "string lookup payload outside a star"
             return None
         k = len(arr)
         kpad = 16
@@ -2333,6 +2516,18 @@ def _run_mesh_once(storage, req: CopRequest, tid: int,
             sp.set(bytes=sum(a.nbytes for a in sent))
         counts.append(k)
         kpads.append((kpad, kdt.name) if fd else kpad)
+    if star:
+        star, why = _star_args(an, req.aux)
+        if star is None:
+            req.mesh_reject_reason = why
+            return None
+        for fid, t in zip(star["dims"], star["tables"]):
+            with span("star.build", dim=req.aux.get(f"dim_{fid}", fid),
+                      phase="upload",
+                      rows=len(t), bytes=t.nbytes):
+                pargs.append(jnp.asarray(t))
+        counts += star["bases"]
+        kpads.append(("star",) + star["caps"])
 
     # column arrays load BEFORE the program lookup: the compiled program
     # is specialized on each column's wire dtype/null pattern AND its
@@ -2367,7 +2562,8 @@ def _run_mesh_once(storage, req: CopRequest, tid: int,
                                 prep.layout[2], _program_name(kind, fp),
                                 hoisted=(None if hoisted is None else
                                          (len(hoisted[0]), len(hoisted[1]))),
-                                col_layout=col_layout)
+                                col_layout=col_layout,
+                                star_caps=star and star["caps"])
             _COMPILED.put(fp, fn)
             # label this query's FIRST dispatch as the compile: jit compiles
             # lazily, so the program-cache miss pays XLA compilation there
@@ -2428,7 +2624,10 @@ def _run_mesh_once(storage, req: CopRequest, tid: int,
     out = None
     if bounds:
         out = _dispatch_once(kind, fn, datas, valids, del_mask, bounds,
-                             lvals, pargs, scalars, mesh_ids)
+                             lvals, pargs, scalars, mesh_ids,
+                             lookups=len(an.lookups))
+        if star:
+            out["decode"] = star["decode"]
     try:
         with span("mesh.result") as rsp:
             chunks = _mesh_result(req, dag, table, an, kind, out, inserted,
@@ -2468,6 +2667,8 @@ def _mesh_result(req, dag, table, an: _Analyzed, kind: str, out, inserted,
         chunks.extend(_lookup_agg_chunks(out, an, req.aux))
     elif kind == "agg" and an.agg_mode == "sort":
         chunks.extend(_sort_agg_chunks(out, table, an))
+    elif kind == "agg" and an.agg_mode == "star":
+        chunks.extend(_star_agg_chunks(out, an, out["decode"]))
     elif kind == "agg":
         # wrapped() already unpacked to numpy and merged shard partials
         chunks.append(je._device_agg_to_chunk(
@@ -2506,7 +2707,7 @@ def _mesh_result(req, dag, table, an: _Analyzed, kind: str, out, inserted,
 
 
 def _dispatch_once(kind, fn, datas, valids, del_mask, bounds, lvals, pargs,
-                   scalars, mesh_ids):
+                   scalars, mesh_ids, lookups: int = 0):
     """The ONE dispatch of a mesh statement: the compiled program over
     all of the statement's range slots, between two cancellation seams.
     A dispatch in flight runs to completion and costs one pass over the
@@ -2526,8 +2727,10 @@ def _dispatch_once(kind, fn, datas, valids, del_mask, bounds, lvals, pargs,
     _check_membership_epoch()
     FAILPOINTS.hit("copr/chunk_dispatch", kind=kind, chunk=0, total=1,
                    start=start, end=end)
+    if lookups:
+        REGISTRY.inc(STAR_LOOKUPS, float(lookups))
     with span("copr.chunk", kind=kind, devices=len(mesh_ids),
-              rows=sum(hi - lo for lo, hi in bounds)):
+              rows=sum(hi - lo for lo, hi in bounds), lookups=lookups):
         # resource-group admission per dispatch: a depleted group
         # waits here, and the dispatch's device time is its charge
         with dispatch_admission(DISPATCH_LOCK):

@@ -341,54 +341,72 @@ class UnionScanExec(Executor):
 
 
 class DeviceJoinReaderExec(Executor):
-    """Broadcast lookup join completed inside the cop task: drain the
-    (small, unique-key) build side, ship its sorted keys + payload columns
-    to the probe reader's device DAG (JoinLookupIR), then stream the
-    reader's joined/aggregated chunks.
+    """Broadcast lookup joins completed inside the cop task: drain each
+    (small, unique-key) build side, ship its sorted keys + payload
+    columns to the probe reader's device DAG (one JoinLookupIR each),
+    then stream the reader's joined/aggregated chunks.
 
     The role of the reference's HashJoinExec build phase + probe worker
     pool (executor/join.go:232-414), but the probe+join+partial-agg all
     execute in the device shard program; only aggregated partials return.
     Build-key uniqueness is guaranteed at plan time
-    (planner/physical.py _build_key_unique)."""
+    (planner/physical.py _build_key_unique).  `builds` holds one
+    (executor, key position, payload positions, filter id, dimension
+    name) a build side: one is a lookup join, several a star."""
 
-    def __init__(self, ctx: ExecContext, reader: Executor, build: Executor,
-                 build_key_pos: int, payload_pos: List[int],
-                 filter_id: int = 0, plan_id: int = -1):
-        super().__init__(ctx, reader.ftypes, [build, reader], plan_id)
+    def __init__(self, ctx: ExecContext, reader: Executor, builds: list,
+                 plan_id: int = -1):
+        super().__init__(ctx, reader.ftypes, [b[0] for b in builds]
+                         + [reader], plan_id)
         self.reader = reader
-        self.build = build
-        self.build_key_pos = build_key_pos
-        self.payload_pos = payload_pos
-        self.filter_id = filter_id
+        self.builds = builds
 
     def open(self):
-        from ..copr.ir import key_bits_int64
-        from ..chunk import concat_chunks
-        from ..errors import ExecutorError
+        aux = {}
+        for build, key_pos, payload_pos, fid, dim in self.builds:
+            keys, payload, pvalid = self._build_side(
+                build, key_pos, payload_pos, dim)
+            aux[f"probe_keys_{fid}"] = np.ascontiguousarray(
+                keys, dtype=np.int64)
+            aux[f"payload_{fid}"] = payload
+            aux[f"payload_valid_{fid}"] = pvalid
+            aux[f"dim_{fid}"] = dim
+        self.reader.set_runtime_aux(aux)
+        self.reader.open()
+        self._opened = True
 
-        self.build.open()
+    def _build_side(self, build: Executor, key_pos: int, payload_pos,
+                    dim: str):
+        """The build side's rows, drained, as (sorted keys, payload
+        columns and their validity in the keys' order).  A plain lookup
+        join's host work is the `join.build` span (phase "sort"; its
+        upload is in the mesh dispatch, copr/parallel.py); a star's is
+        `star.build`, one a dimension."""
+        from ..chunk import concat_chunks
+        from ..copr.ir import key_bits_int64
+        from ..errors import ExecutorError
+        from ..trace import span
+
+        build.open()
         chunks = []
         while True:
-            c = self.build.next()
+            c = build.next()
             if c is None:
                 break
             if c.num_rows:
                 chunks.append(c)
-        self.build.close()
-        from ..trace import span
-
-        # the build side's host work between the rows it drained and the
-        # probe program: sort here, upload in the mesh dispatch
-        # (`join.build` phase="upload", copr/parallel.py)
-        with span("join.build", phase="sort") as sp:
+        build.close()
+        star = len(self.builds) > 1
+        attrs = {"dim": dim} if star else {}
+        with span("star.build" if star else "join.build", phase="sort",
+                  **attrs) as sp:
             if chunks:
                 built = concat_chunks(chunks)
-                kcol = built.col(self.build_key_pos)
+                kcol = built.col(key_pos)
                 valid = kcol.validity()
                 if not valid.all():
                     built = built.filter(valid)  # NULL keys never match
-                    kcol = built.col(self.build_key_pos)
+                    kcol = built.col(key_pos)
                 bits = key_bits_int64(kcol.data)
                 order = np.argsort(bits, kind="stable")
                 keys = bits[order]
@@ -397,34 +415,28 @@ class DeviceJoinReaderExec(Executor):
                         "device join: build keys not unique (planner "
                         "uniqueness inference violated)")
                 payload, pvalid = [], []
-                for pos in self.payload_pos:
+                for pos in payload_pos:
                     col = built.col(pos)
                     payload.append(col.data[order])
                     v = col.validity()
                     pvalid.append(None if v.all() else v[order])
             else:
                 keys = np.zeros(0, dtype=np.int64)
-                payload = [np.zeros(0, dtype=np.int64)
-                           for _ in self.payload_pos]
-                pvalid = [None for _ in self.payload_pos]
+                payload = [np.zeros(0, dtype=ft.np_dtype) for ft in
+                           (build.ftypes[p] for p in payload_pos)]
+                pvalid = [None for _ in payload_pos]
             sp.set(rows=len(keys),
                    bytes=keys.nbytes + sum(p.nbytes for p in payload))
-        fid = self.filter_id
-        self.reader.set_runtime_aux({
-            f"probe_keys_{fid}": np.ascontiguousarray(keys, dtype=np.int64),
-            f"payload_{fid}": payload,
-            f"payload_valid_{fid}": pvalid,
-        })
-        self.reader.open()
-        self._opened = True
+        return keys, payload, pvalid
 
     def _next(self):
         return self.reader.next()
 
     def close(self):
-        try:
-            self.build.close()  # no-op when already closed after the drain
-        except Exception:
-            pass
+        for b in self.builds:
+            try:
+                b[0].close()  # no-op when already closed after the drain
+            except Exception:
+                pass
         self.reader.close()
         self._opened = False

@@ -168,7 +168,7 @@ def _reader_dags(phys):
             out.append((p, dag))
         for c in getattr(p, "children", ()) or ():
             walk(c)
-        for attr in ("reader", "build_plan", "select_phys"):
+        for attr in ("reader", "select_phys"):
             walk(getattr(p, attr, None))
 
     walk(phys)

@@ -219,31 +219,38 @@ class _Checker:
         from ..copr.ir import JoinLookupIR
 
         self.check(p.reader)
-        build_fts = p.build_plan.schema.ftypes()
-        if not (0 <= p.build_key_pos < len(build_fts)):
-            self.fail(p, f"build_key_pos {p.build_key_pos} out of range "
-                         f"for build schema width {len(build_fts)}")
-        for pos in p.payload_pos:
-            if not (0 <= pos < len(build_fts)):
-                self.fail(p, f"payload pos {pos} out of range for build "
-                             f"schema width {len(build_fts)}")
-        lookups = [ex for ex in p.reader.dag.executors
-                   if isinstance(ex, JoinLookupIR)]
+        lookups = {ex.filter_id: ex for ex in p.reader.dag.executors
+                   if isinstance(ex, JoinLookupIR)}
         if not lookups:
             self.fail(p, "device join reader DAG carries no JoinLookupIR")
             return
-        lk = lookups[0]
-        if len(lk.payload_ftypes) != len(p.payload_pos):
-            self.fail(p, f"JoinLookupIR ships {len(lk.payload_ftypes)} "
-                         f"payload cols but the build plan provides "
-                         f"{len(p.payload_pos)}")
+        if sorted(lookups) != sorted(b.filter_id for b in p.builds):
+            self.fail(p, f"JoinLookupIR filter ids {sorted(lookups)} != "
+                         f"build sides {[b.filter_id for b in p.builds]}")
             return
-        for j, pos in enumerate(p.payload_pos):
-            if pos < len(build_fts) and not _kinds_ok(
-                    lk.payload_ftypes[j], build_fts[pos]):
-                self.fail(p, f"payload col {j} typed "
-                             f"{lk.payload_ftypes[j].kind.name} but build "
-                             f"schema col is {build_fts[pos].kind.name}")
+        for b in p.builds:
+            build_fts = b.plan.schema.ftypes()
+            if not (0 <= b.key_pos < len(build_fts)):
+                self.fail(p, f"build key_pos {b.key_pos} out of range "
+                             f"for build schema width {len(build_fts)}")
+            for pos in b.payload_pos:
+                if not (0 <= pos < len(build_fts)):
+                    self.fail(p, f"payload pos {pos} out of range for "
+                                 f"build schema width {len(build_fts)}")
+            lk = lookups[b.filter_id]
+            if len(lk.payload_ftypes) != len(b.payload_pos):
+                self.fail(p, f"JoinLookupIR ships "
+                             f"{len(lk.payload_ftypes)} payload cols but "
+                             f"the build plan provides "
+                             f"{len(b.payload_pos)}")
+                continue
+            for j, pos in enumerate(b.payload_pos):
+                if pos < len(build_fts) and not _kinds_ok(
+                        lk.payload_ftypes[j], build_fts[pos]):
+                    self.fail(p, f"payload col {j} typed "
+                                 f"{lk.payload_ftypes[j].kind.name} but "
+                                 f"build schema col is "
+                                 f"{build_fts[pos].kind.name}")
 
     # the sender IS a table reader (schema == cop DAG output); receivers
     # are pass-through markers

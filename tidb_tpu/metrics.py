@@ -251,6 +251,10 @@ def merge_fleet(snapshots: Dict[int, dict]) -> dict:
 
 REGISTRY = Registry()
 
+#: lookup joins chained inside the mesh programs dispatched (one a
+#: lookup a dispatch: a star's fact scan counts each of its dimensions)
+STAR_LOOKUPS = "star_lookups_total"
+
 #: statement classes carrying per-class end-to-end latency histograms
 #: (`stmt_latency_<class>_ms`) and SLO threshold sysvars
 #: (`tidb_tpu_slo_<class>_ms`) with error-budget burn counters

@@ -763,6 +763,12 @@ def _run_tree_once(storage, spec: MPPJoinTreeSpec, modes: List[str],
               f"|b:{_fingerprint(bs.an, 'filter')}|Tl={bs.Tl}"
               f"|k={rung.build_key_pos}|wire={bs.wire_sig}"
               f"|oc={conds_sig}")
+        # the next rung's input is this rung's output, whose slots every
+        # earlier rung's build side shaped: each fingerprint carries the
+        # whole ladder up to it (two trees that differ only in an
+        # earlier build side's columns once shared a later rung's program
+        # and failed on its operand count)
+        base_fp = fp
         fn = _COMPILED.get(fp)
         if fn is None:
             fn = _build_rung_fn(spec, r, states, mesh, mode, n_in,

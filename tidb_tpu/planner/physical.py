@@ -187,37 +187,52 @@ class PhysTableReader(PhysicalPlan):
         return lines
 
 
+@dataclass
+class DeviceJoinBuild:
+    """One build side of a device lookup join: its plan, where its key
+    and its payload columns sit in the plan's output, the cop DAG's
+    `JoinLookupIR.filter_id` it feeds, and the dimension's table name
+    (trace attributes)."""
+
+    plan: PhysicalPlan
+    key_pos: int
+    payload_pos: List[int]
+    filter_id: int = 0
+    dim: str = ""
+
+
 class PhysDeviceJoinReader(PhysicalPlan):
-    """Broadcast lookup join pushed into the cop task: the build subplan
-    runs root-side first, its sorted unique keys + payload columns ship to
-    every mesh shard, and the probe table's device DAG completes
-    scan -> filter -> JOIN -> partial aggregation on chip
+    """Broadcast lookup joins pushed into the cop task: each build
+    subplan runs root-side first, its sorted unique keys + payload
+    columns ship to every mesh shard, and the probe table's device DAG
+    completes scan -> filter -> JOIN x k -> partial aggregation on chip
     (copr/ir.py JoinLookupIR; the reference's executor/join.go HashJoin
-    role, relocated into the coprocessor)."""
+    role, relocated into the coprocessor).  One build is a plain lookup
+    join; several are a star: a fact table's lookups into all of its
+    dimensions, chained in one program."""
 
     def __init__(self, schema: Schema, reader: PhysTableReader,
-                 build: PhysicalPlan, build_key_pos: int,
-                 payload_pos: List[int], filter_id: int = 0):
-        super().__init__(schema, [build])
+                 builds: List[DeviceJoinBuild]):
+        super().__init__(schema, [b.plan for b in builds])
         self.reader = reader
-        self.build_plan = build
-        self.build_key_pos = build_key_pos
-        self.payload_pos = payload_pos
-        self.filter_id = filter_id
+        self.builds = builds
 
     def task(self) -> str:
         return "root"
 
     def info(self) -> str:
-        return (f"build key @{self.build_key_pos}, "
-                f"payload cols {self.payload_pos} -> cop join")
+        return "; ".join(
+            f"build {b.dim or b.filter_id}: key @{b.key_pos}, "
+            f"payload cols {b.payload_pos}" for b in self.builds) \
+            + " -> cop join"
 
     def build(self, ctx):
         from ..executor.readers import DeviceJoinReaderExec
 
         return DeviceJoinReaderExec(
-            ctx, self.reader.build(ctx), self.build_plan.build(ctx),
-            self.build_key_pos, self.payload_pos, self.filter_id, self.id)
+            ctx, self.reader.build(ctx),
+            [(b.plan.build(ctx), b.key_pos, b.payload_pos, b.filter_id,
+              b.dim) for b in self.builds], self.id)
 
     def explain_tree(self, indent: int = 0, lines=None):
         lines = lines if lines is not None else []
@@ -225,7 +240,8 @@ class PhysDeviceJoinReader(PhysicalPlan):
         lines.append((f"{pad}{self.name}_{self.id}", self._est_str(), "root",
                       self.info()))
         self.reader.explain_tree(indent + 1, lines)
-        self.build_plan.explain_tree(indent + 1, lines)
+        for b in self.builds:
+            b.plan.explain_tree(indent + 1, lines)
         return lines
 
 
@@ -1434,6 +1450,9 @@ def _physical_agg(plan: LogicalAggregation,
         dj = _try_device_join_agg(plan, child_l, pctx)
         if dj is not None:
             return dj
+        sj = _try_star_join_agg(plan, child_l, pctx)
+        if sj is not None:
+            return sj
         mj = _try_mpp_join_agg(plan, child_l, pctx)
         if mj is not None:
             return mj
@@ -1463,6 +1482,9 @@ DEVICE_JOIN_BUILD_MAX = 1 << 23
 _DJ_KEY_KINDS = (TypeKind.INT, TypeKind.UINT, TypeKind.DECIMAL,
                  TypeKind.DATE)
 _DJ_PAYLOAD_KINDS = _DJ_KEY_KINDS + (TypeKind.FLOAT, TypeKind.BOOL)
+#: a star's payload: a dimension's string rides its lookup as a code
+#: (copr/parallel.py `_star_args`)
+_STAR_PAYLOAD_KINDS = _DJ_PAYLOAD_KINDS + (TypeKind.STRING,)
 
 
 def _build_key_unique(plan, uid: int) -> bool:
@@ -1513,6 +1535,36 @@ def _build_key_unique(plan, uid: int) -> bool:
     return False
 
 
+def _dj_keys_ok(pk_e, bk_e) -> bool:
+    """A lookup join's key pair: an int-domain probe key and a build
+    column of the same kind (and scale: both sides must share the
+    scaled-int comparison domain)."""
+    return (isinstance(bk_e, ColumnExpr) and bk_e.unique_id >= 0
+            and pk_e.ftype.kind in _DJ_KEY_KINDS
+            and bk_e.ftype.kind == pk_e.ftype.kind
+            and (pk_e.ftype.kind != TypeKind.DECIMAL
+                 or bk_e.ftype.scale == pk_e.ftype.scale))
+
+
+def _dj_reader_agg(plan: LogicalAggregation, task, probe_l, gb, aggs,
+                   builds: List["DeviceJoinBuild"]):
+    """The plan a device lookup join's aggregate becomes once its cop
+    DAG holds the lookups: the partial aggregation appended, the reader,
+    the DeviceJoinReader over it and the build sides, and the final
+    HashAgg over their partial rows ([keys..., states...] by position)."""
+    task.dag_execs.append(AggregationIR(gb, aggs, mode="partial"))
+    # first_row partials are position-sensitive: region chunks must
+    # merge in handle order (same invariant as the direct agg pushdown
+    # path) or "first" depends on task completion order
+    has_first = any(a.name == "first_row" for a in aggs)
+    reader = PhysTableReader(_partial_schema(plan), task,
+                             keep_order=has_first, ranges=probe_l.ranges)
+    djr = PhysDeviceJoinReader(reader.schema, reader, builds)
+    fin_gb = [ColumnExpr(i, g.ftype, str(g), -1)
+              for i, g in enumerate(plan.group_by)]
+    return PhysHashAgg(djr, fin_gb, plan.aggs, True, plan.schema)
+
+
 def _try_device_join_agg(plan: LogicalAggregation, join: LogicalJoin,
                          pctx: PhysicalContext):
     """Agg(InnerJoin(probe datasource, small unique-key build)) ->
@@ -1535,15 +1587,7 @@ def _try_device_join_agg(plan: LogicalAggregation, join: LogicalJoin,
             (left, right, le, re_), (right, left, re_, le)):
         if not isinstance(probe_l, LogicalDataSource):
             continue
-        if not isinstance(bk_e, ColumnExpr) or bk_e.unique_id < 0:
-            continue
-        if pk_e.ftype.kind not in _DJ_KEY_KINDS:
-            continue
-        # both key sides must share the scaled-int comparison domain
-        if bk_e.ftype.kind != pk_e.ftype.kind:
-            continue
-        if pk_e.ftype.kind == TypeKind.DECIMAL \
-                and bk_e.ftype.scale != pk_e.ftype.scale:
+        if not _dj_keys_ok(pk_e, bk_e):
             continue
         if not _build_key_unique(build_l, bk_e.unique_id):
             continue
@@ -1604,21 +1648,138 @@ def _try_device_join_agg(plan: LogicalAggregation, join: LogicalJoin,
         pk_pos = pk_e.remap_columns(task.scan_pos_map())
         task.dag_execs.append(JoinLookupIR(
             pk_pos, 0, [c.ftype for c in payload_cols]))
-        task.dag_execs.append(AggregationIR(gb, aggs, mode="partial"))
-        # first_row partials are position-sensitive: region chunks must
-        # merge in handle order (same invariant as the direct agg
-        # pushdown path) or "first" depends on task completion order
-        has_first = any(a.name == "first_row" for a in aggs)
-        reader = PhysTableReader(_partial_schema(plan), task,
-                                 keep_order=has_first,
-                                 ranges=probe_l.ranges)
-        djr = PhysDeviceJoinReader(
-            reader.schema, reader, build_phys,
-            build_pos[bk_e.unique_id],
-            [build_pos[u] for u in payload_uids])
-        fin_gb = [ColumnExpr(i, g.ftype, str(g), -1)
-                  for i, g in enumerate(plan.group_by)]
-        return PhysHashAgg(djr, fin_gb, plan.aggs, True, plan.schema)
+        return _dj_reader_agg(plan, task, probe_l, gb, aggs, [
+            DeviceJoinBuild(build_phys, build_pos[bk_e.unique_id],
+                            [build_pos[u] for u in payload_uids])])
+    return None
+
+
+def _star_parts(join: LogicalJoin):
+    """A star: inner equi-joins of data sources, and no other condition,
+    in which ONE source (the probe) meets each of the others in exactly
+    one equality, probe column against a unique column of that other
+    source.  Returns [(probe, [(build, probe key, build key), ...]), ...]
+    for each source that can be the probe, largest estimate first; []
+    where the join is not a star of three or more sources."""
+    from .jointree import _collect as collect_join
+
+    col = collect_join(join)
+    if col is None or col.filters or col.others \
+            or len(col.members) < 3 \
+            or len(col.eqs) != len(col.members) - 1:
+        return []
+
+    def owner(e):
+        if not isinstance(e, ColumnExpr) or e.unique_id < 0:
+            return None
+        return next((m for m in col.members
+                     if any(c.uid == e.unique_id for c in m.schema.cols)),
+                    None)
+
+    out = []
+    for probe in col.members:
+        builds, seen = [], set()
+        for le, re_ in col.eqs:
+            lo, ro = owner(le), owner(re_)
+            if lo is probe and ro is not None and ro is not probe:
+                b, pk, bk = ro, le, re_
+            elif ro is probe and lo is not None and lo is not probe:
+                b, pk, bk = lo, re_, le
+            else:
+                break
+            if id(b) in seen:
+                break
+            seen.add(id(b))
+            builds.append((b, pk, bk))
+        else:
+            if len(builds) == len(col.members) - 1:
+                out.append((probe, builds))
+    return out
+
+
+def _try_star_join_agg(plan: LogicalAggregation, join: LogicalJoin,
+                       pctx: PhysicalContext):
+    """Agg(a star of inner joins: one probe data source and k >= 2
+    filtered dimensions, each joined on its own plan-time-unique key) ->
+    final agg over ONE DeviceJoinReader whose cop DAG is
+    scan -> selection -> JoinLookupIR x k -> partial AggregationIR.
+    A dimension's filter stays on its build side: a key the filter
+    removes is a miss, and the inner join drops the probe row.  The
+    gates of `_try_device_join_agg` hold per build side.  None whenever
+    a gate fails (the MPP paths take over)."""
+    from ..copr.ir import JoinLookupIR
+
+    if not plan.aggs or pctx.prefer_merge_join or pctx.enforce_mpp:
+        return None
+    refs: set = set()
+    for g in plan.group_by:
+        g.collect_columns(refs)
+    for a in plan.aggs:
+        for x in a.args:
+            x.collect_columns(refs)
+    for probe_l, sides in _star_parts(join):
+        if not all(_dj_keys_ok(pk, bk) and _build_key_unique(b, bk.unique_id)
+                   for b, pk, bk in sides):
+            continue
+        task, residual = _start_cop(probe_l, pctx)
+        if task is None or residual or task.ranges == [] \
+                or any(not isinstance(ex, SelectionIR)
+                       for ex in task.dag_execs):
+            continue
+        dict_uids = _dict_uids(probe_l, pctx)
+        if not all(can_push_expr(pk, pctx.pushdown_blacklist, dict_uids)
+                   for _b, pk, _bk in sides):
+            continue
+        probe_uids = {c.uid for c in probe_l.schema.cols}
+        payloads = []  # per build: (schema positions, payload uids)
+        for b, _pk, _bk in sides:
+            pos = {c.uid: i for i, c in enumerate(b.schema.cols)}
+            payloads.append((pos, sorted(u for u in refs if u in pos)))
+        if any(u not in probe_uids and not any(u in pos
+                                               for pos, _ in payloads)
+               for u in refs):
+            continue  # references something outside the join
+        if any(b.schema.cols[pos[u]].ftype.kind not in _STAR_PAYLOAD_KINDS
+               for (b, _pk, _bk), (pos, uids) in zip(sides, payloads)
+               for u in uids):
+            continue
+        if any(a.name == "first_row" and any(u not in probe_uids
+                                             for u in _collect(a))
+               for a in plan.aggs):
+            continue  # first_row partials gather from the table
+        probe_est = _est_rows(
+            PhysTableReader(Schema(task.scan_cols), task, False,
+                            probe_l.ranges), pctx)
+        build_phys = [to_physical(b, pctx) for b, _pk, _bk in sides]
+        if any(_est_rows(bp, pctx) > min(DEVICE_JOIN_BUILD_MAX,
+                                         0.5 * max(probe_est, 1))
+               for bp in build_phys):
+            continue
+        # probe uids -> scan positions; build j's payload uids -> the
+        # slots after the scan's and every earlier lookup's payloads
+        mapping = dict(task.scan_pos_map())
+        off = len(task.scan_cols)
+        for _pos, uids in payloads:
+            for u in uids:
+                mapping[u] = off
+                off += 1
+        gb = [g.remap_columns(mapping) for g in plan.group_by]
+        aggs = [a.remap_columns(mapping) for a in plan.aggs]
+        if not all(can_push_expr(g, pctx.pushdown_blacklist, dict_uids)
+                   or _is_plain_col(g) for g in gb) \
+                or not all(can_push_agg(a, pctx.pushdown_blacklist,
+                                        dict_uids) for a in aggs):
+            continue
+        builds = []
+        for j, ((b, pk, bk), bp, (pos, uids)) in enumerate(
+                zip(sides, build_phys, payloads)):
+            task.dag_execs.append(JoinLookupIR(
+                pk.remap_columns(task.scan_pos_map()), j,
+                [b.schema.cols[pos[u]].ftype for u in uids]))
+            builds.append(DeviceJoinBuild(
+                bp, pos[bk.unique_id], [pos[u] for u in uids], j,
+                b.table.name))
+        return _dj_reader_agg(plan, task, probe_l, gb, aggs, builds)
     return None
 
 

@@ -208,10 +208,9 @@ def _plan_hints(phys) -> frozenset:
             hints.add("hash_join")
         for c in getattr(p, "children", []):
             walk(c)
-        for attr in ("reader", "build_plan"):
-            r = getattr(p, attr, None)
-            if r is not None:
-                walk(r)
+        r = getattr(p, "reader", None)
+        if r is not None:
+            walk(r)
 
     walk(phys)
     return frozenset(hints)
