@@ -163,3 +163,213 @@ def test_explain_analyze_runs(d):
     s = _load(d)
     rows = s.execute("explain analyze " + Q3)[0].rows
     assert any("DeviceJoinReader" in r[0] for r in rows)
+
+
+# ---- a lookup into a small side compares keys instead of gathering ---------
+
+I32 = np.iinfo(np.int32)
+FACT_ROWS = 12_000
+
+
+@pytest.fixture
+def mesh_of(request):
+    """The mesh the statements run on: one device, or all eight."""
+    from tidb_tpu.copr import parallel
+
+    devs, epoch = parallel._eligible_devices()
+    mp = pytest.MonkeyPatch()
+    mp.setattr(parallel, "_eligible_devices",
+               lambda: (devs[:request.param], epoch))
+    parallel.MESH_CACHE.clear()
+    try:
+        yield request.param
+    finally:
+        mp.undo()
+        parallel.MESH_CACHE.clear()
+
+
+@pytest.fixture(scope="module")
+def fact():
+    """A fact table whose probe columns are cached at three widths: `a`
+    in 16 bits, `e` in 32 with values at int32's edges, `b` in 64; `n` a
+    key with NULLs; `v` a summed value with NULLs."""
+    d = Domain()
+    s = d.new_session()
+    s.execute("create table f (a bigint, b bigint, e bigint, n bigint,"
+              " v bigint)")
+    rng = np.random.default_rng(46)
+    n = FACT_ROWS
+    edges = np.array([I32.min, I32.min + 1, -5, 0, 7, I32.max - 1, I32.max])
+    cols = [rng.integers(-50, 5000, n),
+            (1 << 40) + rng.integers(0, 400, n),
+            np.concatenate([edges, rng.integers(100, 200, n - len(edges))]),
+            rng.integers(0, 100, n),
+            rng.integers(-1000, 1000, n)]
+    valids = [None, None, None, np.arange(n) % 7 != 0, np.arange(n) % 5 != 0]
+    t = d.catalog.info_schema().table("test", "f")
+    d.storage.table(t.id).bulk_load_arrays(cols, valids,
+                                           ts=d.storage.current_ts())
+    d.storage.regions.split_even(t.id, 8, n)
+    s.execute("analyze table f")
+    return d
+
+
+def _dim(d, name, keys):
+    """A dimension `name` over `keys` (k primary key, w bigint with NULLs,
+    x double, s a string), loaded and analysed once in `d`."""
+    if d.catalog.info_schema().has_table("test", name):
+        return
+    sess = d.new_session()
+    keys = np.asarray(keys, dtype=np.int64)
+    k = len(keys)
+    sess.execute(f"create table {name} (k bigint primary key, w bigint,"
+                 " x double, s varchar(8))")
+    t = d.catalog.info_schema().table("test", name)
+    d.storage.table(t.id).bulk_load_arrays(
+        [keys, np.arange(k) % 11 - 3, keys % 13 * 0.5 - 2,
+         np.array([f"s{i % 4}" for i in range(k)], dtype=object)],
+        [None, np.arange(k) % 6 != 0, None, None],
+        ts=d.storage.current_ts())
+    sess.execute(f"analyze table {name}")
+
+
+#: (case, the dimensions' keys, the statement, whether each of its
+#: lookups compares): one-lookup aggregates over a side of 0 to 4,097
+#: keys, probe keys cached in 16, 32 and 64 bits, NULL probe keys, a
+#: payload, and stars whose sides take both forms
+ONE = "select count(*), sum(f.v) from f, {d} where f.{col} = {d}.k{cond}"
+LOOKUP_CASES = [
+    ("keys-0", {"d0": range(100)}, ONE.format(d="d0", col="a",
+                                               cond=" and d0.w > 100"),
+     [False]),
+    ("keys-1", {"d1": [17]}, ONE.format(d="d1", col="a", cond=""), [False]),
+    ("keys-32", {"d32": range(0, 320, 10)},
+     ONE.format(d="d32", col="a", cond=""), [False]),
+    ("keys-33", {"d33": range(0, 330, 10)},
+     ONE.format(d="d33", col="a", cond=""), [True]),
+    ("keys-256", {"d256": range(-40, 4800, 19)[:256]},
+     ONE.format(d="d256", col="a", cond=""), [True]),
+    ("keys-4096", {"d4096": range(4096)},
+     ONE.format(d="d4096", col="a", cond=""), [True]),
+    ("keys-4097", {"d4097": range(4097)},
+     ONE.format(d="d4097", col="a", cond=""), [False]),
+    ("int32-edges", {"dedge": [I32.min - 7, I32.min, -5, 7, I32.max,
+                               I32.max + 1, 1 << 40] + list(range(100, 160))},
+     ONE.format(d="dedge", col="e", cond=""), [True]),
+    ("int64-probe", {"dwide": [(1 << 40) + i for i in range(0, 400, 3)]},
+     ONE.format(d="dwide", col="b", cond=""), [True]),
+    ("null-probe", {"dnull": range(0, 100, 2)},
+     ONE.format(d="dnull", col="n", cond=""), [True]),
+    ("filtered-to-some", {"dsome": range(3000)},
+     ONE.format(d="dsome", col="a", cond=" and dsome.w = 2"), [True]),
+    ("payload", {"dpay": range(0, 2000, 7)},
+     "select count(*), sum(f.v), sum(dpay.w), count(dpay.w), sum(dpay.x)"
+     " from f, dpay where f.a = dpay.k and dpay.w < 5", [True]),
+    ("star-mixed", {"dbig": range(0, 3000, 2), "dsmall": range(100, 120)},
+     "select dbig.s, dsmall.w, sum(f.v), count(*) from f, dbig, dsmall"
+     " where f.a = dbig.k and f.e = dsmall.k and dbig.w < 4"
+     " group by dbig.s, dsmall.w order by dbig.s, dsmall.w", [True, False]),
+    ("star-wide", {"dwide2": [(1 << 40) + i for i in range(0, 400, 3)],
+                   "dnarrow": range(0, 30, 3)},
+     "select dwide2.s, dnarrow.s, sum(f.v) from f, dwide2, dnarrow"
+     " where f.b = dwide2.k and f.n = dnarrow.k"
+     " group by dwide2.s, dnarrow.s order by dwide2.s, dnarrow.s",
+     [True, False]),
+]
+
+
+def _chunk_attrs(sess, sql):
+    """The `copr.chunk` spans' attributes of one traced run of `sql`."""
+    import json
+
+    tr = json.loads(sess.execute("trace format='json' " + sql)[0].rows[0][0])
+    found = []
+
+    def walk(node):
+        if node["name"] == "copr.chunk":
+            found.append(node.get("attrs") or {})
+        for c in node.get("children", ()):
+            walk(c)
+
+    walk(tr["root"] if "root" in tr else tr)
+    return found
+
+
+def _sides(d, sql):
+    """Each lookup's (build keys after its filter, entries its gather
+    would read) as the host hands them to the program, in plan order."""
+    from tidb_tpu.copr import parallel as par
+
+    seen = []
+    real = par._compare_side
+
+    def spy(keys, gathered, narrow):
+        seen.append((len(keys), gathered))
+        return real(keys, gathered, narrow)
+
+    mp = pytest.MonkeyPatch()
+    mp.setattr(par, "_compare_side", spy)
+    try:
+        d.new_session().query(sql)
+    finally:
+        mp.undo()
+    return seen
+
+
+@pytest.mark.parametrize("mesh_of", [1, 8], indirect=True)
+@pytest.mark.parametrize("case,dims,sql,forms", LOOKUP_CASES,
+                         ids=[c[0] for c in LOOKUP_CASES])
+def test_a_lookup_compares_or_gathers_and_answers_as_the_host(
+        fact, mesh_of, case, dims, sql, forms):
+    """Each lookup takes the form the rule gives for its side's size
+    (`_compare_keys`: compare where its keys, padded to 256, are at most
+    4,096 and the operand a gather reads has more than 32 entries), the
+    two forms answer as the host engine, and `compares` on `copr.chunk`
+    and `lookup_compares_total` count the compared sides."""
+    from tidb_tpu.copr import parallel as par
+    from tidb_tpu.metrics import LOOKUP_COMPARES, REGISTRY
+
+    for name, keys in dims.items():
+        _dim(fact, name, keys)
+    s = fact.new_session()
+    ops = [r[0] for r in s.execute("explain " + sql)[0].rows]
+    assert sum("DeviceJoinReader" in op for op in ops) == 1, ops
+    assert sum("JoinLookup" in op for op in ops) == len(forms), ops
+    # the rule, side by side: at this scale the sizes are the case's own
+    sides = _sides(fact, sql)
+    assert [par._compare_keys(k, g) > 0 for k, g in sides] == forms, sides
+    before = REGISTRY.snapshot().get(LOOKUP_COMPARES, 0)
+    errors = REGISTRY.snapshot().get("mesh_scan_errors_total", 0)
+    s.execute("set tidb_use_tpu = 1")
+    got = s.query(sql)
+    assert REGISTRY.snapshot().get(LOOKUP_COMPARES, 0) - before == sum(forms)
+    assert REGISTRY.snapshot().get("mesh_scan_errors_total", 0) == errors
+    # the fact's program; each filtered side's own has no lookup
+    chunks = [c for c in _chunk_attrs(s, sql) if c["lookups"]]
+    assert [(c["lookups"], c["compares"]) for c in chunks] == [
+        (len(forms), sum(forms))]
+    s.execute("set tidb_use_tpu = 0")
+    want = s.query(sql)
+    assert got == want, (got[:3], want[:3])
+    if case != "keys-0":
+        assert want[0][0], "no probe row joined"
+
+
+@pytest.mark.parametrize("keys,gathered,pad", [
+    (365, 512, 512),          # SSB Q1.1's date side at SF1
+    (31, 32, 0),              # Q1.2's one month: a small gather stays
+    (7, 16, 0),               # Q1.3's one week
+    (2556, 1 << 16, 2560),    # a star's whole date side, its direct table
+    (400, 1 << 11, 512),      # a filtered supplier side
+    (6000, 1 << 15, 0),       # a filtered customer side: past 4,096
+    (80_000, 1 << 18, 0),     # a filtered part side
+    (0, 16, 0),               # an empty side: all misses, by the gather
+    (3, 1 << 12, 256),        # few keys over a wide range: compared
+])
+def test_the_lookup_form_follows_the_sides_size(keys, gathered, pad):
+    from tidb_tpu.copr import parallel as par
+
+    assert par._compare_keys(keys, gathered) == pad
+    if gathered == par._pow2(keys) and keys:
+        # a one-lookup side's operand: its length alone says its form
+        assert par._compared(pad or gathered) == bool(pad)

@@ -309,6 +309,8 @@ def test_sf1_ssb_rehearsed_is_correct_and_its_control_is_caught(
     # one lookup a statement of flight 1, 3 or 4 of the others
     assert 1 < got["star_lookups_per_stmt"]["value"] <= 4
     assert got["star_build_ms"]["value"] > 0
+    # some of the lookups compare (the date side's 2,556 keys among them)
+    assert 0 < got["compare_lookup_pct"]["value"] <= 100
     assert "star_roofline" not in got   # no peaks in a rehearsal
 
 
@@ -353,3 +355,20 @@ def test_star_lookups_per_stmt_is_the_mean_of_the_dispatches_lookups(bench):
     # the parent's `copr.chunk` carries no such attribute: nothing
     bare = [_span("copr.chunk", kind="agg")]
     assert _read("star_lookups_per_stmt", {"spans": [bare, []]}) is None
+
+
+def test_compare_lookup_pct_is_the_compared_share_of_the_lookups(bench):
+    star = [_span("copr.chunk", lookups=0, compares=0),
+            _span("copr.chunk", lookups=4, compares=3)]
+    one = [_span("copr.chunk", lookups=1, compares=1)]
+    gathered = [_span("copr.chunk", lookups=1, compares=0)]
+    # 4 of the 6 lookups over the three statements
+    assert _read("compare_lookup_pct",
+                 {"spans": [star, one, gathered, []]}) == pytest.approx(
+                     100 * 4 / 6)
+    # a scan cell's programs carry the attribute and no lookup: nothing
+    scan = [_span("copr.chunk", lookups=0, compares=0)]
+    assert _read("compare_lookup_pct", {"spans": [scan, scan]}) is None
+    # the parent's `copr.chunk` carries no such attribute: nothing
+    bare = [_span("copr.chunk", lookups=3)]
+    assert _read("compare_lookup_pct", {"spans": [bare, []]}) is None

@@ -134,14 +134,18 @@ def _fragment_args(sess, table, sql, mesh, n_tiles, build_pad=16,
         valids.append(None)
     pargs = []
     if an.agg is not None and an.agg_mode == "star":
-        # one code table a lookup
-        pargs = [sds((cap,), np.int32) for cap in star_caps[0]]
+        # one code table a lookup, or a compared side's [2, K] keys and
+        # codes (`parallel._star_args`)
+        pargs = [sds((cap,), np.int32) if isinstance(cap, int)
+                 else sds((2, cap[0]), np.dtype(cap[1]))
+                 for cap in star_caps[0]]
     for lk in () if pargs else an.lookups:
         # where the groups are the build rows the keys alone are sent,
-        # in 32 bits beside a probe key cached that narrow
+        # and so are a compared side's with its payload; either in 32
+        # bits beside a probe key cached that narrow
         fd = par._fd_lookup(an)
-        pargs.append(sds((build_pad,),
-                         np.int32 if fd and wire is not None else np.int64))
+        narrow = wire is not None and (fd or par._compared(build_pad))
+        pargs.append(sds((build_pad,), np.int32 if narrow else np.int64))
         for ft in () if fd else lk.payload_ftypes:
             pargs += [sds((build_pad,), par._full_dtype(ft.kind)),
                       sds((build_pad,), np.bool_)]
@@ -498,25 +502,27 @@ def test_one_chip_q3_join_programs_at_sf1_shapes(meshes, q3_bench):
 # a star join of SSB at SF1 (configuration `ssb-sf1-star`)
 # ---------------------------------------------------------------------------
 
-#: SF1's wire dtypes of the fact columns SSB's Q2.1 reads
+#: SF1's wire dtypes of the fact columns SSB's Q1.1 and Q2.1 read
 SSB_WIRE = {"lo_orderdate": np.int32, "lo_custkey": np.int16,
             "lo_partkey": np.int32, "lo_suppkey": np.int16,
-            "lo_revenue": np.int32}
+            "lo_revenue": np.int32, "lo_extendedprice": np.int32,
+            "lo_discount": np.int8, "lo_quantity": np.int8}
+#: SSB SF1's fact rows (about 6.0 M) in tiles of 2^20, padded
+SSB_TILES = 8
 #: Q2.1's code tables at SF1 (the date keys' range of 61,130, 200,000
 #: parts, 2,000 suppliers, each padded to a power of two) and its 280
 #: groups, padded
 SSB_Q21_CAPS = ((1 << 16, 1 << 18, 1 << 11), 512)
 
 
-def test_star_join_program_compiles_for_one_v5e_chip(meshes):
-    """The fact scan of SSB's Q2.1 with its three lookups chained in one
-    program, at SF1's shapes: one gather a dimension and one sort of the
-    group ids for the aggregate."""
+@pytest.fixture(scope="module")
+def ssb():
+    """(domain, the fact table, a query's text by name) over the
+    benchmark's SSB generator at SF 0.001: only the plans reach the
+    compiler, at SF1's shapes."""
     import json
     import os
     import sys
-
-    import jax
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     bench = os.path.join(root, "benchmarks")
@@ -526,15 +532,28 @@ def test_star_join_program_compiles_for_one_v5e_chip(meshes):
 
         config = json.load(open(os.path.join(bench, "configs",
                                              "ssb-sf1-star.json")))
-        sql = json.load(open(os.path.join(bench, "queries",
-                                          "ssb21.json")))["sql"]
         domain, _, _ = serve.load(config, 11, 0.001, lambda _n: None)
     finally:
         sys.path.remove(bench)
     table = domain.storage.table(
         domain.catalog.info_schema().table("test", "lineorder").id)
-    core, args = _fragment_args(domain.new_session(), table, sql,
-                                meshes[1], 8, wire=SSB_WIRE,
+
+    def sql(name):
+        with open(os.path.join(bench, "queries", name + ".json")) as f:
+            return json.load(f)["sql"]
+
+    return domain, table, sql
+
+
+def test_star_join_program_compiles_for_one_v5e_chip(meshes, ssb):
+    """The fact scan of SSB's Q2.1 with its three lookups chained in one
+    program, at SF1's shapes: one gather a dimension and one sort of the
+    group ids for the aggregate."""
+    import jax
+
+    domain, table, sql = ssb
+    core, args = _fragment_args(domain.new_session(), table, sql("ssb21"),
+                                meshes[1], SSB_TILES, wire=SSB_WIRE,
                                 star_caps=SSB_Q21_CAPS)
     compiled, took, nbytes = _timed_compile(jax.jit(core), args)
     assert compiled.as_text().count(" sort(") == 1
@@ -542,3 +561,77 @@ def test_star_join_program_compiles_for_one_v5e_chip(meshes):
     # gather's whole operand a row)
     print(f"star {took:.1f}s {nbytes / 1e9:.3f}GB")
     assert took < 150
+
+
+#: 2^23 probe rows: SSB SF1's fact on one chip
+SSB_ROWS = SSB_TILES * PROD_TILE
+
+
+@pytest.mark.parametrize("keys", [512, 2560])
+@pytest.mark.parametrize("out", ["hit", "code"])
+def test_compare_lookup_is_one_fusion_that_reads_the_rows_once(
+        keys, out, topo):
+    """The compare emitter over a side of 512 or 2,560 int32 keys and
+    2^23 probe rows: each of its outputs one fused reduction over the
+    keys that reads its arguments once and writes its output once, and
+    no [keys, rows] operand (a gather from the same side reads 34.5 GB
+    by the compiler's count; the binary search it replaces, 139.5 GB)."""
+    import re
+
+    import jax
+    from jax.sharding import SingleDeviceSharding
+
+    from tidb_tpu.copr import fusion
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    if out == "hit":
+        def fn(k, p, v):
+            return fusion.compare_lookup(k, None, p, v)[0]
+
+        args = (sds((keys,), np.int32), sds((SSB_ROWS,), np.int32),
+                sds((SSB_ROWS,), np.bool_))
+    else:
+        def fn(k, c, p, v):
+            return fusion.compare_lookup(k, c, p, v)[1]
+
+        args = (sds((keys,), np.int32), sds((keys,), np.int32),
+                sds((SSB_ROWS,), np.int32), sds((SSB_ROWS,), np.bool_))
+    compiled, _took, nbytes = _timed_compile(jax.jit(fn), args)
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY"):]
+    assert len(re.findall(r"= \S+ fusion\(", entry)) == 1, entry
+    assert nbytes < 0.25e9
+    mem = compiled.memory_analysis()
+    # its arguments read once and its output written once, give or take
+    # a fifth (readings on an 8-core x86 host: 50.3 MB for a hit over
+    # 41.9 MB of arguments, 67.1 MB for a code over 33.6 MB and a 33.6 MB
+    # output)
+    assert nbytes <= 1.2 * (mem.argument_size_in_bytes
+                            + mem.output_size_in_bytes)
+    assert mem.temp_size_in_bytes < 0.05e9
+
+
+def test_q11_program_compares_its_date_keys(meshes, ssb):
+    """SSB's Q1.1 at SF1's shapes: the dense aggregate over 2^23 fact
+    rows with its one lookup into the 365 dates of 1993, padded to 512,
+    compared (`parallel._compared`) and not binary-searched: no `while`
+    loop, and the compiler's bytes a hundredth of the search's (over
+    139 GB: ten int64 gathers at the fact's length and a check)."""
+    import jax
+
+    from tidb_tpu.copr import parallel as par
+
+    domain, table, sql = ssb
+    assert par._compare_keys(365, par._pow2(365)) == 512
+    core, args = _fragment_args(domain.new_session(), table, sql("ssb11"),
+                                meshes[1], SSB_TILES, build_pad=512,
+                                wire=SSB_WIRE)
+    assert args[5].shape == (512,) and args[5].dtype == np.int32
+    compiled, took, nbytes = _timed_compile(jax.jit(core), args)
+    assert " while(" not in compiled.as_text()
+    print(f"q1.1 {took:.1f}s {nbytes / 1e9:.3f}GB")
+    assert nbytes < 2e9

@@ -954,6 +954,56 @@ def lookup_group_sums(bkeys, keys, live, values):
     return rows, out
 
 
+def _equal_keys(keys, probe):
+    """[K, rows...]: build key k equals the row's key.  The keys lie on
+    the major axis and the rows keep their own layout on the minor ones,
+    so a reduction over axis 0 is one fused loop that reads each row's
+    key once and never writes this operand."""
+    return keys.reshape(keys.shape + (1,) * probe.ndim) == probe[None]
+
+
+def compare_lookup(keys, codes, probe, valid):
+    """A lookup into a small build side by comparing each probe row's key
+    with every build key, in place of a gather or a binary search.  On a
+    TPU v5e a gather from more than 32 entries reads a 4 KB tile a row
+    whatever the table's size (34.5 GB at 2^23 rows by the compiler's
+    count), and a binary search is one such gather a level; this is one
+    fused reduction over the keys, a few lane operations a key and row
+    (`parallel.COMPARE_LOOKUP_MAX_KEYS` says where it stops paying).
+
+    `keys` [K]: the side's keys, a pad repeating a real key and its code
+    (so no sentinel can equal a probe value); `codes` [K] non-negative
+    integers or None; `probe` the rows' keys in the keys' dtype, `valid`
+    their validity (a NULL key never matches).  Returns (hit, code): the
+    code of the build row whose key the row's equals, -1 on a miss, or
+    None where `codes` is None."""
+    eq = _equal_keys(keys, probe)
+    if codes is None:
+        # the validity inside the reduction: outside it, the compiler
+        # reads the probe keys a second time
+        return jnp.any(eq & valid[None], axis=0), None
+    fill = jnp.asarray(-1, codes.dtype)
+    code = jnp.max(jnp.where(eq, codes.reshape(eq.shape[:1]
+                                               + (1,) * probe.ndim), fill),
+                   axis=0)
+    return valid & (code >= 0), code
+
+
+def compare_take(keys, values, probe):
+    """A payload column of a compared lookup (`compare_lookup`): each row's
+    value of the build row whose key equals its key, by the same fused
+    reduction (a max, the dtype's least value where no key equals; an
+    `any` for a boolean column).  The caller's hit masks a miss."""
+    eq = _equal_keys(keys, probe)
+    vals = values.reshape(eq.shape[:1] + (1,) * probe.ndim)
+    if values.dtype == jnp.bool_:
+        return jnp.any(eq & vals, axis=0)
+    least = (-jnp.inf if jnp.issubdtype(values.dtype, jnp.floating)
+             else jnp.iinfo(values.dtype).min)
+    return jnp.max(jnp.where(eq, vals, jnp.asarray(least, values.dtype)),
+                   axis=0)
+
+
 def star_group_sums(gid, live, values, G: int, nulls: bool):
     """Counts and exact sums of the live rows by group, for group ids in
     [0, G) (a star's: each row's codes from its dimensions, combined

@@ -44,7 +44,7 @@ from jax import shard_map
 from .. import serving as _serving
 from ..chunk import Chunk, Column
 from ..coord import CoordEpochMismatch
-from ..metrics import REGISTRY, STAR_LOOKUPS
+from ..metrics import LOOKUP_COMPARES, REGISTRY, STAR_LOOKUPS
 from ..store.fault import FAILPOINTS
 from ..store.kv import CopRequest
 from ..types import TypeKind
@@ -986,38 +986,59 @@ def _key_device(d):
     return d.astype(jnp.int64)
 
 
+def _probe_key(key, cols, n_local: int, dtype):
+    """A probe row's join key in the build keys' dtype, and its validity:
+    on int32 lanes where the host sent the keys in 32 bits (`_narrow_key`:
+    the key is a column cached at most that wide, so its values fit),
+    from the column's wire array; else `_key_device`'s."""
+    from ..expr.expression import ColumnExpr
+
+    d, v = compile_expr(key, cols, n_local)
+    if dtype != jnp.int32:
+        return _key_device(d), v
+    wire = (cols["__wire__"].get(key.index)
+            if isinstance(key, ColumnExpr) else None)
+    return (d if wire is None else wire).astype(jnp.int32), v
+
+
 def _apply_probes(an: _Analyzed, cols, m, pargs, counts, n_local: int):
     """AND the runtime join-filter membership tests into the row mask:
-    sorted build keys broadcast to every shard, searchsorted probe.
-    Then run the broadcast lookup JOINS: drop misses and extend the
-    column env with gathered payload rows (JoinLookupIR) — the join
-    completes ON DEVICE, inside the same shard program as the scan and
-    the partial aggregation."""
-    for i, p in enumerate(an.probes):
-        keys, kn = pargs[i], counts[i]
-        d, v = compile_expr(p.key, cols, n_local)
-        k = _key_device(d)
+    build keys broadcast to every shard, each side compared key by key
+    (`fusion.compare_lookup`) where `_compared` says its length is a
+    compared side's, else binary-searched (sorted, padded with the
+    type's maximum).  Then run the broadcast lookup JOINS the same way:
+    drop misses and extend the column env with each matched build row's
+    payload (JoinLookupIR) — the join completes ON DEVICE, inside the
+    same shard program as the scan and the partial aggregation."""
+    from . import fusion
+
+    def look(keys, kn, key):
+        k, v = _probe_key(key, cols, n_local, keys.dtype)
+        if _compared(keys.shape[0]):
+            return fusion.compare_lookup(keys, None, k, v)[0], k, None
         pos = jnp.searchsorted(keys, k)
         pos_c = jnp.clip(pos, 0, keys.shape[0] - 1)
-        hit = (pos < kn) & (keys[pos_c] == k)
-        m = m & v & hit
+        return v & (pos < kn) & (keys[pos_c] == k), k, pos_c
+
+    for i, p in enumerate(an.probes):
+        m = m & look(pargs[i], counts[i], p.key)[0]
     off = len(an.probes)
     out_idx = len(an.scan.columns)
     for j, lk in enumerate(an.lookups):
-        keys, kn = pargs[off], counts[len(an.probes) + j]
+        keys = pargs[off]
         off += 1
-        d, v = compile_expr(lk.key, cols, n_local)
-        k = d.astype(jnp.int64)
-        pos = jnp.searchsorted(keys, k)
-        pos_c = jnp.clip(pos, 0, keys.shape[0] - 1)
-        hit = (pos < kn) & (keys[pos_c] == k) & v
+        hit, k, pos_c = look(keys, counts[len(an.probes) + j], lk.key)
         m = m & hit
         for _ft in lk.payload_ftypes:
             pl, pv = pargs[off], pargs[off + 1]
             off += 2
-            # broadcast gather: matched build row per probe row; misses
-            # are dead rows under m, their payload validity is False
-            cols[out_idx] = (pl[pos_c], hit & pv[pos_c])
+            # the matched build row's payload per probe row; misses are
+            # dead rows under m, their payload validity is False
+            if pos_c is None:
+                cols[out_idx] = (fusion.compare_take(keys, pl, k),
+                                 hit & fusion.compare_take(keys, pv, k))
+            else:
+                cols[out_idx] = (pl[pos_c], hit & pv[pos_c])
             out_idx += 1
     return m
 
@@ -1603,15 +1624,98 @@ def _lookup_agg_chunks(out: dict, an: _Analyzed, aux: dict) -> List[Chunk]:
 STAR_MAX_GROUPS = 1 << 16
 STAR_MAX_TABLE = 1 << 24
 
+#: A lookup (a star's side, a JoinLookupIR, a runtime filter's probe)
+#: compares each probe key with every build key (`fusion.compare_lookup`)
+#: where its keys, padded to a multiple of COMPARE_LOOKUP_PAD, are at most
+#: this many and the operand a gather would read (the direct code table,
+#: the padded sorted keys) has more than GATHER_SMALL entries; else it
+#: gathers or binary-searches as before.  A gather from more than 32 entries reads a 4 KB
+#: tile a probe row whatever the table's size: 34.5 GB at 2^23 rows by the
+#: compiler's count, 64-72 ms measured on a v5e.  The compare reads the
+#: probe keys once and costs three lane operations a key and row, about
+#: 8.7 us a key at 2^23 rows at the 2.9e12 lane operations a second the
+#: scan programs reach: the two break even near 8,000 keys, and 4,096
+#: leaves a factor of two for that reckoning.
+COMPARE_LOOKUP_MAX_KEYS = 4096
+COMPARE_LOOKUP_PAD = 256
+#: a gather from this many entries or fewer is lowered without a read
+#: from HBM a row (2-3 ms at 2^23 rows on a v5e): such a side gathers
+GATHER_SMALL = 32
+
+
+def _pow2(n: int, least: int = 16) -> int:
+    """The power of two at least `n` and `least`."""
+    p = least
+    while p < n:
+        p <<= 1
+    return p
+
+
+def _compare_keys(k: int, gathered: int) -> int:
+    """The padded key count of a side of `k` build keys looked up by
+    comparing, or 0 where it gathers from its `gathered` entries (an empty
+    side gathers: its misses need no key)."""
+    kp = -(-k // COMPARE_LOOKUP_PAD) * COMPARE_LOOKUP_PAD
+    return kp if k and kp <= COMPARE_LOOKUP_MAX_KEYS \
+        and gathered > GATHER_SMALL else 0
+
+
+def _compared(n: int) -> bool:
+    """Whether a lookup's keys operand of `n` entries is a compared side's:
+    the host pads a searched side to a power of two (`_pow2`) and a
+    compared one to a multiple of COMPARE_LOOKUP_PAD by `_compare_keys`,
+    which compares every side of 33 to 4,096 keys, so every compared
+    side's length lies in this range and no searched side's does."""
+    return GATHER_SMALL < n <= COMPARE_LOOKUP_MAX_KEYS
+
+
+def _narrow_key(table, an: _Analyzed, key) -> bool:
+    """The probe key is an integer column cached in at most 32 bits, so a
+    compared side's keys go in 32 bits too (`_compare_side`)."""
+    from ..expr.expression import ColumnExpr
+
+    return isinstance(key, ColumnExpr) \
+        and key.ftype.kind != TypeKind.FLOAT \
+        and _wire_dtype(table, an.scan.columns[key.index]).itemsize <= 4
+
+
+def _compare_side(keys: np.ndarray, gathered: int, narrow: bool):
+    """How a side whose sorted build keys are `keys` is compared: (the
+    rows kept, their padded count, the keys' dtype), or None where the
+    side gathers (`_compare_keys`).  With `narrow` (`_narrow_key`) the
+    keys go in 32 bits, and one outside that range, which no probe value
+    can equal, is dropped (where that drops every key, they go in 64)."""
+    pad = _compare_keys(len(keys), gathered)
+    if not pad:
+        return None
+    kept = np.arange(len(keys))
+    dt = keys.dtype if keys.dtype.kind == "f" else np.dtype(np.int64)
+    if narrow:
+        i32 = np.iinfo(np.int32)
+        inside = np.flatnonzero((keys >= i32.min) & (keys <= i32.max))
+        if len(inside):
+            kept, dt = inside, np.dtype(np.int32)
+    return kept, pad, dt
+
+
+def _pad_repeat(a: np.ndarray, pad: int, dtype=None) -> np.ndarray:
+    """`a` (not empty) padded to `pad` entries by repeating its last."""
+    out = np.empty(pad, dtype=dtype or a.dtype)
+    out[:len(a)] = a
+    out[len(a):] = a[-1]
+    return out
+
 
 def _build_star_agg_core(an: _Analyzed, col_order: List[int], mesh: Mesh,
                          tiles_per_shard: int, star_caps, hoisted=None,
                          col_layout=None):
     """The shard_map'd core of a star's aggregate (`an.agg_mode` 'star'):
-    scan, selection, then each lookup as one gather from its dimension's
-    code table (`_star_args`: a direct table over the build keys' range,
-    a build row's group code times its stride, -1 where no build row
-    is), the codes summed into a mixed-radix group id, and
+    scan, selection, then each lookup: a gather from its dimension's code
+    table (`_star_args`: a direct table over the build keys' range, a
+    build row's group code times its stride, -1 where no build row is),
+    or, for a side `_star_args` sends as its [2, K] keys and codes, a
+    compare with every key (`fusion.compare_lookup`); the codes summed
+    into a mixed-radix group id, and
     `fusion.star_group_sums` over the G groups; the shards' states add up
     by psum.  A probe row with a miss in any table is dropped (the inner
     joins)."""
@@ -1636,6 +1740,13 @@ def _build_star_agg_core(an: _Analyzed, col_order: List[int], mesh: Mesh,
         live = ctx.mask
         gid = jnp.zeros(n_local, dtype=jnp.int32)
         for j, lk in enumerate(an.lookups):
+            if pargs[j].ndim == 2:
+                k, v = _probe_key(lk.key, cols, n_local, pargs[j].dtype)
+                hit, code = fusion.compare_lookup(pargs[j][0], pargs[j][1],
+                                                  k, v)
+                live = live & hit
+                gid = gid + code.astype(jnp.int32)
+                continue
             d, v = compile_expr(lk.key, cols, n_local)
             # the offset into the table in 32 bits where the key's column
             # came that narrow: `_star_args` keeps a base within 2^30 of
@@ -1672,20 +1783,23 @@ def _build_star_agg_core(an: _Analyzed, col_order: List[int], mesh: Mesh,
                             _mesh_in_specs(an, hoisted, n_lvals), out_specs)
 
 
-def _star_args(an: _Analyzed, aux: dict):
+def _star_args(an: _Analyzed, aux: dict, narrow):
     """What a star's program takes for this statement, from the build
     sides the host holds: for each lookup its code table (a build row's
     group code, the index of its group keys' values among the distinct
     ones of its side, times the stride of its side in the mixed-radix
     group id; -1 where the range holds no build key) and its keys' base,
-    the int32 width a table and the group count are padded to (so the
-    program compiles once a class), and what `_star_agg_chunks` decodes
-    the group ids with.  None, with the reason, where the group space or
-    a key range is past what the program takes."""
+    or, where `_compare_side` says the side compares (`narrow[j]`: its
+    probe key is `_narrow_key`), its keys and their codes as one [2, K]
+    operand; the sizes a table, a compared side and the group count are
+    padded to (so the program compiles once a class), and what
+    `_star_agg_chunks` decodes the group ids with.  None, with the
+    reason, where the group space or a table's key range is past what
+    the program takes."""
     from ..trace import span
     from .aggstate import group_indices
 
-    stride, tables, bases, decode = 1, [], [], []
+    stride, tables, bases, decode, sides = 1, [], [], [], []
     for j, lk in enumerate(an.lookups):
         fid = lk.filter_id
         keys = aux[f"probe_keys_{fid}"]
@@ -1704,27 +1818,31 @@ def _star_args(an: _Analyzed, aux: dict):
                 gidx, card, kept = np.zeros(len(keys), np.int64), 1, {}
             lo = int(keys[0]) if len(keys) else 0
             span_ = int(keys[-1]) - lo + 1 if len(keys) else 1
-            if stride * card > STAR_MAX_GROUPS or span_ > STAR_MAX_TABLE \
-                    or not -(1 << 30) <= lo < 1 << 30:
+            cap = _pow2(span_)
+            cmp = _compare_side(keys, cap, narrow[j])
+            if stride * card > STAR_MAX_GROUPS or cmp is None and (
+                    span_ > STAR_MAX_TABLE or not -(1 << 30) <= lo < 1 << 30):
                 return None, (f"star lookup {fid}: {card} groups, a key "
                               f"range of {span_} from {lo}")
-            cap = 16
-            while cap < span_:
-                cap <<= 1
-            table = np.full(cap, -1, dtype=np.int32)
-            table[keys - lo] = gidx * stride
+            if cmp is not None:
+                rows, pad, dt = cmp
+                table = np.stack([_pad_repeat(keys[rows], pad, dt),
+                                  _pad_repeat(gidx[rows] * stride, pad, dt)])
+                sides.append((pad, dt.name))
+                lo = 0
+            else:
+                table = np.full(cap, -1, dtype=np.int32)
+                table[keys - lo] = gidx * stride
+                sides.append(cap)
             sp.set(bytes=table.nbytes, groups=int(card))
         tables.append(table)
         bases.append(lo)
         decode.append((stride, card, kept))
         stride *= card
-    G = 16
-    while G < stride:
-        G <<= 1
     return {"tables": tables, "bases": bases, "decode": decode,
             "dims": [aux.get(f"dim_{lk.filter_id}", lk.filter_id)
                      for lk in an.lookups],
-            "caps": (tuple(len(t) for t in tables), G)}, None
+            "caps": (tuple(sides), _pow2(stride))}, None
 
 
 def _star_agg_chunks(out: dict, an: _Analyzed, decode) -> List[Chunk]:
@@ -2340,10 +2458,11 @@ class _Prepared:
         return new
 
 
-def _probe_args(req: CopRequest, an: _Analyzed):
+def _probe_args(req: CopRequest, an: _Analyzed, table):
     """The statement's runtime join-filter payloads: sorted build keys,
     padded to a pow2 bucket so compiled programs are reused across
-    key-set sizes.  (device arrays, key counts, pads)."""
+    key-set sizes, or, for a side `_compare_side` compares, its keys
+    padded to that side's multiple.  (device arrays, key counts, pads)."""
     pargs: list = []
     counts: List[int] = []
     kpads: list = []
@@ -2360,22 +2479,21 @@ def _probe_args(req: CopRequest, an: _Analyzed):
             # this backend), so translate bits -> values here and re-sort
             # (bit order != value order for negatives)
             vals = np.sort(arr.view(np.float64))
-            k = len(vals)
-            kpad = 16
-            while kpad < k:
-                kpad <<= 1
-            padded = np.full(kpad, np.inf, dtype=np.float64)
-            padded[:k] = vals
+            pad = np.inf
         else:
-            k = len(arr)
-            kpad = 16
-            while kpad < k:
-                kpad <<= 1
-            padded = np.full(kpad, np.iinfo(np.int64).max, dtype=np.int64)
-            padded[:k] = arr
+            vals, pad = arr, np.iinfo(np.int64).max
+        k = len(vals)
+        kpad = _pow2(k)
+        cmp = _compare_side(vals, kpad, _narrow_key(table, an, p.key))
+        if cmp is not None:
+            rows, kpad, kdt = cmp
+            padded = _pad_repeat(vals[rows], kpad, kdt)
+        else:
+            padded = np.full(kpad, pad, dtype=vals.dtype)
+            padded[:k] = vals
         pargs.append(jnp.asarray(padded))
         counts.append(k)
-        kpads.append(kpad)
+        kpads.append((kpad, padded.dtype.name))
     return pargs, counts, kpads
 
 
@@ -2460,14 +2578,13 @@ def _run_mesh_once(storage, req: CopRequest, tid: int,
         n_tiles, n_pad, _ = prep.layout
         S = len(prep.mesh_ids)
         _observe_fragment(table, prep.uses)
-        pargs, counts, kpads = _probe_args(req, an)
+        pargs, counts, kpads = _probe_args(req, an, table)
         asp.set(kind=kind)
-
-    from ..expr.expression import ColumnExpr
 
     if an.lookups:
         annotate(join=len(an.lookups))  # on `distsql.fanout`
     star = an.agg is not None and an.agg_mode == "star"
+    compares = 0  # the lookups that compare (`_compare_side`)
     for lk in an.lookups:
         arr = (req.aux or {}).get(f"probe_keys_{lk.filter_id}")
         payload = (req.aux or {}).get(f"payload_{lk.filter_id}")
@@ -2487,46 +2604,60 @@ def _run_mesh_once(storage, req: CopRequest, tid: int,
             req.mesh_reject_reason = "string lookup payload outside a star"
             return None
         k = len(arr)
-        kpad = 16
-        while kpad < k:
-            kpad <<= 1
+        kpad = _pow2(k)
         fd = _fd_lookup(an)
+        cmp = None if fd else _compare_side(
+            arr, kpad, _narrow_key(table, an, lk.key))
         # a merge's sort key in 32 bits where both sides fit: the probe
         # key is a column cached that narrow, the build keys lie inside
         # (the type's maximum stays free for the pad)
         kdt = np.dtype(np.int64)
-        if fd and k and isinstance(lk.key, ColumnExpr) \
-                and _wire_dtype(table, an.scan.columns[lk.key.index]
-                                ).itemsize <= 4 \
+        if fd and k and _narrow_key(table, an, lk.key) \
                 and np.iinfo(np.int32).min <= arr[0] \
                 and arr[-1] < np.iinfo(np.int32).max:
             kdt = np.dtype(np.int32)
         with span("join.build", phase="upload", rows=k) as sp:
-            padded = np.full(kpad, np.iinfo(kdt).max, dtype=kdt)
-            padded[:k] = arr
-            sent = [padded]
-            for j, ft in enumerate(() if fd else lk.payload_ftypes):
-                pl = np.zeros(kpad, dtype=_full_dtype(ft.kind))
-                pl[:k] = payload[j]
-                pv = np.zeros(kpad, dtype=np.bool_)
-                src_v = pvalids[j] if pvalids is not None else None
-                pv[:k] = True if src_v is None else src_v
-                sent += [pl, pv]
+            if cmp is not None:
+                # compared: the kept keys and their payload rows, the pad
+                # repeating the last
+                rows, kpad, kdt = cmp
+                sent = [_pad_repeat(arr[rows], kpad, kdt)]
+                for j, ft in enumerate(lk.payload_ftypes):
+                    src_v = pvalids[j] if pvalids is not None else None
+                    sent += [_pad_repeat(payload[j][rows], kpad,
+                                         _full_dtype(ft.kind)),
+                             _pad_repeat(np.ones(len(rows), np.bool_)
+                                         if src_v is None else src_v[rows],
+                                         kpad)]
+            else:
+                padded = np.full(kpad, np.iinfo(kdt).max, dtype=kdt)
+                padded[:k] = arr
+                sent = [padded]
+                for j, ft in enumerate(() if fd else lk.payload_ftypes):
+                    pl = np.zeros(kpad, dtype=_full_dtype(ft.kind))
+                    pl[:k] = payload[j]
+                    pv = np.zeros(kpad, dtype=np.bool_)
+                    src_v = pvalids[j] if pvalids is not None else None
+                    pv[:k] = True if src_v is None else src_v
+                    sent += [pl, pv]
             pargs += [jnp.asarray(a) for a in sent]
             sp.set(bytes=sum(a.nbytes for a in sent))
         counts.append(k)
-        kpads.append((kpad, kdt.name) if fd else kpad)
+        compares += cmp is not None
+        kpads.append((kpad, kdt.name) if fd or cmp else kpad)
     if star:
-        star, why = _star_args(an, req.aux)
+        star, why = _star_args(an, req.aux, [
+            _narrow_key(table, an, lk.key) for lk in an.lookups])
         if star is None:
             req.mesh_reject_reason = why
             return None
         for fid, t in zip(star["dims"], star["tables"]):
             with span("star.build", dim=req.aux.get(f"dim_{fid}", fid),
                       phase="upload",
-                      rows=len(t), bytes=t.nbytes):
+                      rows=t.shape[-1], bytes=t.nbytes):
                 pargs.append(jnp.asarray(t))
         counts += star["bases"]
+        compares = sum(t.ndim == 2 for t in star["tables"])
         kpads.append(("star",) + star["caps"])
 
     # column arrays load BEFORE the program lookup: the compiled program
@@ -2625,7 +2756,7 @@ def _run_mesh_once(storage, req: CopRequest, tid: int,
     if bounds:
         out = _dispatch_once(kind, fn, datas, valids, del_mask, bounds,
                              lvals, pargs, scalars, mesh_ids,
-                             lookups=len(an.lookups))
+                             lookups=len(an.lookups), compares=compares)
         if star:
             out["decode"] = star["decode"]
     try:
@@ -2707,7 +2838,7 @@ def _mesh_result(req, dag, table, an: _Analyzed, kind: str, out, inserted,
 
 
 def _dispatch_once(kind, fn, datas, valids, del_mask, bounds, lvals, pargs,
-                   scalars, mesh_ids, lookups: int = 0):
+                   scalars, mesh_ids, lookups: int = 0, compares: int = 0):
     """The ONE dispatch of a mesh statement: the compiled program over
     all of the statement's range slots, between two cancellation seams.
     A dispatch in flight runs to completion and costs one pass over the
@@ -2729,8 +2860,11 @@ def _dispatch_once(kind, fn, datas, valids, del_mask, bounds, lvals, pargs,
                    start=start, end=end)
     if lookups:
         REGISTRY.inc(STAR_LOOKUPS, float(lookups))
+    if compares:
+        REGISTRY.inc(LOOKUP_COMPARES, float(compares))
     with span("copr.chunk", kind=kind, devices=len(mesh_ids),
-              rows=sum(hi - lo for lo, hi in bounds), lookups=lookups):
+              rows=sum(hi - lo for lo, hi in bounds), lookups=lookups,
+              compares=compares):
         # resource-group admission per dispatch: a depleted group
         # waits here, and the dispatch's device time is its charge
         with dispatch_admission(DISPATCH_LOCK):

@@ -254,6 +254,9 @@ REGISTRY = Registry()
 #: lookup joins chained inside the mesh programs dispatched (one a
 #: lookup a dispatch: a star's fact scan counts each of its dimensions)
 STAR_LOOKUPS = "star_lookups_total"
+#: those of them that compared the probe key with every build key
+#: (`fusion.compare_lookup`) instead of gathering
+LOOKUP_COMPARES = "lookup_compares_total"
 
 #: statement classes carrying per-class end-to-end latency histograms
 #: (`stmt_latency_<class>_ms`) and SLO threshold sysvars
