@@ -5,20 +5,25 @@
 - Each of Q2.1-Q4.3 plans as ONE DeviceJoinReader whose cop DAG chains a
   lookup into each of its dimensions (3 or 4), and no join tree.
 - Served over the wire, all 13 queries equal the plain reference
-  (`benchmarks/queries/_ssb.py`) on both engines, over a mesh of one device
-  and of eight.
+  (`benchmarks/queries/_ssb.py`) on both engines, over a mesh of one device,
+  of four and of eight; over four, a star's dispatch names its shards and
+  its build sides' uploads the bytes that went to the devices.
+- The blocked reference for SF100 (`benchmarks/queries/_ssbw.py`) equals
+  `_ssb.py` on every query, blocks smaller than the fact.
 - A dimension filter that removes every row gives the empty result.
 - A star whose aggregate is not a star's (a group key from the fact, a
   `max`) leaves the mesh before a dimension string reaches the device,
   and answers as the host engine.
-- `benchmarks/run.py` rehearses `sf1-ssb` correct, and catches the float32
-  control; the cell's readers read hand-made runs as they should.
+- `benchmarks/run.py` rehearses `sf1-ssb` correct, and `sf100-ssb-4chip`
+  over four of the eight devices, and catches the float32 control in
+  both; the cells' readers read hand-made runs as they should.
 """
 
 import importlib
 import json
 import os
 import sys
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
@@ -109,7 +114,8 @@ def test_a_star_query_is_one_device_join_reader_with_a_lookup_a_dimension(
 
 @pytest.fixture
 def mesh_of(request):
-    """The mesh the statements run on: one device, or all eight."""
+    """The mesh the statements run on: one device, four (the four chips
+    of one host), or all eight."""
     from tidb_tpu.copr import parallel
 
     n = request.param
@@ -124,14 +130,18 @@ def mesh_of(request):
         parallel.MESH_CACHE.clear()
 
 
-@pytest.mark.parametrize("mesh_of", [1, 8], indirect=True)
+@pytest.mark.parametrize("mesh_of", [1, 4, 8], indirect=True)
 @pytest.mark.parametrize("engine", [1, 0])
 def test_served_answers_equal_the_reference(loaded, mesh_of, engine):
     from harness import compare, serve
 
+    from tidb_tpu.copr import jax_engine as je
     from tidb_tpu.metrics import REGISTRY, STAR_LOOKUPS
 
     domain, tables = loaded
+    # the fact is not a whole number of tiles a shard: the last shard's
+    # padding is masked
+    assert len(tables["lineorder"]["lo_orderdate"]) % (mesh_of * je.TILE)
     srv = serve.Served(domain)
     before = REGISTRY.snapshot().get(STAR_LOOKUPS, 0)
     try:
@@ -152,6 +162,59 @@ def test_served_answers_equal_the_reference(loaded, mesh_of, engine):
     # lookups chained in it: 3 x 1 + 7 x 3 + 3 x 4
     lookups = REGISTRY.snapshot().get(STAR_LOOKUPS, 0) - before
     assert lookups == (36 if engine else 0)
+
+
+@pytest.mark.parametrize("mesh_of", [4], indirect=True)
+def test_a_star_over_four_shards_names_them_and_its_uploads_bytes(
+        loaded, mesh_of):
+    """Q4.1 over four devices: one dispatch that names its four shards
+    and its four lookups, and one upload a dimension whose `bytes` are
+    its code table's on each of the four devices."""
+    domain, tables = loaded
+    sess = domain.new_session()
+    sess.execute("set tidb_use_tpu = 1")
+    got = sess.execute("trace " + _query("ssb41")["sql"])
+    assert got
+    spans = []
+
+    def walk(sp):
+        spans.append(sp)
+        for c in sp.children:
+            walk(c)
+
+    walk(sess.last_trace.root)
+    chunks = [sp.attrs for sp in spans if sp.name == "copr.chunk"
+              and (sp.attrs or {}).get("lookups")]
+    assert [(a["lookups"], a["shards"], a["devices"]) for a in chunks] \
+        == [(4, 4, 4)]
+    ups = [sp.attrs for sp in spans if sp.name == "star.build"
+           and sp.attrs.get("phase") == "upload"]
+    assert len(ups) == 4
+    for a in ups:
+        assert a["devices"] == 4
+        # a direct table of int32 codes, or a compared side's [2, K] keys
+        # and codes in 32 or 64 bits, on each device
+        assert a["bytes"] in (4 * 4 * a["rows"], 4 * 2 * 4 * a["rows"],
+                              4 * 2 * 8 * a["rows"]), a
+
+
+def test_the_blocked_reference_equals_the_plain_one(loaded, monkeypatch):
+    """`_ssbw.py` answers every query as `_ssb.py` does over blocks that
+    cut the fact anywhere; its float32 control has the same groups (its
+    sums round block by block, so they are not `_ssb.py`'s control's)."""
+    w = importlib.import_module("queries._ssbw")
+    _domain, tables = loaded
+    monkeypatch.setattr(w, "ROWS", 4093)
+    assert len(tables["lineorder"]["lo_orderdate"]) > 10 * w.ROWS
+    for name in QUERIES:
+        ref = importlib.import_module(f"queries.{name}")
+        assert w.reference(tables, ref.Q) == ref.reference(tables, {}), name
+        lower = w.control(tables, ref.Q)
+        assert len(lower) == len(ref.control(tables, {})), name
+    for name in LOOKUPS:
+        blocked = importlib.import_module(f"queries.{name}w")
+        assert blocked.Q is importlib.import_module(f"queries.{name}").Q
+        assert _query(name + "w")["sql"] == _query(name)["sql"]
 
 
 def test_join_trees_that_differ_in_an_earlier_build_side_keep_apart(
@@ -314,6 +377,39 @@ def test_sf1_ssb_rehearsed_is_correct_and_its_control_is_caught(
     assert "star_roofline" not in got   # no peaks in a rehearsal
 
 
+def test_sf100_ssb_4chip_rehearsed_over_four_devices_is_correct(
+        bench, capsys, monkeypatch):
+    """The four-chip cell at SF 0.01 over four of the eight devices: every
+    statement a star over four shards, `correct`, the control caught."""
+    from tidb_tpu.copr import parallel
+
+    devs, epoch = parallel._eligible_devices()
+    monkeypatch.setattr(parallel, "_eligible_devices",
+                        lambda: (devs[:4], epoch))
+    run = importlib.import_module("run")
+    parallel.MESH_CACHE.clear()
+    try:
+        rc = run.main(["--workload", "sf100-ssb-4chip", "--seed",
+                       str(SEED + 3), "--seconds", "2", "--trace", "1",
+                       "--sf", str(SF), "--rehearse-cpu", "1",
+                       "--control", "1"])
+    finally:
+        parallel.MESH_CACHE.clear()
+    assert rc == 0
+    lines = capsys.readouterr().out.strip().splitlines()
+    result, notes = json.loads(lines[-1]), [json.loads(x) for x in lines[:-1]]
+    assert result["correct"] is True and result["rehearsal"] is True
+    assert result["attempted"] >= 1 and result["failed"] == 0
+    assert [n["control"]["caught"] for n in notes if "control" in n] == [
+        True]
+    got = result["metrics"]
+    assert got["device_rung_pct"]["value"] == 100.0
+    assert got["compiles_in_window"]["value"] == 0
+    assert got["star_upload_mb"]["value"] > 0
+    assert 0 < got["host_store_gb"]["value"] < 1
+    assert "mesh_star_roofline" not in got   # no peaks in a rehearsal
+
+
 # ---- the cell's readers, on hand-made runs ----------------------------------
 
 def _span(name, **attrs):
@@ -372,3 +468,104 @@ def test_compare_lookup_pct_is_the_compared_share_of_the_lookups(bench):
     # the parent's `copr.chunk` carries no such attribute: nothing
     bare = [_span("copr.chunk", lookups=3)]
     assert _read("compare_lookup_pct", {"spans": [bare, []]}) is None
+
+
+def test_mesh_star_roofline_divides_by_the_traced_devices(bench):
+    run = {"device_trace": {"stmt_busy_s": [0.01, 0.03], "devices": 4},
+           "peaks": {"hbm_bytes_per_s": 819e9},
+           "stmt_bytes": [8.19e9, 8.19e9]}
+    # 0.02 s the least one chip could take, a quarter of it over four
+    # chips, over 0.04 s busy on the first
+    assert _read("mesh_star_roofline", run) == pytest.approx(12.5)
+    assert _read("mesh_star_roofline", dict(run, peaks=None)) is None
+
+
+def test_star_collective_ms_is_the_collectives_a_device_and_statement(
+        bench):
+    ok = SimpleNamespace(ok=True)
+    run = {"statements": [ok, ok, SimpleNamespace(ok=False)],
+           "device_trace": {"devices": 4, "op_time": {
+               "all-reduce.3": 0.008, "all-reduce-start.1": 0.004,
+               "sort.19": 9.0}}}
+    # 12 ms of collectives over four devices and two statements completed
+    assert _read("star_collective_ms", run) == pytest.approx(1.5)
+    assert _read("star_collective_ms", dict(run, device_trace=None)) is None
+
+
+def test_star_upload_mb_is_the_median_of_the_statements_upload_bytes(bench):
+    def up(*sizes, devices=4):
+        return [_span("star.build", phase="upload", bytes=b,
+                      devices=devices) for b in sizes]
+
+    spans = [up(1e6, 3e6), up(8e6) + [_span("star.build", phase="codes",
+                                             bytes=5e6)], up(2e6),
+             [_span("copr.chunk", lookups=3)]]
+    # 4, 8 and 2 MB; the statement with no upload is not one
+    assert _read("star_upload_mb", {"spans": spans}) == pytest.approx(4.0)
+    # the parent's upload spans name no devices: nothing
+    bare = [[_span("star.build", phase="upload", bytes=1e6)]]
+    assert _read("star_upload_mb", {"spans": bare}) is None
+
+
+def test_host_store_gb_reads_the_gauge_of_the_stores_blocks(bench):
+    from tidb_tpu.metrics import REGISTRY, STORE_BASE_BYTES
+
+    before = REGISTRY.get(STORE_BASE_BYTES, None)
+    try:
+        REGISTRY.set(STORE_BASE_BYTES, 18.5e9)
+        assert _read("host_store_gb", {}) == pytest.approx(18.5)
+    finally:
+        if before is not None:
+            REGISTRY.set(STORE_BASE_BYTES, before)
+
+
+@pytest.mark.parametrize("G", [1, 16, 256])
+@pytest.mark.parametrize("nulls", [False, True])
+def test_star_group_sums_compare_and_sort_agree(G, nulls, monkeypatch):
+    """A star's group sums compare every row's id with every group, block
+    by block, where `star_compares` (up to STAR_COMPARE_GROUPS groups, or
+    STAR_COMPARE_GROUPS_LARGE past STAR_COMPARE_LARGE_ROWS rows a shard);
+    else they sort the ids.
+    Both give the same rows, counts and sums, exact modulo 2^64: rows
+    that are not live, an argument's NULLs, a count(*) beside two sums,
+    values at both ends of int64 that wrap, and a shard of several blocks
+    and a part of one."""
+    import jax.numpy as jnp
+
+    from tidb_tpu.copr import fusion
+
+    rng = np.random.default_rng(G + 7 * nulls)
+    n = 3 * 4096
+    monkeypatch.setattr(fusion, "STAR_COMPARE_BLOCK", 4096)
+    gid = jnp.asarray(rng.integers(0, G, n, dtype=np.int32))
+    live = jnp.asarray(rng.random(n) < 0.7)
+    big = rng.integers(-(1 << 62), 1 << 62, n) * 2
+    small = rng.integers(-1000, 1000, n).astype(np.int32)
+    valid = [jnp.asarray(rng.random(n) < 0.8 if nulls else np.ones(n, bool))
+             for _ in range(2)]
+    values = [(None, jnp.ones(n, dtype=jnp.bool_)),
+              (jnp.asarray(big), valid[0]), (jnp.asarray(small), valid[1])]
+
+    def run(limit):
+        monkeypatch.setattr(fusion, "STAR_COMPARE_GROUPS", limit)
+        monkeypatch.setattr(fusion, "STAR_COMPARE_GROUPS_LARGE", limit)
+        rows, out = fusion.star_group_sums(gid, live, values, G, nulls)
+        return [np.asarray(rows)] + [np.asarray(x) for t, c in out
+                                     for x in (t, c) if x is not None]
+
+    compared, sorted_ = run(G), run(G - 1)
+    assert len(compared) == len(sorted_) == 6
+    for a, b in zip(compared, sorted_):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(a, b)
+    assert compared[0].sum() == int(np.asarray(live).sum())
+
+
+def test_a_star_compares_its_groups_where_that_is_the_faster():
+    """The compare up to 512 groups, and up to 1,024 from 2^26 rows a
+    shard, where the sort costs more a row; past that the sort."""
+    from tidb_tpu.copr.fusion import star_compares
+
+    assert star_compares(512, 1 << 23) and not star_compares(1024, 1 << 23)
+    assert star_compares(1024, 144 << 20) and star_compares(1024, 1 << 26)
+    assert not star_compares(2048, 144 << 20)

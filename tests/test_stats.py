@@ -88,3 +88,57 @@ class TestAnalyze:
         assert sess.domain.stats.need_auto_analyze(t.id)  # no stats, rows > 0
         sess.execute("analyze table t")
         assert not sess.domain.stats.need_auto_analyze(t.id)
+
+
+def _stats_tuple(cs):
+    h = cs.hist
+    return ([(b.upper, b.lower, b.count, b.repeat) for b in h.buckets],
+            h.null_count, h.ndv, h.total, cs.null_count, cs.ndv,
+            cs.cms.table.tolist(), cs.cms.count)
+
+
+@pytest.mark.parametrize("part_rows,dense", [(1 << 24, 1 << 26),
+                                             (3000, 1 << 26), (3000, 64)])
+def test_analyze_in_parts_gives_the_whole_columns_stats(monkeypatch,
+                                                        part_rows, dense):
+    """ANALYZE counts an integer column in parts of ANALYZE_PART_ROWS base
+    rows and merges their distinct values and counts (densely, or by a
+    sort past DENSE_MERGE_RANGE): the histogram, sketch, NDV and NULLs of
+    every column are those of the whole column read and sorted at once,
+    for blocks of several widths, NULLs, strings, negative values, a
+    column of one value, one of NULLs alone and one past 2^53 whose
+    neighbours round to one float."""
+    from tidb_tpu.statistics import handle as hmod
+    from tidb_tpu.store.blockstore import BLOCK_SIZE
+
+    monkeypatch.setattr(hmod, "ANALYZE_PART_ROWS", part_rows)
+    monkeypatch.setattr(hmod, "DENSE_MERGE_RANGE", dense)
+    dom = Domain()
+    s = dom.new_session()
+    s.execute("create table t (a bigint, b bigint, c varchar(8), "
+              "d bigint, e bigint, f bigint)")
+    info = dom.catalog.info_schema().table("test", "t")
+    store = dom.storage.table(info.id)
+    rng = np.random.default_rng(11)
+    for n, wide in ((BLOCK_SIZE + 777, False), (5000, True)):
+        a = rng.integers(-50, 50, n) * (1 << 20 if wide else 1)
+        b = rng.integers(0, 300, n)
+        c = rng.choice(np.array(["x", "yy", "z", "w"], dtype=object), n)
+        d = np.full(n, 7)
+        e = np.zeros(n, dtype=np.int64)
+        f = (1 << 60) + rng.integers(0, 8, n) * (1 if wide else 512)
+        store.bulk_load_arrays(
+            [a, b, c, d, e, f], ts=dom.storage.current_ts(),
+            valids=[None, rng.random(n) > 0.2, None, None,
+                    np.zeros(n, dtype=np.bool_), None])
+    h = dom.stats
+    got = h.analyze_table(info.id)
+    n_base = store.base_rows
+    for ci in range(store.n_cols):
+        assert hmod._counted(store, ci, n_base)
+        want = h._column_stats(store, ci, n_base, set(), {}, 64)
+        assert _stats_tuple(got.columns[ci]) == _stats_tuple(want), ci
+    assert got.columns[1].null_count > 0
+    assert got.columns[4].null_count == n_base
+    # 2^60 + 0..7 round to one float, 2^60 + 512 x 0..7 to eight
+    assert got.columns[5].ndv == 8

@@ -545,20 +545,23 @@ def ssb():
     return domain, table, sql
 
 
-def test_star_join_program_compiles_for_one_v5e_chip(meshes, ssb):
+@pytest.mark.parametrize("groups,sorts", [(SSB_Q21_CAPS[1], 0), (1024, 1)])
+def test_star_join_program_compiles_for_one_v5e_chip(meshes, ssb, groups,
+                                                     sorts):
     """The fact scan of SSB's Q2.1 with its three lookups chained in one
-    program, at SF1's shapes: one gather a dimension and one sort of the
-    group ids for the aggregate."""
+    program, at SF1's shapes: one gather a dimension, and the group sums
+    by a compare of the ids with its 512 groups (`fusion.star_compares`),
+    or with 1,024 groups one sort of the ids."""
     import jax
 
     domain, table, sql = ssb
     core, args = _fragment_args(domain.new_session(), table, sql("ssb21"),
                                 meshes[1], SSB_TILES, wire=SSB_WIRE,
-                                star_caps=SSB_Q21_CAPS)
+                                star_caps=(SSB_Q21_CAPS[0], groups))
     compiled, took, nbytes = _timed_compile(jax.jit(core), args)
-    assert compiled.as_text().count(" sort(") == 1
-    # an 8-core x86 host reads 43.8 s, 106.9 GB (the compiler counts a
-    # gather's whole operand a row)
+    assert compiled.as_text().count(" sort(") == sorts
+    # an 8-core x86 host reads 43.8 s, 106.9 GB with the sort (the
+    # compiler counts a gather's whole operand a row)
     print(f"star {took:.1f}s {nbytes / 1e9:.3f}GB")
     assert took < 150
 

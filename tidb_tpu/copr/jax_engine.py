@@ -283,7 +283,8 @@ def _gather_tile(table, store_ci: int, start: int, end: int):
         np.float64 if meta.ftype.kind == TypeKind.FLOAT else np.int64
     )
     parts, vparts = [], []
-    for _, arrs, vals in table.iter_base_blocks([store_ci], start, end):
+    for _, arrs, vals in table.iter_base_blocks([store_ci], start, end,
+                                                narrow=True):
         parts.append(arrs[0])
         v = vals[0]
         vparts.append(v if v is not None else np.ones(len(arrs[0]), np.bool_))

@@ -445,7 +445,7 @@ class _MeshCache:
                 if has_null:
                     vflat = np.zeros(n_pad * tile, dtype=np.bool_)
                 for _s, arrs, vals in table.iter_base_blocks(
-                        [store_ci], 0, table.base_rows):
+                        [store_ci], 0, table.base_rows, narrow=True):
                     blk, v = arrs[0], vals[0]
                     n = len(blk)
                     flat[off:off + n] = blk  # casts to wire dtype
@@ -2651,11 +2651,16 @@ def _run_mesh_once(storage, req: CopRequest, tid: int,
         if star is None:
             req.mesh_reject_reason = why
             return None
+        # each table goes to every device of the mesh here, once, so the
+        # span holds the whole upload (`bytes` summed over the devices;
+        # across processes jit replicates it, as it does every operand)
+        rep = NamedSharding(mesh, P()) if jax.process_count() == 1 \
+            else None
         for fid, t in zip(star["dims"], star["tables"]):
             with span("star.build", dim=req.aux.get(f"dim_{fid}", fid),
-                      phase="upload",
-                      rows=t.shape[-1], bytes=t.nbytes):
-                pargs.append(jnp.asarray(t))
+                      phase="upload", rows=t.shape[-1],
+                      bytes=t.nbytes * S, devices=S):
+                pargs.append(jax.device_put(t, rep))
         counts += star["bases"]
         compares = sum(t.ndim == 2 for t in star["tables"])
         kpads.append(("star",) + star["caps"])
@@ -2756,7 +2761,8 @@ def _run_mesh_once(storage, req: CopRequest, tid: int,
     if bounds:
         out = _dispatch_once(kind, fn, datas, valids, del_mask, bounds,
                              lvals, pargs, scalars, mesh_ids,
-                             lookups=len(an.lookups), compares=compares)
+                             lookups=len(an.lookups), compares=compares,
+                             shards=S if star else None)
         if star:
             out["decode"] = star["decode"]
     try:
@@ -2838,13 +2844,16 @@ def _mesh_result(req, dag, table, an: _Analyzed, kind: str, out, inserted,
 
 
 def _dispatch_once(kind, fn, datas, valids, del_mask, bounds, lvals, pargs,
-                   scalars, mesh_ids, lookups: int = 0, compares: int = 0):
+                   scalars, mesh_ids, lookups: int = 0, compares: int = 0,
+                   shards: Optional[int] = None):
     """The ONE dispatch of a mesh statement: the compiled program over
     all of the statement's range slots, between two cancellation seams.
     A dispatch in flight runs to completion and costs one pass over the
     resident table whatever its bounds (the program masks rows outside
     them, it does not skip them), so KILL / timeout / a depleted
-    resource group land before it or after it."""
+    resource group land before it or after it.  A star's dispatch
+    names its `shards`: the fact table's, each one summing its rows by
+    group before the states merge by psum."""
     from ..lifecycle import dispatch_admission, scope_check
     from ..trace import span
 
@@ -2864,7 +2873,9 @@ def _dispatch_once(kind, fn, datas, valids, del_mask, bounds, lvals, pargs,
         REGISTRY.inc(LOOKUP_COMPARES, float(compares))
     with span("copr.chunk", kind=kind, devices=len(mesh_ids),
               rows=sum(hi - lo for lo, hi in bounds), lookups=lookups,
-              compares=compares):
+              compares=compares) as sp:
+        if shards is not None:
+            sp.set(shards=shards)
         # resource-group admission per dispatch: a depleted group
         # waits here, and the dispatch's device time is its charge
         with dispatch_admission(DISPATCH_LOCK):

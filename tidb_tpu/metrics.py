@@ -257,6 +257,10 @@ STAR_LOOKUPS = "star_lookups_total"
 #: those of them that compared the probe key with every build key
 #: (`fusion.compare_lookup`) instead of gathering
 LOOKUP_COMPARES = "lookup_compares_total"
+#: gauge: the bytes every live table store's base blocks hold, as held
+#: (integers at their narrow widths), set at the end of each bulk load
+#: and compaction
+STORE_BASE_BYTES = "store_base_bytes"
 
 #: statement classes carrying per-class end-to-end latency histograms
 #: (`stmt_latency_<class>_ms`) and SLO threshold sysvars

@@ -11,8 +11,10 @@ the pushdown-ANALYZE shape of executor/analyze.go, minus the RPC hop.
 
 from __future__ import annotations
 
+import os
 import threading
 import time
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
@@ -21,6 +23,15 @@ import numpy as np
 from ..types import TypeKind
 from .histogram import sorted_ints, CMSketch, FMSketch, Histogram
 from ..util_concurrency import make_rlock
+
+#: threads an ANALYZE builds with (numpy's sorts and scans release the
+#: GIL), at most the CPUs the process may run on
+ANALYZE_THREADS = 16
+#: base rows one thread sorts: a longer integer column is counted in parts
+#: whose distinct values and counts are then merged
+ANALYZE_PART_ROWS = 1 << 24
+#: the widest range of values whose parts' counts merge in one dense array
+DENSE_MERGE_RANGE = 1 << 26
 
 
 @dataclass
@@ -43,6 +54,71 @@ class TableStats:
     # offsets, in index order): correlated multi-column selectivity
     # (statistics/index.go histogram NDV role)
     index_ndv: Dict[tuple, int] = field(default_factory=dict)
+
+
+def _counted(store, ci: int, n_base: int) -> bool:
+    """Whether ANALYZE counts column `ci` in parts: base rows held as
+    integers (strings as their dictionary codes)."""
+    if not n_base:
+        return False
+    _off, (a,), _v = next(store.iter_base_blocks([ci], 0, 1, narrow=True))
+    return a.dtype.kind in "ib" or a.dtype.kind == "u" and a.itemsize < 8
+
+
+def _part_counts(store, ci: int, start: int, end: int):
+    """(the distinct values of integer column `ci` in base rows
+    [start, end), sorted, as int64; the rows of each; the NULLs)."""
+    parts, nulls = [], 0
+    for _off, (a,), (v,) in store.iter_base_blocks([ci], start, end,
+                                                   narrow=True):
+        if v is not None:
+            nulls += int(len(v) - np.count_nonzero(v))
+            a = a[v]
+        parts.append(a)
+    # a byte-wide column sorts as int16: numpy's vectorised sort takes
+    # 16 bits and up, and sorts bytes twenty times slower
+    dt = np.result_type(*parts)
+    vals = np.concatenate(parts, dtype=np.int16 if dt.itemsize == 1 else dt)
+    if not len(vals):
+        return np.zeros(0, np.int64), np.zeros(0, np.int64), nulls
+    vals.sort()
+    first = np.flatnonzero(np.concatenate(([True], vals[1:] != vals[:-1])))
+    return (vals[first].astype(np.int64),
+            np.diff(np.append(first, len(vals))), nulls)
+
+
+def _merge_counts(parts) -> tuple:
+    """One column's (distinct values, counts) from its parts'."""
+    parts = [(u, c) for u, c, _n in parts if len(u)]
+    if len(parts) <= 1:
+        return parts[0] if parts else (np.zeros(0, np.int64),
+                                       np.zeros(0, np.int64))
+    lo = min(int(u[0]) for u, _c in parts)
+    hi = max(int(u[-1]) for u, _c in parts)
+    if hi - lo < DENSE_MERGE_RANGE:
+        dense = np.zeros(hi - lo + 1, dtype=np.int64)
+        for u, c in parts:
+            dense[u - lo] += c  # a part's values are distinct
+        nz = np.flatnonzero(dense)
+        return nz + lo, dense[nz]
+    u = np.concatenate([u for u, _c in parts])
+    c = np.concatenate([c for _u, c in parts])
+    order = np.argsort(u, kind="stable")
+    u, c = u[order], c[order]
+    first = np.flatnonzero(np.concatenate(([True], u[1:] != u[:-1])))
+    return u[first], np.add.reduceat(c, first)
+
+
+def _from_parts(parts, n_buckets: int) -> ColumnStats:
+    """A column's stats from its parts' counts (`_part_counts`): the
+    histogram and sketch the whole column's sort gives."""
+    values, counts = _merge_counts(parts)
+    nulls = sum(n for _u, _c, n in parts)
+    hist = Histogram.from_counts(values, counts, nulls, n_buckets)
+    cms = CMSketch()
+    if len(values):
+        cms.insert_batch(values, counts)
+    return ColumnStats(hist, cms, nulls, hist.ndv)
 
 
 class StatsHandle:
@@ -94,6 +170,71 @@ class StatsHandle:
             self._cache[table_info.id] = merged
         return merged
 
+    @staticmethod
+    def _column_stats(store, ci: int, n_base: int, dele, inserted,
+                      n_buckets: int) -> "ColumnStats":
+        """One column's histogram, sketch and NULL count over the base rows
+        (read as the store holds them: integers narrow) and the committed
+        delta."""
+        meta = store.cols[ci]
+        chunk = store.base_chunk([ci], 0, n_base, decode_strings=False,
+                                 narrow=True)
+        col = chunk.col(0)
+        data = col.data
+        valid = col.validity()
+        if dele:
+            keep = np.ones(n_base, dtype=np.bool_)
+            keep[list(dele)] = False
+            data, valid = data[keep], valid[keep]
+        if col.valid is None and not dele:
+            vals, nulls = data, 0  # no NULL: the column as it is
+        else:
+            vals = data[valid]
+            nulls = int((~valid).sum())
+        if inserted:
+            # fold committed delta rows in (strings -> dict codes)
+            dvals = []
+            for row in inserted.values():
+                x = row[ci]
+                if x is None:
+                    nulls += 1
+                    continue
+                if meta.ftype.kind == TypeKind.STRING:
+                    code = store.encode_dict_const(ci, str(x)) \
+                        if meta.dictionary is not None else \
+                        hash(str(x)) & 0x7FFFFFFF
+                    dvals.append(code)
+                else:
+                    dvals.append(x)
+            if dvals:
+                vals = np.concatenate([
+                    vals.astype(np.float64, copy=False),
+                    np.asarray(dvals, dtype=np.float64),
+                ])
+        if meta.ftype.kind == TypeKind.STRING and vals.dtype == object:
+            # shouldn't happen (dict-encoded), but guard
+            vals = np.array([hash(x) & 0x7FFFFFFF for x in vals],
+                            dtype=np.int64)
+        cms = CMSketch()
+        if vals.dtype.kind in "iub" and len(vals):
+            # one sort serves both: the histogram reads the sorted
+            # column, the sketch each distinct value with its count
+            sv = sorted_ints(vals)
+            hist = Histogram.build(sv, nulls, n_buckets, presorted=True)
+            first = np.flatnonzero(
+                np.concatenate(([True], sv[1:] != sv[:-1])))
+            cms.insert_batch(
+                sv[first].astype(np.int64),
+                np.diff(np.append(first, len(sv))))
+        else:
+            hist = Histogram.build(vals.astype(np.float64, copy=False),
+                                   nulls, n_buckets)
+            if len(vals):
+                cms.insert_batch(vals.astype(np.int64, copy=False)
+                                 if vals.dtype != np.float64
+                                 else vals.view(np.int64))
+        return ColumnStats(hist, cms, nulls, hist.ndv)
+
     def _analyze_table(self, table_id: int, n_buckets: int = 64,
                        index_offsets=None) -> TableStats:
         store = self.storage.table(table_id)
@@ -107,61 +248,31 @@ class StatsHandle:
             row_count=n_base - len(dele) + len(inserted),
             build_time=time.time(),
         )
-        for ci in range(store.n_cols):
-            meta = store.cols[ci]
-            chunk = store.base_chunk([ci], 0, n_base, decode_strings=False)
-            col = chunk.col(0)
-            data = col.data
-            valid = col.validity()
-            if dele:
-                keep = np.ones(n_base, dtype=np.bool_)
-                keep[list(dele)] = False
-                data, valid = data[keep], valid[keep]
-            vals = data[valid]
-            nulls = int((~valid).sum())
-            if inserted:
-                # fold committed delta rows in (strings -> dict codes)
-                dvals = []
-                for row in inserted.values():
-                    x = row[ci]
-                    if x is None:
-                        nulls += 1
-                        continue
-                    if meta.ftype.kind == TypeKind.STRING:
-                        code = store.encode_dict_const(ci, str(x)) \
-                            if meta.dictionary is not None else \
-                            hash(str(x)) & 0x7FFFFFFF
-                        dvals.append(code)
-                    else:
-                        dvals.append(x)
-                if dvals:
-                    vals = np.concatenate([
-                        vals.astype(np.float64, copy=False),
-                        np.asarray(dvals, dtype=np.float64),
-                    ])
-            if meta.ftype.kind == TypeKind.STRING and vals.dtype == object:
-                # shouldn't happen (dict-encoded), but guard
-                vals = np.array([hash(x) & 0x7FFFFFFF for x in vals],
-                                dtype=np.int64)
-            cms = CMSketch()
-            if vals.dtype.kind in "iub" and len(vals):
-                # one sort serves both: the histogram reads the sorted
-                # column, the sketch each distinct value with its count
-                sv = sorted_ints(vals)
-                hist = Histogram.build(sv, nulls, n_buckets, presorted=True)
-                first = np.flatnonzero(
-                    np.concatenate(([True], sv[1:] != sv[:-1])))
-                cms.insert_batch(
-                    sv[first].astype(np.int64),
-                    np.diff(np.append(first, len(sv))))
-            else:
-                hist = Histogram.build(vals.astype(np.float64, copy=False),
-                                       nulls, n_buckets)
-                if len(vals):
-                    cms.insert_batch(vals.astype(np.int64, copy=False)
-                                     if vals.dtype != np.float64
-                                     else vals.view(np.int64))
-            stats.columns[ci] = ColumnStats(hist, cms, nulls, hist.ndv)
+        # an integer column of the base rows alone is sorted in parts of
+        # ANALYZE_PART_ROWS, all columns' parts at once, and its stats
+        # built from the merged distinct values and counts; any other
+        # column is read and sorted whole
+        threads = max(1, min(ANALYZE_THREADS, len(os.sched_getaffinity(0))))
+        with ThreadPoolExecutor(threads) as pool:
+            work = {}
+            for ci in range(store.n_cols):
+                if not dele and not inserted and _counted(store, ci, n_base):
+                    work[ci] = [pool.submit(_part_counts, store, ci, a,
+                                            min(a + ANALYZE_PART_ROWS,
+                                                n_base))
+                                for a in range(0, n_base,
+                                               ANALYZE_PART_ROWS)]
+                else:
+                    work[ci] = pool.submit(self._column_stats, store, ci,
+                                           n_base, dele, inserted,
+                                           n_buckets)
+            for ci, w in work.items():
+                if isinstance(w, list):
+                    w = pool.submit(_from_parts, [f.result() for f in w],
+                                    n_buckets)
+                work[ci] = w
+            for ci, w in work.items():
+                stats.columns[ci] = w.result()
         for offs in (index_offsets or ()):
             offs = tuple(offs)
             if not offs or any(o >= store.n_cols for o in offs):

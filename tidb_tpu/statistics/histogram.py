@@ -20,7 +20,7 @@ def sorted_ints(values: np.ndarray) -> np.ndarray:
         lo, hi = int(values.min()), int(values.max())
         for dt in (np.int16, np.int32):
             if np.iinfo(dt).min <= lo and hi <= np.iinfo(dt).max:
-                values = values.astype(dt)
+                values = values.astype(dt, copy=False)
                 break
     return np.sort(values)
 
@@ -50,23 +50,60 @@ class Histogram:
         n = len(values)
         if n == 0:
             return Histogram([], null_count, 0, 0)
-        v = (values.astype(np.float64, copy=False) if presorted
-             else np.sort(values.astype(np.float64, copy=False)))
-        ndv = int((np.diff(v) != 0).sum()) + 1
+        if presorted and values.dtype.kind in "iu" \
+                and max(abs(int(values[0])), abs(int(values[-1]))) < 1 << 53:
+            # sorted integers that float64 holds exactly: read as they are
+            v = values
+        else:
+            v = (values.astype(np.float64, copy=False) if presorted
+                 else np.sort(values.astype(np.float64, copy=False)))
+        ndv = int(np.count_nonzero(v[1:] != v[:-1])) + 1
         per = max(n // n_buckets, 1)
         buckets: List[Bucket] = []
         i = 0
         while i < n:
             j = min(i + per, n)
             upper = v[j - 1]
-            # extend to include all duplicates of upper (repeat semantics)
-            while j < n and v[j] == upper:
-                j += 1
-            repeat = int(np.searchsorted(v, upper, "right")
-                         - np.searchsorted(v, upper, "left"))
+            right = int(np.searchsorted(v, upper, "right"))
+            # extend to include all duplicates of upper (repeat semantics;
+            # a NaN upper has none)
+            if upper == upper:
+                j = max(j, right)
+            repeat = int(right - np.searchsorted(v, upper, "left"))
             buckets.append(Bucket(float(upper), float(v[i]), j - i, repeat))
             i = j
         return Histogram(buckets, null_count, ndv, n)
+
+    @staticmethod
+    def from_counts(values: np.ndarray, counts: np.ndarray,
+                    null_count: int = 0,
+                    n_buckets: int = 64) -> "Histogram":
+        """The histogram `build` makes of an integer column whose distinct
+        values, sorted, are `values`, with `counts[i]` rows of each."""
+        if len(values) and max(abs(int(values[0])),
+                               abs(int(values[-1]))) >= 1 << 53:
+            # `build` reads such a column as float64: values that round
+            # to one float are one value
+            f = values.astype(np.float64)
+            first = np.flatnonzero(np.concatenate(([True], f[1:] != f[:-1])))
+            values, counts = f[first], np.add.reduceat(counts, first)
+        n = int(counts.sum()) if len(counts) else 0
+        if n == 0:
+            return Histogram([], null_count, 0, 0)
+        ends = np.cumsum(counts)  # a value's rows end before ends[k]
+        per = max(n // n_buckets, 1)
+        buckets: List[Bucket] = []
+        i = 0
+        while i < n:
+            # the bucket's upper is the value at row min(i + per, n) - 1,
+            # and takes all its duplicates
+            k = int(np.searchsorted(ends, min(i + per, n) - 1, "right"))
+            j = int(ends[k])
+            lower = values[int(np.searchsorted(ends, i, "right"))]
+            buckets.append(Bucket(float(values[k]), float(lower), j - i,
+                                  int(counts[k])))
+            i = j
+        return Histogram(buckets, null_count, len(values), n)
 
     # ------------------------------------------------------------------
     def row_count(self) -> int:

@@ -10,6 +10,11 @@ note).  Design:
   row-validity masks, so every block compiles to the same program.
 - **Strings** are dictionary-encoded at load with a *sorted* dictionary
   (order-preserving: code comparisons = string comparisons), codes int32.
+- **Integers** (every column whose logical dtype is an integer: INT,
+  DECIMAL, DATE, ...) are held block by block in the narrowest of int8,
+  int16, int32 and int64 that holds the block's values; every reader that
+  hands values out widens them to the column's logical dtype, and the
+  device path (`iter_base_blocks(narrow=True)`) takes them as they are.
 - **Delta**: an MVCC row store (handle -> version chain) for DML after load,
   with Percolator locks — the moral equivalent of TiDB's membuffer+TiKV MVCC
   (kv/memdb + mocktikv/mvcc_leveldb.go).  Scans overlay delta on base like
@@ -23,6 +28,7 @@ from __future__ import annotations
 import bisect
 import itertools
 import threading
+import weakref
 from dataclasses import dataclass, field
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
@@ -36,6 +42,42 @@ from ..util_concurrency import make_rlock
 BLOCK_SIZE = 1 << 16  # 65536 rows per block
 
 _STORE_SEQ = itertools.count(1)  # process-unique store tokens (cache keys)
+
+#: every live store, for the `store_base_bytes` gauge
+_LIVE_STORES: "weakref.WeakSet[TableStore]" = weakref.WeakSet()
+
+_NARROW_DTYPES = tuple(np.dtype(t) for t in
+                       (np.int8, np.int16, np.int32, np.int64))
+
+
+def narrowest(a: np.ndarray) -> np.ndarray:
+    """Integers `a` in the narrowest of int8, int16, int32 and int64 that
+    holds their values (`a` itself where it already is)."""
+    if a.dtype.kind == "u" and a.dtype.itemsize == 8:
+        a = a.astype(np.int64)
+    lo, hi = (int(a.min()), int(a.max())) if len(a) else (0, 0)
+    for dt in _NARROW_DTYPES:
+        info = np.iinfo(dt)
+        if info.min <= lo and hi <= info.max:
+            return a if a.dtype == dt else a.astype(dt)
+    return a.astype(np.int64)
+
+
+def _narrows(meta: "ColumnMeta") -> bool:
+    """Whether the store holds the column's blocks narrow (`narrowest`):
+    every column whose logical dtype is an integer (strings' int32 codes
+    and wide decimals' objects are held as they are)."""
+    return (meta.ftype.kind != TypeKind.STRING
+            and np.dtype(meta.ftype.np_dtype).kind == "i")
+
+
+def _publish_base_bytes():
+    """The `store_base_bytes` gauge: the base blocks every live store
+    holds, in bytes."""
+    from ..metrics import REGISTRY, STORE_BASE_BYTES
+
+    REGISTRY.set(STORE_BASE_BYTES,
+                 float(sum(s._held for s in list(_LIVE_STORES))))
 
 
 @dataclass
@@ -83,6 +125,9 @@ class TableStore:
         # bumped on bulk load / compact: device caches key on this
         self.base_version = 0
         self._col_stats: Dict[int, Tuple[int, int, bool]] = {}
+        # bytes of the base blocks held (`nbytes`), kept as blocks change
+        self._held = 0
+        _LIVE_STORES.add(self)
         # durability hook (store/persist.TablePersister); None = RAM-only
         self.persister = None
         self.on_mutate = None  # storage-level data-version bump (plan cache)
@@ -175,7 +220,10 @@ class TableStore:
                         meta.dictionary = dictionary
                         arr = codes
                 else:
-                    arr = np.ascontiguousarray(arr, dtype=meta.ftype.np_dtype)
+                    arr = np.asarray(arr)
+                    if not (_narrows(meta) and arr.dtype.kind in "iu"):
+                        arr = np.ascontiguousarray(
+                            arr, dtype=meta.ftype.np_dtype)
                 self._append_blocks_locked(ci, arr, valid)
             self.base_rows += n
             self.next_handle = max(self.next_handle, self.base_rows)
@@ -187,6 +235,7 @@ class TableStore:
                 self.on_mutate()
             if self.persister is not None:
                 self.persister.save_base(self)
+        _publish_base_bytes()
 
     def _validate_coded_locked(self, ci: int, codes: np.ndarray, new_dict):
         """Pure validation for Arrow-style coded ingest (no mutation)."""
@@ -222,13 +271,18 @@ class TableStore:
 
     def _append_blocks_locked(self, ci: int, arr: np.ndarray, valid: Optional[np.ndarray]):
         blocks, valids = self._blocks[ci], self._valids[ci]
+        narrow = narrowest if _narrows(self.cols[ci]) \
+            else np.ascontiguousarray
         off = 0
         n = len(arr)
-        # fill the last partial block first
+        # fill the last partial block first (it widens as np.concatenate
+        # promotes it, where the new values need more)
         if blocks and len(blocks[-1]) < BLOCK_SIZE:
             space = BLOCK_SIZE - len(blocks[-1])
             take = min(space, n)
-            blocks[-1] = np.concatenate([blocks[-1], arr[:take]])
+            old = blocks[-1]
+            blocks[-1] = np.concatenate([old, narrow(arr[:take])])
+            self._held += _block_bytes(blocks[-1]) - _block_bytes(old)
             if valids[-1] is not None or (valid is not None and not valid[:take].all()):
                 old_v = (
                     valids[-1]
@@ -244,7 +298,9 @@ class TableStore:
             off = take
         while off < n:
             take = min(BLOCK_SIZE, n - off)
-            blocks.append(np.ascontiguousarray(arr[off : off + take]))
+            blk = np.ascontiguousarray(narrow(arr[off : off + take]))
+            blocks.append(blk)
+            self._held += _block_bytes(blk)
             v = None
             if valid is not None and not valid[off : off + take].all():
                 v = valid[off : off + take].copy()
@@ -255,10 +311,14 @@ class TableStore:
     # base block access (device scan path)
     # ------------------------------------------------------------------
     def iter_base_blocks(
-        self, col_idx: Sequence[int], start: int, end: int
+        self, col_idx: Sequence[int], start: int, end: int,
+        narrow: bool = False,
     ) -> Iterator[Tuple[int, List[np.ndarray], List[Optional[np.ndarray]]]]:
         """Yield (handle_offset, [col arrays], [col valids]) for each base
-        block slice intersecting [start, end)."""
+        block slice intersecting [start, end): each array in its column's
+        logical dtype, or with `narrow` as the store holds it (an integer
+        column's block in the narrowest type that holds it: for a caller
+        that casts to a wire width of its own)."""
         # snapshot the block lists under the lock, then iterate the
         # locals: base blocks are append-only (compaction replaces the
         # whole lists), so the slices stay valid without holding the
@@ -269,26 +329,34 @@ class TableStore:
                 return
             blocks = {ci: list(self._blocks[ci]) for ci in col_idx}
             valids = {ci: list(self._valids[ci]) for ci in col_idx}
+        wide = {ci: np.dtype(self.cols[ci].ftype.np_dtype) for ci in col_idx
+                if not narrow and _narrows(self.cols[ci])}
         b0, b1 = start // BLOCK_SIZE, (end - 1) // BLOCK_SIZE
         for b in range(b0, b1 + 1):
             lo = max(start - b * BLOCK_SIZE, 0)
             hi = min(end - b * BLOCK_SIZE, BLOCK_SIZE)
             arrs, vals = [], []
             for ci in col_idx:
-                blk = blocks[ci][b]
-                arrs.append(blk[lo:hi])
+                blk = blocks[ci][b][lo:hi]
+                arrs.append(blk.astype(wide[ci], copy=False) if ci in wide
+                            else blk)
                 v = valids[ci][b]
                 vals.append(v[lo:hi] if v is not None else None)
             yield b * BLOCK_SIZE + lo, arrs, vals
 
     def base_chunk(self, col_idx: Sequence[int], start: int, end: int,
-                   decode_strings: bool = True) -> Chunk:
-        """Materialize base rows [start, end) as a host Chunk."""
+                   decode_strings: bool = True,
+                   narrow: bool = False) -> Chunk:
+        """Materialize base rows [start, end) as a host Chunk: integer
+        columns in their logical dtype, or with `narrow` in the narrowest
+        type that holds every block read (for a reader of the values'
+        order and counts alone, as ANALYZE is)."""
         cols: List[Column] = []
         parts: List[List[np.ndarray]] = [[] for _ in col_idx]
         vparts: List[List[np.ndarray]] = [[] for _ in col_idx]
         any_rows = False
-        for off, arrs, vals in self.iter_base_blocks(col_idx, start, end):
+        for off, arrs, vals in self.iter_base_blocks(col_idx, start, end,
+                                                     narrow=True):
             any_rows = True
             for i, (a, v) in enumerate(zip(arrs, vals)):
                 parts[i].append(a)
@@ -300,7 +368,9 @@ class TableStore:
             if not any_rows:
                 cols.append(Column.from_values(meta.ftype, []))
                 continue
-            data = np.concatenate(parts[i])
+            data = np.concatenate(
+                parts[i], dtype=meta.ftype.np_dtype
+                if _narrows(meta) and not narrow else None)
             valid = np.concatenate(vparts[i])
             if meta.ftype.kind == TypeKind.STRING and decode_strings:
                 data = _decode_dict(data, meta.dictionary)
@@ -323,7 +393,8 @@ class TableStore:
         for ci in col_idx:
             meta = self.cols[ci]
             blocks, valids = snap[ci]
-            dt = blocks[0].dtype if blocks else meta.ftype.np_dtype
+            dt = meta.ftype.np_dtype if _narrows(meta) or not blocks \
+                else blocks[0].dtype
             data = np.zeros(n, dtype=dt)
             valid = np.ones(n, dtype=np.bool_)
             for b in uniq_blocks:
@@ -502,6 +573,7 @@ class TableStore:
             # rebuild
             self._blocks = [[] for _ in self.cols]
             self._valids = [[] for _ in self.cols]
+            self._held = 0
             for meta in self.cols:
                 meta.dictionary = None
             self.base_rows = 0
@@ -565,12 +637,22 @@ class TableStore:
             return out
 
     def nbytes(self) -> int:
+        """Bytes of the base blocks as held (integers at their narrow
+        widths; an object value counted as its 8-byte reference)."""
         with self._mu:
-            total = 0
-            for blocks in self._blocks:
-                for b in blocks:
-                    total += b.nbytes if b.dtype != object else len(b) * 8
-            return total
+            return self._held
+
+    def recount_held(self):
+        """Recount `nbytes` after the block lists were replaced whole (the
+        persister's restore), and publish the gauge."""
+        with self._mu:
+            self._held = sum(_block_bytes(b) for blocks in self._blocks
+                             for b in blocks)
+        _publish_base_bytes()
+
+
+def _block_bytes(b: np.ndarray) -> int:
+    return b.nbytes if b.dtype != object else len(b) * 8
 
 
 def _decode_dict(codes: np.ndarray, dictionary: Optional[List[str]]) -> np.ndarray:

@@ -234,13 +234,17 @@ class TablePersister:
                 store._valids[ci] = []
                 if len(data):
                     # re-block without re-encoding: dictionaries restore
-                    # verbatim, so codes stay valid
-                    from .blockstore import BLOCK_SIZE
+                    # verbatim, so codes stay valid; integer blocks are
+                    # held narrow again, as the load held them
+                    from .blockstore import BLOCK_SIZE, _narrows, narrowest
 
+                    narrow = narrowest if _narrows(colmeta) \
+                        else np.ascontiguousarray
                     for off in range(0, len(data), BLOCK_SIZE):
                         blk = data[off: off + BLOCK_SIZE]
                         vb = valid[off: off + BLOCK_SIZE]
-                        store._blocks[ci].append(np.ascontiguousarray(blk))
+                        store._blocks[ci].append(
+                            np.ascontiguousarray(narrow(blk)))
                         store._valids[ci].append(
                             None if vb.all() else vb.copy()
                         )
@@ -252,6 +256,7 @@ class TablePersister:
         # base_version, which is bumped here
         store.base_version += 1
         store._col_stats.clear()
+        store.recount_held()
 
 
 def _np_scalar(o):
